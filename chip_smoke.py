@@ -7,8 +7,9 @@ process at a time; the served phase runs the daemon on a thread).
 There is no CPU branch: without a TPU it exits non-zero before any
 phase. All data comes from ``--seed``.
 
-Phases (one JSON line each; wall time, XLA lowerings, Mosaic builds
-and peak device bytes are informational, not metrics):
+Phases (one JSON line each; wall time, XLA lowerings, Mosaic builds,
+the lowerings outside the declared compile surface (PROGRAMS.md) and
+peak device bytes are informational, not metrics):
 
 - ``device``: the TPU the run holds.
 - ``north-star``: the 50k-op cas-register history of BASELINE.json
@@ -95,6 +96,8 @@ class Phase:
         self.line.update(
             wall_s=wall, xla_lowerings=c["xla_lowerings"],
             mosaic_builds=c["mosaic_builds"],
+            compile_offenders=[r.format()
+                               for r in self._guard.offenders()],
             peak_bytes_in_use=peaks if len(peaks) > 1 else peaks[0])
         if exc[0] is not None:
             self.line["error"] = f"{exc[0].__name__}: {exc[1]}"

@@ -309,7 +309,10 @@ def static_inventory() -> Inventory:
                       values=L["kernel_table_rows"])
     b_pad = Axis("b_pad", "pow2", 8, 2048)
     mesh_D = Axis("D", "pow2", *L["mesh_D"])
+    off2 = Axis("off", "enum", values=(2,))
+    res8 = Axis("res_rows", "enum", values=(8,))
     run_templates = []
+    chunk_templates = []
     run_sharded_templates = []
     reset_templates = []
     for W in L["kernel_words"]:
@@ -317,6 +320,13 @@ def static_inventory() -> Inventory:
             ((n_chunks, chunk, width),)
             + ((rows, lane),) * W
             + ((one, lane), (b_pad, lane), (table_rows, lane), ()))
+        # one chunk call as its own program (pallas_seg._chunk_jit):
+        # a spec chunk + (offset, nt), the (ws, stat, res) carry with
+        # the single-history 8-row results buffer, the packed table
+        chunk_templates.append(
+            ((chunk, width), (off2,))
+            + ((rows, lane),) * W
+            + ((one, lane), (res8, lane), (table_rows, lane)))
         # the shard_map form: every per-shard tensor gains the leading
         # mesh axis; per-shard shapes are the bucketed shapes divided
         # by D (global / D — both pow2, so the division stays on the
@@ -416,8 +426,6 @@ def static_inventory() -> Inventory:
     # the kernel rung's chunk call: one spec chunk + offsets + the
     # (ws, stat, res) carry + packed table — same axes as the
     # pallas-stream-scan ladder, single-chunk form
-    off2 = Axis("off", "enum", values=(2,))
-    res8 = Axis("res_rows", "enum", values=(8,))
     stream_kernel_templates = []
     for W in L["kernel_words"]:
         stream_kernel_templates.append(
@@ -482,13 +490,19 @@ def static_inventory() -> Inventory:
     sites = (
         Site(
             key="pallas-stream-scan",
-            jit_names=("run", "run_sharded", "carry_reset"),
+            jit_names=("seg_scan", "seg_stream_scan", "seg_chunk",
+                       "run_sharded", "carry_reset"),
             note="fused-kernel chunk scan (checker/pallas_seg._scan_fn)"
                  ": one Mosaic program per (SegKernelSpec, b_pad, "
                  "stream); specs are drawn from the production tier "
                  "table (pallas_budget.production_tiers), b_pad is the "
                  "pow2 results-buffer bucket, chunk count is the "
                  "chunked-engine scan length (linear by design). "
+                 "`seg_scan` is the single-history form and "
+                 "`seg_stream_scan` the multi-history stream form. "
+                 "`seg_chunk` (pallas_seg._chunk_jit) is one chunk "
+                 "call, dispatched chunk by chunk by the progress "
+                 "path and the counterexample re-scan. "
                  "`run_sharded` (pallas_seg._sharded_scan_fn) is the "
                  "shard_map form: the SAME per-shard kernel body with "
                  "a leading mesh axis D on every per-shard tensor — "
@@ -498,6 +512,7 @@ def static_inventory() -> Inventory:
                  "donated (ws, stat) carry set, one per (spec, b_pad) "
                  "the run ladder already admits",
             templates=tuple(run_templates)
+            + tuple(chunk_templates)
             + tuple(run_sharded_templates)
             + tuple(reset_templates),
             axes_doc=(chunk, width, rows, table_rows, b_pad, mesh_D,
@@ -699,6 +714,18 @@ def _witness_specs():
             st((1, 128)), st((8, 128)),
             st((spec.table_rows_pad, 128)), 32)
 
+    def chunk_witness():
+        from ..checker import pallas_seg as PS
+
+        spec = PS.spec_for(8, 32, 4, 2)
+        assert spec is not None
+        W = spec.n_words
+        return jax.eval_shape(
+            PS._chunk_jit(spec), st((spec.chunk, 2 + 2 * spec.K)),
+            st((2,)), tuple(st((spec.rows, 128)) for _ in range(W)),
+            st((1, 128)), st((8, 128)),
+            st((spec.table_rows_pad, 128)))
+
     def carry_reset_witness():
         from ..checker import pallas_seg as PS
 
@@ -882,6 +909,8 @@ def _witness_specs():
     return (
         ("pallas-stream-scan",
          "spec_for(8,32,P=4,K=2), 2 chunks, b_pad=8", kernel_witness),
+        ("pallas-stream-scan",
+         "seg_chunk: same spec, one chunk", chunk_witness),
         ("pallas-stream-scan",
          "run_sharded: same spec, D=1 mesh rung",
          kernel_sharded_witness),

@@ -34,6 +34,7 @@ from typing import List, Optional, Set
 import numpy as np
 
 from ..models.memo import MemoizedModel
+from ..obs import trace as _obs
 from ..ops.packed import PackedHistory
 from ..utils import next_pow2 as _next_pow2
 from . import linear_host
@@ -102,12 +103,14 @@ def reconstruct(mm: MemoizedModel, packed: PackedHistory,
     # The device frontier decodes in renamed-slot space; ``owners``
     # (slot -> original process, per segment) maps it back before the
     # host replay, which speaks process-indexed slots.
-    segs = LJ.make_segments(packed)
-    S = segs.ok_proc.shape[0]
-    segs = LJ.make_segments(
-        packed, s_pad=_next_pow2(S, 64),
-        k_pad=_next_pow2(segs.inv_proc.shape[1], 2))
-    segs, P_eff, owners = LJ.remap_slots(segs, with_maps=True)
+    with _obs.span("linear.segments") as sp:
+        segs = LJ.make_segments(packed)
+        S = segs.ok_proc.shape[0]
+        segs = LJ.make_segments(
+            packed, s_pad=_next_pow2(S, 64),
+            k_pad=_next_pow2(segs.inv_proc.shape[1], 2))
+        segs, P_eff, owners = LJ.remap_slots(segs, with_maps=True)
+        sp.set(segments=S, builds=2, p_eff=P_eff)
     Pe = max(P_eff, 1)
     P2 = max(Pe + (Pe & 1), 2)
 

@@ -82,19 +82,18 @@ def analysis(model: Model,
     path runs chunked.
     """
     t0 = _obs.monotonic()
-    packed = (history if isinstance(history, PackedHistory)
-              else pack_history(list(history)))
-    n = len(packed)
-    P = len(packed.process_table)
-    if n == 0 or P == 0:
-        return Analysis(valid=True, info={"backend": "trivial"})
-
-    try:
-        mm = make_memo(model, packed, max_states=max_states)
-    except MemoOverflow as e:
-        return Analysis(valid=UNKNOWN, info={"cause": str(e)})
-    # pack+memo attribution for the offline trace (filetest --trace)
-    _obs.record("linear.pack", t0, _obs.monotonic(), n=n, P=P)
+    with _obs.span("linear.pack") as sp:
+        packed = (history if isinstance(history, PackedHistory)
+                  else pack_history(list(history)))
+        n = len(packed)
+        P = len(packed.process_table)
+        sp.set(n=n, P=P)
+        if n == 0 or P == 0:
+            return Analysis(valid=True, info={"backend": "trivial"})
+        try:
+            mm = make_memo(model, packed, max_states=max_states)
+        except MemoOverflow as e:
+            return Analysis(valid=UNKNOWN, info={"cause": str(e)})
 
     if backend == "host" or (backend == "auto" and n < host_threshold):
         return _analyze_host(mm, packed, max_host_configs, t0)
@@ -152,20 +151,22 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
     succ = jax.device_put(LJ.pad_succ(mm.succ,
                                       _next_pow2(mm.succ.shape[0]),
                                       _next_pow2(mm.succ.shape[1])))
-    segs = LJ.make_segments(packed)
-    s_real = segs.ok_proc.shape[0]
-    segs = LJ.make_segments(
-        packed, s_pad=_next_pow2(s_real, 64),
-        k_pad=_next_pow2(segs.inv_proc.shape[1], 2))
-    # slot renaming: processes map to a minimal pool of reusable
-    # slots, so every engine's slot axis scales with the history's
-    # max CONCURRENT open calls instead of its process count (a
-    # concurrency-10 register history with <=6 calls in flight runs
-    # the fused kernel's fast (8,128)/2-word tier; a 30-process
-    # cluster history with bounded in-flight depth becomes
-    # kernel-eligible at all). Pure relabeling — verdicts and fail
-    # segments are unchanged (see LJ.remap_slots).
-    segs, P_eff = LJ.remap_slots(segs)
+    with _obs.span("linear.segments") as sp:
+        segs = LJ.make_segments(packed)
+        s_real = segs.ok_proc.shape[0]
+        segs = LJ.make_segments(
+            packed, s_pad=_next_pow2(s_real, 64),
+            k_pad=_next_pow2(segs.inv_proc.shape[1], 2))
+        # slot renaming: processes map to a minimal pool of reusable
+        # slots, so every engine's slot axis scales with the history's
+        # max CONCURRENT open calls instead of its process count (a
+        # concurrency-10 register history with <=6 calls in flight
+        # runs the fused kernel's fast (8,128)/2-word tier; a
+        # 30-process cluster history with bounded in-flight depth
+        # becomes kernel-eligible at all). Pure relabeling — verdicts
+        # and fail segments are unchanged (see LJ.remap_slots).
+        segs, P_eff = LJ.remap_slots(segs)
+        sp.set(segments=s_real, builds=2, p_eff=P_eff)
     P = max(P_eff, 1)
     info: dict = {"backend": "device", "n_states": mm.n_states,
                   "n_transitions": mm.n_transitions,
@@ -253,10 +254,12 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
                or segs.ok_proc.shape[0] > CHUNKED_S_THRESHOLD)
     if not chunked:
         for F in capacities:
-            status, fail_seg, n_final = LJ.check_device_seg2(
-                succ, segs.inv_proc, segs.inv_tr, segs.ok_proc,
-                segs.depth, F=F, Fs=Fs, P=P2, **sizes)
-            status = int(status)
+            with _obs.span("linear.escalate", engine="xla-seg2",
+                           capacity=F):
+                status, fail_seg, n_final = LJ.check_device_seg2(
+                    succ, segs.inv_proc, segs.inv_tr, segs.ok_proc,
+                    segs.depth, F=F, Fs=Fs, P=P2, **sizes)
+                status = int(status)
             info["frontier_capacity"] = F
             if status != LJ.UNKNOWN:
                 break
@@ -275,45 +278,47 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
         last = t_run
         done = 0
         visited = 0
-        while done < S:
-            end = min(done + chunk, S)
-            pad = chunk - (end - done)
-            ip = np.pad(segs.inv_proc[done:end],
-                        ((0, pad), (0, 0)), constant_values=-1)
-            it = np.pad(segs.inv_tr[done:end], ((0, pad), (0, 0)))
-            op_ = np.pad(segs.ok_proc[done:end], (0, pad),
-                         constant_values=-1)
-            dp = np.pad(segs.depth[done:end], (0, pad))
-            new_carry = LJ.check_device_seg2_chunk(
-                succ, ip, it, op_, dp, done, carry, F=F, Fs=Fs,
-                P=P2, **sizes)
-            st = int(new_carry[4])
-            if st == LJ.UNKNOWN and cap_ix + 1 < len(capacities):
-                cap_ix += 1
-                F = capacities[cap_ix]
-                carry = LJ.expand_seg_carry(carry, F)
-                continue            # same chunk, wider frontier
-            carry = new_carry
-            visited += int(carry[3]) * (end - done)
-            done = end
-            if st != LJ.VALID:
-                break
-            now = _obs.monotonic()
-            if progress is not None and \
-                    now - last >= progress_interval_s:
-                # pending counts from the carry: telemetry parity
-                # with the reference's visited/s + estimated-cost
-                # reporters (core.clj:442-460, config.clj:374-393).
-                # Bucketed on device so only P+1 ints are read
-                # back per tick, never the (F, P) frontier
-                hist = np.asarray(LJ.pending_histogram(
-                    carry[1], carry[2], P=P2))
-                el = max(now - t_run, 1e-9)
-                progress(min(done, s_real), s_real, int(carry[3]),
-                         {"visited_per_s": visited / el,
-                          "segs_per_s": done / el,
-                          "est_cost": LJ.estimated_cost_hist(hist)})
-                last = now
+        with _obs.span("linear.escalate", engine="xla-seg2") as sp:
+            while done < S:
+                end = min(done + chunk, S)
+                pad = chunk - (end - done)
+                ip = np.pad(segs.inv_proc[done:end],
+                            ((0, pad), (0, 0)), constant_values=-1)
+                it = np.pad(segs.inv_tr[done:end], ((0, pad), (0, 0)))
+                op_ = np.pad(segs.ok_proc[done:end], (0, pad),
+                             constant_values=-1)
+                dp = np.pad(segs.depth[done:end], (0, pad))
+                new_carry = LJ.check_device_seg2_chunk(
+                    succ, ip, it, op_, dp, done, carry, F=F, Fs=Fs,
+                    P=P2, **sizes)
+                st = int(new_carry[4])
+                if st == LJ.UNKNOWN and cap_ix + 1 < len(capacities):
+                    cap_ix += 1
+                    F = capacities[cap_ix]
+                    carry = LJ.expand_seg_carry(carry, F)
+                    continue            # same chunk, wider frontier
+                carry = new_carry
+                visited += int(carry[3]) * (end - done)
+                done = end
+                if st != LJ.VALID:
+                    break
+                now = _obs.monotonic()
+                if progress is not None and \
+                        now - last >= progress_interval_s:
+                    # pending counts from the carry: telemetry parity
+                    # with the reference's visited/s + estimated-cost
+                    # reporters (core.clj:442-460, config.clj:374-393).
+                    # Bucketed on device so only P+1 ints are read
+                    # back per tick, never the (F, P) frontier
+                    hist = np.asarray(LJ.pending_histogram(
+                        carry[1], carry[2], P=P2))
+                    el = max(now - t_run, 1e-9)
+                    progress(min(done, s_real), s_real, int(carry[3]),
+                             {"visited_per_s": visited / el,
+                              "segs_per_s": done / el,
+                              "est_cost": LJ.estimated_cost_hist(hist)})
+                    last = now
+            sp.set(capacity=F)
         status, fail_seg, n_final = (int(carry[4]), carry[5],
                                      carry[3])
         info["frontier_capacity"] = F
@@ -331,7 +336,6 @@ def _note_tried(info: dict, engine: str, capacity) -> None:
         {"engine": engine, "frontier_capacity": capacity})
 
 
-@_obs.traced("linear.mxu")
 def _analyze_mxu(mm: MemoizedModel, packed: PackedHistory, segs, succ,
                  P: int, t0: float, info: dict,
                  capacities: Sequence[int] = None, progress=None,
@@ -369,10 +373,12 @@ def _analyze_mxu(mm: MemoizedModel, packed: PackedHistory, segs, succ,
     chunked = (progress is not None or S > CHUNKED_S_THRESHOLD)
     if not chunked:
         for F in ladder:
-            status, fail_seg, n_final = MXU.check_device_mxu(
-                succ, segs.inv_proc, segs.inv_tr, segs.ok_proc,
-                segs.depth, F=F, P=P, **sizes)
-            status = int(status)
+            with _obs.span("linear.escalate", engine="mxu-frontier",
+                           capacity=F):
+                status, fail_seg, n_final = MXU.check_device_mxu(
+                    succ, segs.inv_proc, segs.inv_tr, segs.ok_proc,
+                    segs.depth, F=F, P=P, **sizes)
+                status = int(status)
             info["frontier_capacity"] = F
             if status != LJ.UNKNOWN:
                 break
@@ -385,41 +391,44 @@ def _analyze_mxu(mm: MemoizedModel, packed: PackedHistory, segs, succ,
         last = t_run
         done = 0
         visited = 0
-        while done < S:
-            end = min(done + chunk, S)
-            pad = chunk - (end - done)
-            ip = np.pad(segs.inv_proc[done:end],
-                        ((0, pad), (0, 0)), constant_values=-1)
-            it = np.pad(segs.inv_tr[done:end], ((0, pad), (0, 0)))
-            op_ = np.pad(segs.ok_proc[done:end], (0, pad),
-                         constant_values=-1)
-            dp = np.pad(segs.depth[done:end], (0, pad))
-            new_carry = MXU.check_device_mxu_chunk(
-                succ, ip, it, op_, dp, done, carry, F=F, P=P, **sizes)
-            st = int(new_carry[3][0])
-            if st == LJ.UNKNOWN and cap_ix + 1 < len(ladder):
-                cap_ix += 1
-                F = ladder[cap_ix]
-                carry = MXU.expand_carry(carry, F)
-                continue            # same chunk, wider frontier
-            carry = new_carry
-            visited += int(carry[2][0]) * (end - done)
-            done = end
-            if st != LJ.VALID:
-                break
-            now = _obs.monotonic()
-            if progress is not None and \
-                    now - last >= progress_interval_s:
-                hist = np.asarray(MXU.pending_histogram(
-                    carry[0], carry[1], P=P, **sizes))
-                el = max(now - t_run, 1e-9)
-                # report against the REAL segment count like the XLA
-                # arm — S here is the pow2-padded axis
-                progress(min(done, s_real), s_real, int(carry[2][0]),
-                         {"visited_per_s": visited / el,
-                          "segs_per_s": done / el,
-                          "est_cost": LJ.estimated_cost_hist(hist)})
-                last = now
+        with _obs.span("linear.escalate",
+                       engine="mxu-frontier") as sp:
+            while done < S:
+                end = min(done + chunk, S)
+                pad = chunk - (end - done)
+                ip = np.pad(segs.inv_proc[done:end],
+                            ((0, pad), (0, 0)), constant_values=-1)
+                it = np.pad(segs.inv_tr[done:end], ((0, pad), (0, 0)))
+                op_ = np.pad(segs.ok_proc[done:end], (0, pad),
+                             constant_values=-1)
+                dp = np.pad(segs.depth[done:end], (0, pad))
+                new_carry = MXU.check_device_mxu_chunk(
+                    succ, ip, it, op_, dp, done, carry, F=F, P=P, **sizes)
+                st = int(new_carry[3][0])
+                if st == LJ.UNKNOWN and cap_ix + 1 < len(ladder):
+                    cap_ix += 1
+                    F = ladder[cap_ix]
+                    carry = MXU.expand_carry(carry, F)
+                    continue            # same chunk, wider frontier
+                carry = new_carry
+                visited += int(carry[2][0]) * (end - done)
+                done = end
+                if st != LJ.VALID:
+                    break
+                now = _obs.monotonic()
+                if progress is not None and \
+                        now - last >= progress_interval_s:
+                    hist = np.asarray(MXU.pending_histogram(
+                        carry[0], carry[1], P=P, **sizes))
+                    el = max(now - t_run, 1e-9)
+                    # report against the REAL segment count like the XLA
+                    # arm — S here is the pow2-padded axis
+                    progress(min(done, s_real), s_real, int(carry[2][0]),
+                             {"visited_per_s": visited / el,
+                              "segs_per_s": done / el,
+                              "est_cost": LJ.estimated_cost_hist(hist)})
+                    last = now
+            sp.set(capacity=F)
         status, fail_seg, n_final = (int(carry[3][0]), carry[4][0],
                                      carry[2][0])
         info["frontier_capacity"] = F
@@ -464,9 +473,10 @@ def _device_verdict(mm, packed, segs, status, fail_seg, n_final,
         # rather than compiling a frontier width the XLA engine never
         # otherwise sees (compile time scales with F; a re-scan
         # overflow at the clamp degrades to an undecorated INVALID)
-        ce = CE.reconstruct(mm, packed,
-                            F=max(256, min(info.get(
-                                "frontier_capacity", 256), 65536)))
+        with _obs.span("linear.counterexample"):
+            ce = CE.reconstruct(mm, packed,
+                                F=max(256, min(info.get(
+                                    "frontier_capacity", 256), 65536)))
         if ce is not None:
             cfgs = ce.configs
             op_index = ce.op_index

@@ -828,6 +828,7 @@ def _chunk_call(spec: SegKernelSpec, b_pad: int = 8):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
+            name="seg_frontier",
             out_shape=[word_shape] * W + [
                 jax.ShapeDtypeStruct((1, LANES), jnp.int32),
                 jax.ShapeDtypeStruct((b_pad, LANES), jnp.int32)],
@@ -900,6 +901,9 @@ def _scan_fn(spec: SegKernelSpec, b_pad: int = 8,
             step, (tuple(ws0), stat0, res0), (seg_chunks, offs))
         return ws, stat, res
 
+    # the jit's name names the program in the compile log and in the
+    # profiler's "XLA Modules" line (jit_seg_scan)
+    run.__name__ = "seg_stream_scan" if stream else "seg_scan"
     if donate:
         return jax.jit(run, donate_argnums=(1, 2, 3))
     return jax.jit(run)
@@ -916,18 +920,26 @@ def check_device_pallas(succ: np.ndarray, segs, *, n_states: int,
         return None
     spec, seg_chunks, ws0, stat0, table = prep
     run = _scan_fn(spec)
-    res0 = jnp.zeros((8, LANES), jnp.int32)      # unused: no RESETs
-    _, stat, _ = run(jnp.asarray(seg_chunks), tuple(ws0), stat0,
-                     res0, table, n_transitions)
-    stat = np.asarray(stat)
+    with _obs.span("pallas.scan", dispatches=1, readbacks=1):
+        res0 = jnp.zeros((8, LANES), jnp.int32)  # unused: no RESETs
+        _, stat, _ = run(jnp.asarray(seg_chunks), tuple(ws0), stat0,
+                         res0, table, n_transitions)
+        stat = np.asarray(stat)
     return int(stat[0, 0]), int(stat[0, 1]), int(stat[0, 2])
 
 
 @functools.lru_cache(maxsize=32)
 def _chunk_jit(spec: SegKernelSpec):
+    """One chunk call as its own program, named ``seg_chunk`` in the
+    compile log and the profiler's trace."""
     import jax
 
-    return jax.jit(_chunk_call(spec))
+    call = _chunk_call(spec)
+
+    def seg_chunk(seg, off, ws, stat, res, table):
+        return call(seg, off, ws, stat, res, table)
+
+    return jax.jit(seg_chunk)
 
 
 def pack_stream(segs_list, spec: SegKernelSpec):
@@ -1270,6 +1282,7 @@ def stream_dispatch_sharded(succ, segs_list, spec, n_states,
     return out_res, starts
 
 
+@_obs.traced("pallas.prepare")
 def _prepare(succ, segs, n_states, n_transitions, P):
     """Shared entry-point setup: spec gate, chunked segment stream,
     initial frontier + stat row (status/fail/n in lanes 0..2 — must
@@ -1317,34 +1330,44 @@ def check_device_pallas_chunked(succ: np.ndarray, segs, *,
     last = t_run
     prev_ws, done = ws, 0
     visited = 0
-    for c in range(seg_chunks.shape[0]):
-        off = np.array([c * spec.chunk, n_transitions], np.int32)
-        ws, stat, res = call(jnp.asarray(seg_chunks[c]),
-                             jnp.asarray(off), ws, stat, res, table)
-        st = np.asarray(stat)
-        visited += int(st[0, 2]) * spec.chunk
-        if int(st[0, 0]) != VALID:
-            break
-        prev_ws, done = ws, (c + 1) * spec.chunk
-        now = _obs.monotonic()
-        if progress is not None and now - last >= progress_interval_s:
-            from .linear_jax import estimated_cost
+    # dispatches and device->host reads: one of each per chunk, one
+    # read per frontier decoded (progress, boundary)
+    with _obs.span("pallas.scan") as sp:
+        n_calls = n_reads = 0
+        for c in range(seg_chunks.shape[0]):
+            off = np.array([c * spec.chunk, n_transitions], np.int32)
+            ws, stat, res = call(jnp.asarray(seg_chunks[c]),
+                                 jnp.asarray(off), ws, stat, res, table)
+            st = np.asarray(stat)
+            n_calls += 1
+            n_reads += 1
+            visited += int(st[0, 2]) * spec.chunk
+            if int(st[0, 0]) != VALID:
+                break
+            prev_ws, done = ws, (c + 1) * spec.chunk
+            now = _obs.monotonic()
+            if progress is not None and \
+                    now - last >= progress_interval_s:
+                from .linear_jax import estimated_cost
 
-            cfgs = decode_frontier(
-                spec, [np.asarray(w) for w in ws], spec.P)
-            pend = [sum(1 for t in sl if t >= 0) for _, sl in cfgs]
-            el = max(now - t_run, 1e-9)
-            progress(min((c + 1) * spec.chunk, s_real), s_real,
-                     int(st[0, 2]),
-                     {"visited_per_s": visited / el,
-                      "segs_per_s": done / el,
-                      "est_cost": estimated_cost(pend)})
-            last = now
-    st = np.asarray(stat)
-    out = (int(st[0, 0]), int(st[0, 1]), int(st[0, 2]))
-    if return_boundary:
-        return out + (([np.asarray(w) for w in prev_ws],
-                       min(done, s_real)),)
+                cfgs = decode_frontier(
+                    spec, [np.asarray(w) for w in ws], spec.P)
+                n_reads += 1
+                pend = [sum(1 for t in sl if t >= 0) for _, sl in cfgs]
+                el = max(now - t_run, 1e-9)
+                progress(min((c + 1) * spec.chunk, s_real), s_real,
+                         int(st[0, 2]),
+                         {"visited_per_s": visited / el,
+                          "segs_per_s": done / el,
+                          "est_cost": estimated_cost(pend)})
+                last = now
+        st = np.asarray(stat)      # the last chunk's read, cached
+        out = (int(st[0, 0]), int(st[0, 1]), int(st[0, 2]))
+        if return_boundary:
+            out += (([np.asarray(w) for w in prev_ws],
+                     min(done, s_real)),)
+            n_reads += 1
+        sp.set(dispatches=n_calls, readbacks=n_reads)
     return out
 
 

@@ -25,6 +25,13 @@ measured wall time.
 Export (:func:`export_chrome`) is the Chrome trace-event JSON format
 (``{"traceEvents": [{"ph": "X", "ts": µs, "dur": µs, ...}]}``) —
 loadable in Perfetto / ``chrome://tracing`` unmodified.
+
+Profiler sink: ``enable(annotate=jax.profiler.TraceAnnotation)`` makes
+every span entered also enter ``annotate(span.name)`` until it exits,
+so the span lands on the profiler's host plane, on the clock of the
+device ops. The caller injects the sink; this module never imports
+it. Only entered spans are annotated: :func:`record` is retroactive
+and never reaches the profiler.
 """
 
 from __future__ import annotations
@@ -49,6 +56,8 @@ monotonic = _time.monotonic
 DEFAULT_MAX_SPANS = 200_000
 
 _ENABLED = False
+#: the profiler sink ``name -> context manager`` set by :func:`enable`
+_ANNOTATE = None
 _spans: deque = deque(maxlen=DEFAULT_MAX_SPANS)
 _dropped = 0
 
@@ -61,7 +70,8 @@ class Span:
     """One named monotonic-clock interval (see module docstring).
     Context manager; finished spans land in the module buffer."""
 
-    __slots__ = ("name", "t0", "t1", "rid", "args", "parent", "_token")
+    __slots__ = ("name", "t0", "t1", "rid", "args", "parent", "_token",
+                 "_ann")
 
     def __init__(self, name: str, args: Optional[dict] = None,
                  rid=None):
@@ -72,6 +82,7 @@ class Span:
         self.t0 = monotonic()
         self.t1: Optional[float] = None
         self._token = None
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes after the fact (byte counts etc.)."""
@@ -80,9 +91,15 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _parent_var.set(self)
+        if _ANNOTATE is not None:
+            self._ann = _ANNOTATE(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         if self._token is not None:
             _parent_var.reset(self._token)
             self._token = None
@@ -168,17 +185,22 @@ def request(rid):
 # -- lifecycle ---------------------------------------------------------
 
 
-def enable(max_spans: int = DEFAULT_MAX_SPANS) -> None:
-    global _ENABLED, _spans, _dropped
+def enable(max_spans: int = DEFAULT_MAX_SPANS, annotate=None) -> None:
+    """Turn tracing on. ``annotate`` is the optional profiler sink
+    (module docstring): a callable ``name -> context manager`` that
+    every span enters for its lifetime."""
+    global _ENABLED, _ANNOTATE, _spans, _dropped
     if _spans.maxlen != max_spans:
         _spans = deque(_spans, maxlen=max_spans)
     _dropped = 0
+    _ANNOTATE = annotate
     _ENABLED = True
 
 
 def disable() -> None:
-    global _ENABLED
+    global _ENABLED, _ANNOTATE
     _ENABLED = False
+    _ANNOTATE = None
 
 
 def enabled() -> bool:

@@ -60,6 +60,8 @@ def test_phases_hold_on_cpu_mesh(interpret, capsys):
     assert served["replies"]["txn-g2"]["anomaly_class"] == "G2-item"
     assert multi["bit_identical"] is True
     assert sorted(multi["histories_per_device"].values()) == [16] * 4
+    for line in (batch, served, multi):
+        assert line["compile_offenders"] == [], line["phase"]
 
 
 def test_failed_check_raises_and_is_reported(interpret, capsys,

@@ -45,7 +45,8 @@ def test_programs_artifact_matches_committed():
 
 def test_inventory_covers_every_engine_surface():
     inv = CS.static_inventory()
-    for name in ("run", "check_device_keys", "check_device_flat",
+    for name in ("seg_scan", "seg_stream_scan", "seg_chunk",
+                 "check_device_keys", "check_device_flat",
                  "check_device_seg_batch", "check_device_batch",
                  "check_device_seg2", "closure_diag_kernel"):
         assert inv.site_for(name) is not None, name

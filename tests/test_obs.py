@@ -5,6 +5,7 @@ attribution tiling the measured wall, the timeline SVG, filetest
 --trace, and the daemon wire round-trip (scrape + shutdown trace
 artifact)."""
 
+import contextlib
 import json
 import os
 import random
@@ -145,6 +146,46 @@ def test_disabled_mode_is_a_noop():
     trace.record("r", 0.0, 1.0)
     assert trace.spans() == []
     assert not trace.enabled()
+
+
+def test_annotate_sink_follows_span_nesting(tracing):
+    """The profiler sink is entered and exited once per span, with the
+    span's name, in nesting order; never from record() and never once
+    tracing is off."""
+    log = []
+
+    @contextlib.contextmanager
+    def annotate(name):
+        log.append(("enter", name))
+        yield
+        log.append(("exit", name))
+
+    @trace.traced("deco")
+    def work():
+        with trace.span("leaf"):
+            pass
+
+    trace.enable(annotate=annotate)
+    with trace.span("outer", k=1):
+        with trace.span("inner"):
+            pass
+        work()
+    trace.record("retro", 0.0, 1.0)
+    assert log == [("enter", "outer"), ("enter", "inner"),
+                   ("exit", "inner"), ("enter", "deco"),
+                   ("enter", "leaf"), ("exit", "leaf"),
+                   ("exit", "deco"), ("exit", "outer")]
+    assert {s.name for s in trace.spans()} == {
+        "outer", "inner", "deco", "leaf", "retro"}
+
+    trace.disable()
+    with trace.span("off"):
+        work()
+    trace.record("retro-off", 0.0, 1.0)
+    trace.enable()                        # no sink: spans only
+    with trace.span("plain"):
+        pass
+    assert len(log) == 8
 
 
 def test_span_buffer_is_bounded(tracing):
