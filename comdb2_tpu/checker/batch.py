@@ -101,23 +101,13 @@ def _segments_of(p, s_pad: int = 0, k_pad: int = 0):
     """``make_segments`` with a fast path: an exact stream cached on
     the PackedHistory (the serving layer's admission pass computes one
     per request to derive the shape bucket) is padded to the floors
-    with cheap numpy pads instead of re-running the O(total-ops) host
-    loop. Pad values match ``make_segments``' (dead segments/invokes
-    are ``-1`` procs)."""
+    (``pad_segments``) instead of re-running the O(total-ops) host
+    loop."""
     segs = getattr(p, "_segments_exact", None)
     if segs is None:
         return LJ.make_segments(p, s_pad=s_pad or None,
                                 k_pad=k_pad or None)
-    S, K = segs.ok_proc.shape[0], segs.inv_proc.shape[1]
-    ds, dk = max(s_pad - S, 0), max(k_pad - K, 0)
-    if not ds and not dk:
-        return segs
-    return LJ.SegmentStream(
-        np.pad(segs.inv_proc, ((0, ds), (0, dk)), constant_values=-1),
-        np.pad(segs.inv_tr, ((0, ds), (0, dk))),
-        np.pad(segs.ok_proc, (0, ds), constant_values=-1),
-        np.pad(segs.seg_index, (0, ds)),
-        np.pad(segs.depth, (0, ds)))
+    return LJ.pad_segments(segs, s_pad, k_pad)
 
 
 @_obs.traced("batch.pack")

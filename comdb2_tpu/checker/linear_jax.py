@@ -479,6 +479,25 @@ def make_segments(packed, s_pad: Optional[int] = None,
     return SegmentStream(inv_proc, inv_tr, ok_proc, seg_index, depth)
 
 
+def pad_segments(segs: SegmentStream, s_pad: int,
+                 k_pad: int) -> SegmentStream:
+    """Pad a built stream to ``(s_pad, k_pad)`` with the fill values of
+    :func:`make_segments`' own pads (-1 for ``inv_proc``/``ok_proc``,
+    0 elsewhere), so ``pad_segments(make_segments(p), s, k)`` equals
+    ``make_segments(p, s, k)`` without building twice. Pads are floors,
+    as there."""
+    S, K = segs.inv_proc.shape
+    ds, dk = max(s_pad - S, 0), max(k_pad - K, 0)
+    if not (ds or dk):
+        return segs
+    return SegmentStream(
+        np.pad(segs.inv_proc, ((0, ds), (0, dk)), constant_values=-1),
+        np.pad(segs.inv_tr, ((0, ds), (0, dk))),
+        np.pad(segs.ok_proc, (0, ds), constant_values=-1),
+        np.pad(segs.seg_index, (0, ds)),
+        np.pad(segs.depth, (0, ds)))
+
+
 def make_segments_legacy(packed, s_pad: Optional[int] = None,
                          k_pad: Optional[int] = None) -> SegmentStream:
     """The original per-op segment walk (see :func:`make_segments`)."""
@@ -558,7 +577,9 @@ def remap_slots(segs: SegmentStream, with_maps: bool = False):
     releases happen after the segment's ok — so a slot freed by segment
     s is reusable from segment s+1 on. :info invokes never complete and
     hold their slot for the rest of the stream (process retirement —
-    the retired id never invokes again, ``core.clj:178-200``).
+    the retired id never invokes again, ``core.clj:178-200``). One
+    Python pass visits the real cells only, never the padding; the free
+    slots are an int bitmask (Python ints are unbounded, so any P).
 
     Returns ``(segs', P_eff)``, plus ``proc_of_slot`` (int32[S, P_eff];
     row s = which ORIGINAL process owns each slot after segment s, -1
@@ -566,41 +587,38 @@ def remap_slots(segs: SegmentStream, with_maps: bool = False):
     device frontier back into process-indexed configs
     (:func:`comdb2_tpu.checker.counterexample.reconstruct`).
     """
-    import heapq
-
     S, K = segs.inv_proc.shape
-    ip = segs.inv_proc.tolist()
-    okl = segs.ok_proc.tolist()
-    out_ip = [row[:] for row in ip]
-    out_ok = list(okl)
+    # every real cell in allocation order — segment by segment, its
+    # invokes in k order, then its ok — is row-major order over the
+    # invokes with each ok appended as column K
+    ext = np.empty((S, K + 1), np.int64)
+    ext[:, :K] = segs.inv_proc
+    ext[:, K] = ~segs.ok_proc.astype(np.int64)     # oks as ~p < 0
+    real = np.empty((S, K + 1), bool)
+    real[:, :K] = segs.inv_proc >= 0
+    real[:, K] = segs.ok_proc >= 0
+    at = np.flatnonzero(real)
+    seg = at // (K + 1)
     slot_of: dict = {}
-    free: list = []
+    free = 0          # bitmask of free slots; lowest-free is free & -free
     n_slots = 0
-    maps = [] if with_maps else None
-    owners: list = []
-    for s in range(S):
-        row = ip[s]
-        orow = out_ip[s]
-        for k in range(K):
-            p = row[k]
-            if p < 0:
-                continue
+    out: list = []
+    for p in ext.reshape(-1)[at].tolist():
+        if p >= 0:
             if p in slot_of:
                 raise ValueError(
-                    f"process {p} invokes in segment {s} while an "
-                    "earlier invocation is still open")
+                    f"process {p} invokes in segment {int(seg[len(out)])} "
+                    "while an earlier invocation is still open")
             if free:
-                sl = heapq.heappop(free)
+                low = free & -free
+                free ^= low
+                sl = low.bit_length() - 1
             else:
                 sl = n_slots
                 n_slots += 1
-                owners.append(-1)
             slot_of[p] = sl
-            owners[sl] = p
-            orow[k] = sl
-        o = okl[s]
-        if o >= 0:
-            sl = slot_of.pop(o, None)
+        else:
+            sl = slot_of.pop(~p, None)
             if sl is None:
                 # ok without an open invocation: the process's slot is
                 # IDLE in every config, so the ok filter empties the
@@ -608,30 +626,41 @@ def remap_slots(segs: SegmentStream, with_maps: bool = False):
                 # IDLE everywhere too — map to one to preserve exactly
                 # that instead of rejecting the stream.
                 if free:
-                    out_ok[s] = free[0]
+                    sl = (free & -free).bit_length() - 1
                 else:
-                    out_ok[s] = n_slots
+                    sl = n_slots
                     n_slots += 1
-                    owners.append(-1)
-                    heapq.heappush(free, out_ok[s])
+                    free |= 1 << sl
             else:
-                out_ok[s] = sl
-                owners[sl] = -1
-                heapq.heappush(free, sl)
-        if with_maps:
-            maps.append(owners[:])
+                free |= 1 << sl
+        out.append(sl)
+    slots = np.asarray(out, np.int32)
+    is_ok = at % (K + 1) == K
+    inv = ~is_ok
+    out_ip = np.array(segs.inv_proc, np.int32, order="C")
+    out_ip.reshape(-1)[at[inv] - seg[inv]] = slots[inv]
+    out_ok = np.array(segs.ok_proc, np.int32)
+    out_ok[seg[is_ok]] = slots[is_ok]
     P_eff = n_slots
-    segs2 = SegmentStream(
-        np.asarray(out_ip, np.int32).reshape(S, K),
-        segs.inv_tr, np.asarray(out_ok, np.int32),
-        segs.seg_index, segs.depth)
-    if with_maps:
-        pos = np.full((S, max(P_eff, 1)), -1, np.int32)
-        for s, row in enumerate(maps):
-            if row:
-                pos[s, :len(row)] = row
-        return segs2, P_eff, pos
-    return segs2, P_eff
+    segs2 = SegmentStream(out_ip, segs.inv_tr, out_ok,
+                          segs.seg_index, segs.depth)
+    if not with_maps:
+        return segs2, P_eff
+    # owner of each slot after each segment: an invoke sets its owner
+    # at its segment, an ok clears it (written second, so an ok after an
+    # invoke in the same segment wins; an unmatched ok's slot is free,
+    # so clearing it changes nothing), then each slot's column is
+    # forward-filled from its last change
+    W = max(P_eff, 1)
+    changed = np.zeros((S, W), bool)
+    owner = np.full((S, W), -1, np.int32)
+    changed[seg, slots] = True
+    owner[seg[inv], slots[inv]] = ext.reshape(-1)[at[inv]]
+    owner[seg[is_ok], slots[is_ok]] = -1
+    last = np.where(changed, np.arange(S)[:, None], -1)
+    np.maximum.accumulate(last, axis=0, out=last)
+    pos = np.where(last >= 0, owner[np.maximum(last, 0), np.arange(W)], -1)
+    return segs2, P_eff, pos
 
 
 def remap_slots_batch(streams):
