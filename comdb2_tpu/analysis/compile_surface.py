@@ -223,7 +223,12 @@ def _ladders() -> dict:
         "mesh_D": (1, MAX_SHARDS),
         "kernel_chunks": tuple(sorted({s.chunk for s in specs})),
         "kernel_widths": tuple(sorted({2 + 2 * s.K for s in specs})),
-        "kernel_rows": tuple(sorted({s.rows for s in specs})),
+        # key-word carry rows: the (8,128)/(16,128) rung-0 buffer
+        # plus the rows a wide-rung frontier crosses chunk calls in
+        "kernel_rows": tuple(sorted({pallas_seg.carry_rows(s)
+                                     for s in specs})),
+        "kernel_rungs": tuple(pallas_seg.F << r for r in
+                              range(pallas_seg.WIDE_RUNGS + 1)),
         "kernel_table_rows": tuple(sorted({s.table_rows_pad
                                            for s in specs})),
         "kernel_words": tuple(sorted({s.n_words for s in specs})),
@@ -710,7 +715,7 @@ def _witness_specs():
         W = spec.n_words
         return jax.eval_shape(
             run, st((2, spec.chunk, 2 + 2 * spec.K)),
-            tuple(st((spec.rows, 128)) for _ in range(W)),
+            tuple(st((PS.carry_rows(spec), 128)) for _ in range(W)),
             st((1, 128)), st((8, 128)),
             st((spec.table_rows_pad, 128)), 32)
 
@@ -722,7 +727,8 @@ def _witness_specs():
         W = spec.n_words
         return jax.eval_shape(
             PS._chunk_jit(spec), st((spec.chunk, 2 + 2 * spec.K)),
-            st((2,)), tuple(st((spec.rows, 128)) for _ in range(W)),
+            st((2,)),
+            tuple(st((PS.carry_rows(spec), 128)) for _ in range(W)),
             st((1, 128)), st((8, 128)),
             st((spec.table_rows_pad, 128)))
 
@@ -734,7 +740,8 @@ def _witness_specs():
         reset = PS._reset_fn(spec, 8)
         W = spec.n_words
         return jax.eval_shape(
-            reset, tuple(st((spec.rows, 128)) for _ in range(W)),
+            reset,
+            tuple(st((PS.carry_rows(spec), 128)) for _ in range(W)),
             st((1, 128)))
 
     def keys_witness():
@@ -814,7 +821,8 @@ def _witness_specs():
         dspec = PS.delta_spec(spec, 16)
         fn = SE.stream_kernel_megabatch(dspec, 2)
         W = spec.n_words
-        lane = (tuple(st((spec.rows, 128)) for _ in range(W)),
+        lane = (tuple(st((PS.carry_rows(spec), 128))
+                      for _ in range(W)),
                 st((1, 128)), st((8, 128)),
                 st((spec.table_rows_pad, 128)))
         return jax.eval_shape(
@@ -887,7 +895,8 @@ def _witness_specs():
         W = spec.n_words
         return jax.eval_shape(
             run, st((1, 2, spec.chunk, 2 + 2 * spec.K)),
-            tuple(st((1, spec.rows, 128)) for _ in range(W)),
+            tuple(st((1, PS.carry_rows(spec), 128))
+                  for _ in range(W)),
             st((1, 1, 128)), st((1, 8, 128)),
             st((spec.table_rows_pad, 128)), 32)
 
@@ -1066,8 +1075,12 @@ def render_programs() -> str:
         " | `spec_for` SMEM bound per K |",
         f"| kernel widths (2+2K) | {list(L['kernel_widths'])} | "
         "K = 1..8 |",
-        f"| kernel buffer rows | {list(L['kernel_rows'])} | "
-        "(8,128)/(16,128) tiers |",
+        f"| kernel carry rows | {list(L['kernel_rows'])} | "
+        "(8,128)/(16,128) tiers + `pallas_seg.WIDE_ROWS` wide-rung "
+        "rows |",
+        f"| kernel frontier rungs | {list(L['kernel_rungs'])} | "
+        "per segment inside `seg_frontier`; runtime sizes, no "
+        "program axis |",
         f"| kernel table rows | {list(L['kernel_table_rows'])} | "
         "`table_rows_pad` buckets |",
         f"| mxu table caps | pow2 1..{L['mxu_table'][0]} x pow2 1.."

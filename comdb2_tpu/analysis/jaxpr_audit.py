@@ -234,6 +234,8 @@ def trace_entry_points(
     import jax
     import numpy as np
 
+    from ..checker import pallas_seg as PS
+
     out: List[Finding] = []
     for mod_name, attr, P in TRACE_ENTRY_POINTS:
         mod = importlib.import_module(mod_name)
@@ -245,7 +247,10 @@ def trace_entry_points(
                     np.zeros((S_PAD, K_PAD), np.int32),    # inv_tr
                     np.zeros(S_PAD, np.int32),             # ok_proc
                     np.zeros(S_PAD, np.int32))             # depth
-            kw = dict(F=128, P=P, n_states=ns, n_transitions=nt)
+            # the kernel's rung-0 frontier: the capacity at which the
+            # seg engines are compared with the fused kernel segment
+            # by segment (its wide rungs run past it inside the kernel)
+            kw = dict(F=PS.F, P=P, n_states=ns, n_transitions=nt)
             try:
                 jaxpr = jax.make_jaxpr(
                     lambda *a: fn(*a, **kw))(*args)

@@ -10,8 +10,10 @@ capacity" lands in a fuzz loop):
   production CHUNK stays at 1024;
 - grid-step blocks need (sublane, lane) dims that divide or are
   multiples of (8, 128), or equal the array dims;
-- the fused kernel caps K (invokes per segment) at 8 and fixes the
-  frontier capacity F at 128 (one vreg row).
+- the fused kernel caps K (invokes per segment) at 8 and fixes its
+  rung-0 frontier capacity F at 128 (one vreg row); its wide rungs
+  double F up to a top rung of F_TOP_CAP configs, whose sort buffer,
+  wide frontier and carries must fit the scoped VMEM budget.
 
 Two layers:
 
@@ -48,6 +50,13 @@ SMEM_SAFETY = 0.7
 MAX_GRID_STEPS = 1408
 K_CAP = 8
 F_CAP = 128
+#: the top frontier rung: the first power of two past the largest
+#: closure (2,264 configs) measured on the Jepsen register test's
+#: concurrency-10 keys (256 keys x 2,000 ops)
+F_TOP_CAP = 4096
+#: v5e's default scoped VMEM limit (bytes) a kernel's scratch and
+#: double-buffered blocks must fit without raising it
+VMEM_BUDGET_BYTES = 16 << 20
 
 #: the production shape-bucket ladder (mirrors
 #: scripts/fuzz_pallas_seg.py; jaxpr_audit cross-checks the mirror)
@@ -64,6 +73,8 @@ def check_config(*, grid_steps: Optional[int] = None,
                  prefetch_int32: Optional[int] = None,
                  block: Optional[Tuple[int, int]] = None,
                  K: Optional[int] = None, F: Optional[int] = None,
+                 f_top: Optional[int] = None,
+                 vmem_bytes: Optional[int] = None,
                  where: str = "<config>", path: str = "<config>",
                  line: int = 0) -> List[Finding]:
     """Budget-check one kernel configuration; any field may be left
@@ -114,7 +125,36 @@ def check_config(*, grid_steps: Optional[int] = None,
             "pallas-f-cap", path, line,
             f"{where}: kernel frontier capacity must be F={F_CAP} "
             f"(one vreg row), got {F}"))
+    if f_top is not None and (f_top > F_TOP_CAP or f_top < F_CAP
+                              or f_top % F_CAP
+                              or (f_top // F_CAP) & (f_top // F_CAP - 1)):
+        out.append(Finding(
+            "pallas-f-cap", path, line,
+            f"{where}: the top frontier rung must double F={F_CAP} a "
+            f"whole number of times up to at most {F_TOP_CAP}, got "
+            f"{f_top}"))
+    if vmem_bytes is not None and vmem_bytes > VMEM_BUDGET_BYTES:
+        out.append(Finding(
+            "pallas-vmem-budget", path, line,
+            f"{where}: {vmem_bytes} B of VMEM scratch and blocks "
+            f"exceed the {VMEM_BUDGET_BYTES >> 20} MiB scoped budget"))
     return out
+
+
+def kernel_vmem_bytes(spec, b_pad: Optional[int] = None) -> int:
+    """VMEM the fused kernel asks for at ``spec``: its scratch (rung-0
+    buffer, wide frontier and its entry copy, the top rung's sort
+    buffer) plus its blocks, double-buffered (key-word carries in and
+    out, stat rows, results of ``b_pad`` histories, the table)."""
+    PS = _pallas_seg()
+    row = PS.LANES * 4
+    b_pad = PS.MAX_STREAM_B if b_pad is None else b_pad
+    scratch = spec.n_words * row * (
+        spec.rows + 2 * PS.WIDE_ROWS
+        + PS.rung_blocks(spec.P, PS.WIDE_RUNGS) * PS.ROWS)
+    blocks = row * (2 * spec.n_words * PS.carry_rows(spec)
+                    + 2 + 2 * b_pad + spec.table_rows_pad)
+    return scratch + 2 * blocks
 
 
 def check_spec(spec, *, where: str = "spec") -> List[Finding]:
@@ -125,7 +165,8 @@ def check_spec(spec, *, where: str = "spec") -> List[Finding]:
     width = 2 + 2 * spec.K
     out = check_config(
         grid_steps=spec.chunk, prefetch_int32=spec.chunk * width,
-        block=(spec.rows, PS.LANES), K=spec.K, F=PS.F,
+        block=(PS.carry_rows(spec), PS.LANES), K=spec.K, F=PS.F,
+        f_top=PS.F_TOP, vmem_bytes=kernel_vmem_bytes(spec),
         where=where, path=path, line=0)
     if spec.rows not in (PS.ROWS, 2 * PS.ROWS):
         out.append(Finding(
@@ -180,6 +221,8 @@ def check_production() -> List[Finding]:
             "pallas-block-shape", path, 0,
             "spec_for accepts P=16: the (16,128) tier serves P <= 15"))
     out += check_config(F=PS.F, where="pallas_seg.F", path=path)
+    out += check_config(f_top=PS.F_TOP, where="pallas_seg.F_TOP",
+                        path=path)
     out += check_config(grid_steps=PS.CHUNK, where="pallas_seg.CHUNK",
                         path=path)
     return out
@@ -189,8 +232,8 @@ def budget_table() -> str:
     """The checked production budgets as a markdown artifact."""
     PS = _pallas_seg()
     rows = ["| bucket | P | K | rows | words | chunk | prefetch B "
-            "| step-SMEM B | table rows |",
-            "|---|---|---|---|---|---|---|---|---|"]
+            "| step-SMEM B | table rows | VMEM B |",
+            "|---|---|---|---|---|---|---|---|---|---|"]
     for bucket, P, K, spec in sorted(
             production_tiers(),
             key=lambda t: (t[0], t[1], t[2])):
@@ -200,10 +243,11 @@ def budget_table() -> str:
             f"| {spec.n_words} | {spec.chunk} "
             f"| {spec.chunk * width * 4} "
             f"| {spec.chunk * SMEM_STEP_BYTES} "
-            f"| {spec.table_rows_pad} |")
+            f"| {spec.table_rows_pad} | {kernel_vmem_bytes(spec)} |")
     head = (f"# Pallas budget table (limits: prefetch <= "
             f"{SMEM_PREFETCH_INT32 * 4} B, grid <= {MAX_GRID_STEPS} "
-            f"steps, K <= {K_CAP}, F = {F_CAP})\n\n")
+            f"steps, K <= {K_CAP}, F = {F_CAP} rising to "
+            f"{F_TOP_CAP}, VMEM <= {VMEM_BUDGET_BYTES} B)\n\n")
     return head + "\n".join(rows) + "\n"
 
 

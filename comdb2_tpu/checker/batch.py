@@ -377,7 +377,7 @@ def _slice_spec(streams, sizes, p_eff_pad):
             pe = max(pe, int(s.ok_proc.max()) + 1)
     P = _kernel_P(max(pe, p_eff_pad))
     return PSEG.spec_for(sizes["n_states"], sizes["n_transitions"],
-                         P, K + (K & 1))
+                         P, PSEG.kernel_K(K + (K & 1)))
 
 
 def _slice_with_sentinels(streams, start, end, B):
@@ -489,7 +489,6 @@ def _stream_stage(batch: PackedBatch, succ, sizes, s_pad, k_pad,
     return pending, all_streams
 
 
-@_obs.traced("batch.collect")
 def _stream_collect(pending, B):
     """Block on the staged dispatches in order and merge the
     per-slice verdicts (each ``np.asarray`` waits on that slice's
@@ -497,22 +496,33 @@ def _stream_collect(pending, B):
     sharded — the caller slices sentinel verdicts off before anything
     user-visible. A readback failure clears the donated-carry pool:
     the failed scan's carries were recycled at dispatch time and must
-    not seed the next same-shape dispatch."""
+    not seed the next same-shape dispatch. The ``batch.collect`` span
+    carries the slices' rung counters (``rung_segments``,
+    ``past_top``), read back with the verdicts."""
     rs: list = [None] * B
-    try:
-        for handle, start, end in pending:
-            if len(handle) == 3:      # sharded: (res, starts, D)
-                res, starts, D = handle
-                out = PSEG.merge_stream_shards(np.asarray(res),
-                                               starts, end - start, D)
-            else:
-                res, starts = handle
-                out = PSEG.merge_stream_slice(np.asarray(res), starts,
-                                              end - start)
-            rs[start:end] = out
-    except Exception:
-        PSEG.clear_carry_pool()
-        raise
+    rows: list = []
+    with _obs.span("batch.collect") as sp:
+        try:
+            for handle, start, end in pending:
+                if len(handle) == 3:      # sharded: (res, starts, D)
+                    res, starts, D = handle
+                    res = np.asarray(res)
+                    g = (end - start) // D
+                    out = PSEG.merge_stream_shards(res, starts,
+                                                   end - start, D)
+                    rows.extend(res[d, :g] for d in range(D))
+                else:
+                    res, starts = handle
+                    res = np.asarray(res)
+                    out = PSEG.merge_stream_slice(res, starts,
+                                                  end - start)
+                    rows.append(res[:end - start])
+                rs[start:end] = out
+        except Exception:
+            PSEG.clear_carry_pool()
+            raise
+        if rows:
+            PSEG.note_rungs(np.concatenate(rows), sp)
     return rs
 
 
@@ -721,11 +731,11 @@ def _check_batch_begin(batch: PackedBatch, F: int, mesh,
                     segs_list[b].seg_index[rs[b][1]] if rs[b][1] >= 0
                     else -1 for b in range(B)], np.int64)
                 n_final = np.array([r[2] for r in rs], np.int32)
-                # the kernel's frontier is fixed at 128: histories
-                # that overflowed it get their requested budget F
+                # the kernel's frontier tops out at F_TOP: histories
+                # that overflowed it get a larger requested budget F
                 # through the XLA engines instead of surfacing
                 # spurious UNKNOWNs
-                unk = escalation_indices(status, F, PSEG.F)
+                unk = escalation_indices(status, F, PSEG.F_TOP)
                 # the sub-batch is sized by the overflow count, so
                 # pick the escalation engine from THAT size — at
                 # pod-scale batches the full-B budgets never fit while
@@ -873,9 +883,9 @@ def escalation_indices(status: np.ndarray, F: int,
                        kernel_f: int) -> np.ndarray:
     """Pure: which batch indices must re-run through the XLA engines.
     Only UNKNOWN verdicts escalate, and only when the caller's
-    requested frontier budget actually EXCEEDS the fused kernel's
-    fixed one — re-running at the same budget could only reproduce the
-    overflow."""
+    requested frontier budget actually EXCEEDS the fused kernel's top
+    rung ``kernel_f`` — re-running at a budget the kernel already
+    covered could only reproduce the overflow."""
     if F <= kernel_f:
         return np.empty(0, np.int64)
     return np.flatnonzero(np.asarray(status) == LJ.UNKNOWN)

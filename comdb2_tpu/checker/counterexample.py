@@ -191,7 +191,7 @@ def _pallas_boundary(mm, segs, P_k: int, sizes):
         return None
     status, fail_seg, _n, (ws, done) = r
     spec = PSEG.spec_for(sizes["n_states"], sizes["n_transitions"],
-                         P_k, segs.inv_proc.shape[1])
+                         P_k, PSEG.kernel_K(segs.inv_proc.shape[1]))
     return PSEG.decode_frontier(spec, ws, P_k), done, fail_seg
 
 

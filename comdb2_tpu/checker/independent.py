@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
+from ..obs import trace as _obs
 from ..ops.kv import KVTuple, is_tuple, tuple_
 from ..ops.op import Op
 from .checkers import Checker, Linearizable, check_safe, merge_valid
@@ -60,6 +61,30 @@ def subhistory(k, history: Iterable[Op]) -> List[Op]:
     return out
 
 
+def split(history: Sequence[Op]) -> Dict[Any, List[Op]]:
+    """Every key's :func:`subhistory`, keys in first-appearance order,
+    in ONE pass over the history: keyed ops go to their key, tuples
+    unwrapped; an un-keyed op goes to every key, in order — also to
+    keys that first appear after it (``independent.clj:240-250``).
+    Opens the span ``independent.split`` (``keys``, ``events``)."""
+    with _obs.span("independent.split") as sp:
+        subs: Dict[Any, List[Op]] = {}
+        unkeyed: List[Op] = []
+        for op in history:
+            v = op.value
+            if is_tuple(v):
+                sub = subs.get(v[0])
+                if sub is None:          # a new key: the un-keyed so far
+                    sub = subs[v[0]] = list(unkeyed)
+                sub.append(op.with_(value=v[1]))
+            else:
+                unkeyed.append(op)
+                for sub in subs.values():
+                    sub.append(op)
+        sp.set(keys=len(subs), events=len(history))
+    return subs
+
+
 class IndependentChecker(Checker):
     """Lift a base checker over keyed histories: valid iff valid for
     every key's subhistory; per-key results under ``"results"``, invalid
@@ -76,8 +101,9 @@ class IndependentChecker(Checker):
 
         from ..harness.store import artifact_dir
 
-        ks = history_keys(history)
-        subs = {k: subhistory(k, history) for k in ks}
+        history = list(history)
+        subs = split(history)
+        ks = list(subs)
         # per-key artifact routing: a failing base checker writes its
         # counterexample under independent/<k>/ (the reference's per-key
         # store layout) instead of every key clobbering one linear.svg
@@ -144,14 +170,12 @@ class IndependentChecker(Checker):
                                   ) -> Dict[Any, dict]:
         """One device launch for all keys; unknowns (frontier overflow)
         and packing failures fall back to the per-key escalating path."""
-        from ..ops.packed import pack_history
         from . import batch as B
         from . import linear_jax as LJ
 
         ks = list(subs)
         try:
-            packeds = [pack_history(list(subs[k])) for k in ks]
-            pb = B.pack_batch(packeds, model)
+            pb = B.pack_batch([subs[k] for k in ks], model)
             status, fail_at, _ = B.check_batch(pb, F=self.batch_frontier,
                                                mesh=self.mesh)
         except Exception:
@@ -163,9 +187,12 @@ class IndependentChecker(Checker):
             st = int(status[i])
             if st == LJ.VALID:
                 results[k] = {"valid?": True, "backend": "device-batch"}
-            else:
-                # invalid or overflow: re-check solo for an exact verdict
-                # with escalation and a decoded counterexample
+                continue
+            # invalid or overflow: re-check solo for an exact verdict
+            # with escalation and a decoded counterexample
+            reason = "invalid" if st == LJ.INVALID else "overflow"
+            with _obs.span("independent.recheck", key=str(k),
+                           reason=reason):
                 results[k] = check_safe(self.base, {}, model, subs[k],
                                         key_opts(k))
         return results

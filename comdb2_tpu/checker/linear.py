@@ -180,8 +180,9 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
     P2 = max(P2, 2)
     # fused-kernel fast path: the whole segment loop runs inside one
     # Pallas kernel per 1024-segment chunk (checker/pallas_seg.py),
-    # ~4x the XLA engines on a real TPU. F is fixed at 128 there;
-    # overflow (UNKNOWN) falls through to the XLA capacity ladder, and
+    # ~4x the XLA engines on a real TPU. Its frontier rungs run from
+    # 128 to F_TOP per segment inside the kernel; overflow past the
+    # top rung (UNKNOWN) falls through to the XLA capacity ladder, and
     # so do shapes the kernel can't take (key budget, table size,
     # P > 15) — check_device_pallas* return None when spec_for rejects
     # the shape. Off the TPU the kernel is unavailable by design; on
@@ -211,7 +212,7 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
     if r is not None:
         status, fail_seg, n_final = r
         info["engine"] = "pallas-fused"
-        info["frontier_capacity"] = PSEG.F
+        info["frontier_capacity"] = PSEG.F_TOP
         if status != LJ.UNKNOWN:
             info["time_s"] = _obs.monotonic() - t0
             return _device_verdict(mm, packed, segs, status, fail_seg,
@@ -220,7 +221,7 @@ def _analyze_device(mm: MemoizedModel, packed: PackedHistory,
         # final artifact must say WHICH engine produced the verdict
         # and what was tried on the way (a wide-P UNKNOWN used to be
         # indistinguishable from a capacity abort in filetest output)
-        _note_tried(info, "pallas-fused", PSEG.F)
+        _note_tried(info, "pallas-fused", PSEG.F_TOP)
 
     # MXU frontier engine: P past the fused kernel's tiers but with
     # bounded in-flight (remap_slots makes P the max CONCURRENT open

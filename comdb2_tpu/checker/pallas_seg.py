@@ -32,8 +32,23 @@ ENTIRE segment loop inside one Pallas kernel per 1024-segment chunk:
 Semantics match ``check_device_seg`` exactly: per ok-op segment, apply
 invokes, run the linearization closure at most ``depth`` iterations
 (stopping at a fixed point), keep configs whose ok-slot linearized,
-empty frontier => INVALID at that segment, >128 unique configs =>
-UNKNOWN (the reference's OOM-abort contract, ``linear.clj:318-326``).
+empty frontier => INVALID at that segment, more unique configs than
+the top rung holds => UNKNOWN (the reference's OOM-abort contract,
+``linear.clj:318-326``).
+
+Frontier rungs. Rung 0 is the buffer's row 0 (F = 128) and serves
+almost every segment. A segment whose closure overflows it is redone
+from its entry frontier on the wide rungs, r = 1..WIDE_RUNGS, which
+hold ``128 << r`` configs (256 .. 4096) in a block-structured VMEM
+scratch: the frontier in its first ``2**r`` rows, candidate chunk q
+in the next ``2**r`` rows each, sorted by a bitonic network over
+(8,128) blocks (per-block passes, then cross-block compare-exchanges
+at a runtime block count) — still the exact sort-adjacency dedup. A
+rung that overflows hands the segment to the next; the next segment
+returns to rung 0 as soon as its frontier fits 128. The rung sizes
+are runtime values, so every rung shares one compiled kernel; a
+frontier wider than 128 crosses chunk calls in the carry's extra
+``WIDE_ROWS`` rows.
 Falls back unavailable (see :func:`spec_for`) when P, the key budget,
 or the table don't fit — the driver then uses the XLA engines.
 """
@@ -50,7 +65,18 @@ from ..utils import next_pow2 as _next_pow2
 
 ROWS, LANES = 8, 128
 N = ROWS * LANES          # flat sort width
-F = LANES                 # frontier capacity (row 0)
+F = LANES                 # rung-0 frontier capacity (row 0)
+WIDE_RUNGS = 5            # wide rungs r = 1..5: 128 << r configs
+F_TOP = F << WIDE_RUNGS   # the top rung's capacity (4096)
+WIDE_ROWS = F_TOP // LANES        # carry rows of a wide frontier
+WIDE_BLOCKS = WIDE_ROWS // ROWS   # ... in (8,128) blocks
+#: stat-row lanes: segments finished on rung r (r = 1..WIDE_RUNGS) at
+#: STAT_RUNGS + r - 1, segments past the top rung at STAT_PAST_TOP
+STAT_RUNGS = 4
+STAT_PAST_TOP = STAT_RUNGS + WIDE_RUNGS
+#: stat-row lane: invoke-only rows scanned so far (see INVOKE_ONLY)
+STAT_INV = STAT_PAST_TOP + 1
+K_MAX = 8                 # invokes per kernel row (SMEM row width)
 CHUNK = 1024              # segments per kernel call. SMEM-bounded in
                           # TWO ways: the scalar-prefetch array
                           # (~14336 int32) AND a per-grid-step SMEM
@@ -258,11 +284,24 @@ def pack_table(succ: np.ndarray, rows: int = ROWS) -> np.ndarray:
     return flat.reshape(rows, LANES)
 
 
+def carry_rows(spec: SegKernelSpec) -> int:
+    """Rows of each key-word carry: the (rows,128) rung-0 buffer, then
+    the ``WIDE_ROWS`` rows a wider frontier crosses chunk calls in."""
+    return spec.rows + WIDE_ROWS
+
+
+def rung_blocks(P: int, r: int) -> int:
+    """(8,128) blocks of rung ``r``'s sort buffer: the frontier's
+    ``2**r`` rows and P candidate chunks of as many, padded to a power
+    of two for the bitonic network."""
+    return _next_pow2(-(-((P + 1) << r) // ROWS))
+
+
 def initial_frontier(spec: SegKernelSpec):
-    """List of ``n_words`` (rows,128) host arrays (least-significant
-    word first): lane 0 of row 0 = the empty config (all slots idle,
-    state 0), everything else sentinel."""
-    ws = [np.full((spec.rows, LANES),
+    """List of ``n_words`` (carry_rows,128) host arrays (least-
+    significant word first): lane 0 of row 0 = the empty config (all
+    slots idle, state 0), everything else sentinel."""
+    ws = [np.full((carry_rows(spec), LANES),
                   SENT_HI if w == spec.n_words - 1 else SENT_LO,
                   np.int32)
           for w in range(spec.n_words)]
@@ -273,7 +312,9 @@ def initial_frontier(spec: SegKernelSpec):
 
 def _init_stat() -> np.ndarray:
     """Initial (1, 128) stat row: [status, fail, n, hist-counter] in
-    lanes 0..3 — the layout the kernel's sstat load/flush assumes."""
+    lanes 0..3, then the per-rung segment counters (STAT_RUNGS ..
+    STAT_PAST_TOP) and the invoke-only rows scanned (STAT_INV), all
+    zero — the layout the kernel's sstat load/flush assumes."""
     stat0 = np.zeros((1, LANES), np.int32)
     stat0[0, 0] = VALID
     stat0[0, 1] = -1
@@ -345,23 +386,30 @@ def _cmp_exchange(ws, pws, take_min):
             for w, pw in zip(ws, pws)]
 
 
-def _sort_flat(ws, rows: int = ROWS):
-    """Full ascending bitonic sort of the rows*128 flat keys."""
+def _bitonic_stage(ws, j, asc, lane, flat, rows):
+    """One bitonic compare-exchange stage at flat distance ``j`` (a
+    static power of two): position f pairs with f ^ j and keeps the min
+    where ``asc`` says the pair sorts ascending."""
     import jax.numpy as jnp
 
+    is_low = (flat & j) == 0
+    pws = []
+    for w in ws:
+        wp, wm = _fetch(w, j, lane, rows)
+        pws.append(jnp.where(is_low, wp, wm))
+    return _cmp_exchange(ws, pws, is_low == asc)
+
+
+def _sort_flat(ws, rows: int = ROWS):
+    """Full ascending bitonic sort of the rows*128 flat keys."""
     n = rows * LANES
     _, lane, flat = _iotas(rows)
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            is_low = (flat & j) == 0
             asc = (flat & k) == 0 if k < n else (flat >= 0)
-            pws = []
-            for w in ws:
-                wp, wm = _fetch(w, j, lane, rows)
-                pws.append(jnp.where(is_low, wp, wm))
-            ws = _cmp_exchange(ws, pws, is_low == asc)
+            ws = _bitonic_stage(ws, j, asc, lane, flat, rows)
             j //= 2
         k *= 2
     return ws
@@ -527,7 +575,7 @@ def _slot_field_runtime(spec, ws, p):
     """Extract slot p where p is a runtime scalar (unrolled select)."""
     import jax.numpy as jnp
 
-    out = jnp.zeros((spec.rows, LANES), jnp.int32)
+    out = jnp.zeros_like(ws[0])
     for q in range(spec.P):
         out = jnp.where(p == q,
                         _field(spec, ws, spec.slot_pos[q],
@@ -547,7 +595,117 @@ def _slot_add_runtime(spec, ws, p, delta, mask):
     return ws
 
 
+def _sort_block(ws, base):
+    """Bitonic passes k = 2..1024 over one (8,128) block whose first
+    flat position is ``base`` (a runtime multiple of 1024): ascending
+    where bit 10 of ``base`` is clear, descending where it is set, so
+    neighbouring blocks form the bitonic runs the cross-block passes
+    of :func:`_build_kernel`'s ``sort_blocks`` merge."""
+    _, lane, flat = _iotas(ROWS)
+    pos = flat + base
+    k = 2
+    while k <= N:
+        j = k // 2
+        while j >= 1:
+            ws = _bitonic_stage(ws, j, (pos & k) == 0, lane, flat, ROWS)
+            j //= 2
+        k *= 2
+    return ws
+
+
+def _merge_block(ws, asc):
+    """The in-block stages (j = 512..1) of one bitonic pass over a
+    block whose direction ``asc`` is one (broadcast) bool vector."""
+    _, lane, flat = _iotas(ROWS)
+    j = N // 2
+    while j >= 1:
+        ws = _bitonic_stage(ws, j, asc, lane, flat, ROWS)
+        j //= 2
+    return ws
+
+
+def _tile_rows(ws, m1):
+    """Row i of each word <- row (i & m1) (``m1`` = rows - 1 of a
+    frontier narrower than a block, a runtime scalar; 7 keeps the
+    block as it is)."""
+    import jax.numpy as jnp
+
+    row, _, _ = _iotas(ROWS)
+    sel = row & m1
+    out = []
+    for w in ws:
+        t = w
+        for rr in range(ROWS):
+            t = jnp.where(sel == rr,
+                          jnp.broadcast_to(w[rr:rr + 1, :],
+                                           (ROWS, LANES)), t)
+        out.append(t)
+    return out
+
+
+def _apply_invokes(spec, ws, seg_ref, i, mask):
+    """Row ``i``'s invokes (slot p, transition tr): slot p of every
+    valid key under ``mask`` goes IDLE(1) -> tr+2 (delta tr+1)."""
+    K = spec.K
+    for k in range(K):
+        p = seg_ref[i, 2 + k]
+        tr = seg_ref[i, 2 + K + k]
+        m = mask & (ws[-1] < SENT_HI) & (p >= 0)
+        ws = _slot_add_runtime(spec, ws, p, tr + 1, m)
+    return ws
+
+
 RESET = -2     # ok_proc marker: flush current history, start the next
+#: ok_proc marker of a row that only applies its invokes: a segment
+#: with more than K_MAX invokes rides several rows, all but the last
+#: invoke-only (closing nothing, so they change no verdict). Rows are
+#: numbered in segments by leaving them out (STAT_INV counts them).
+INVOKE_ONLY = -3
+
+
+def kernel_K(K: int) -> int:
+    """The invoke width of the kernel rows for segments of up to ``K``
+    invokes: K itself up to K_MAX, K_MAX past it (the packers split
+    wider segments into invoke-only rows)."""
+    return min(K, K_MAX)
+
+
+def _kernel_rows(segs, K: int, chunk: int = 0):
+    """``(ok_proc, depth, inv_proc, inv_tr)`` of ``segs`` with at most
+    ``K`` invokes per row: a wider segment's invokes are packed left
+    and spread over invoke-only rows ahead of the row that closes it.
+    With ``chunk``, a split segment's rows never straddle a chunk
+    boundary (invoke-only rows with no invokes fill up to it), so a
+    chunk boundary always falls between whole segments."""
+    ip, it = segs.inv_proc, segs.inv_tr
+    if ip.shape[1] <= K:
+        return segs.ok_proc, segs.depth, ip, it
+    order = np.argsort(ip < 0, axis=1, kind="stable")
+    ip = np.take_along_axis(ip, order, 1)
+    it = np.take_along_axis(it, order, 1)
+    extra = np.maximum((ip >= 0).sum(1) - 1, 0) // K
+    pad = np.zeros_like(extra)
+    if chunk:
+        start = np.arange(len(extra)) + np.cumsum(extra) - extra
+        shift = 0
+        for s_ in np.flatnonzero(extra):
+            first = start[s_] + shift
+            if first // chunk != (first + extra[s_]) // chunk:
+                pad[s_] = chunk - first % chunk
+                shift += pad[s_]
+    pos = np.cumsum(extra + pad + 1) - 1        # each segment's closing row
+    total = int(pos[-1]) + 1 if len(pos) else 0
+    okp = np.full(total, INVOKE_ONLY, np.int32)
+    dep = np.zeros(total, np.int32)
+    out_ip = np.full((total, K), -1, np.int32)
+    out_it = np.zeros((total, K), np.int32)
+    okp[pos] = segs.ok_proc
+    dep[pos] = segs.depth
+    s_i, c_i = np.nonzero(ip >= 0)
+    rows_ = pos[s_i] - extra[s_i] + c_i // K
+    out_ip[rows_, c_i % K] = ip[s_i, c_i]
+    out_it[rows_, c_i % K] = it[s_i, c_i]
+    return okp, dep, out_ip, out_it
 
 
 def _root_key(spec):
@@ -563,47 +721,118 @@ def _root_key(spec):
 def _build_kernel(spec: SegKernelSpec):
     """The chunk kernel. Grid = (chunk,); scalar-prefetch args:
     seg[chunk, 2+2K] (ok_proc, depth, inv_proc.., inv_tr..) and
-    off[1] (global segment offset). Inputs: n_words key-word carries
-    (rows,128), carry_stat (1,128) [status, fail, n, hist-counter],
-    results (B_pad, 128), table (rows,128). Outputs: the same carries.
+    off[2] (global segment offset, table row stride). Inputs: n_words
+    key-word carries (carry_rows,128), carry_stat (1,128) [status,
+    fail, n, hist-counter, rung counters], results (B_pad, 128), table
+    (rows,128). Outputs: the same carries.
 
     A segment with ok_proc == RESET is a history boundary in a
     multi-history stream: the current history's (status, fail, n) row
-    is stored at results[counter], the counter advances, and the
-    frontier/status reset to the initial state. Single-history runs
-    simply have no RESET segments and ignore the results buffer."""
+    and rung counters are stored at results[counter], the counter
+    advances, and the frontier/status reset to the initial state.
+    Single-history runs simply have no RESET segments and ignore the
+    results buffer.
+
+    Rungs: a segment whose entry frontier fits F runs on rung 0, the
+    (rows,128) buffer's row 0. A rung-0 overflow commits nothing and
+    the same grid step redoes the segment from its entry frontier on
+    the wide rungs (``wide_segment``); a frontier left wider than F
+    stays in the wide scratch, and the next segment starts there."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
 
-    P, K, W, rows = spec.P, spec.K, spec.n_words, spec.rows
+    P, W, rows = spec.P, spec.n_words, spec.rows
+    R = WIDE_RUNGS
+    NB = [rung_blocks(P, r) for r in range(R + 1)]
+    FB = [max(1, (1 << r) // ROWS) for r in range(R + 1)]
+    LOG_NB = NB[R].bit_length() - 1
 
     root = _root_key(spec)
+    # SMEM slots past the rung-0 ones (0..6): the wide rungs' request,
+    # done, continue, overflow, count, changed, ok-count and final rung,
+    # the invoke-only rows scanned, then the counters (rung 1..R, past
+    # the top) from S_CNT
+    S_REQ, S_DONE, S_CONT, S_OVF, S_NCUR, S_CHG, S_NOK, S_RUNG, S_INV = \
+        range(7, 16)
+    S_CNT = 16
+
+    def _at(b):
+        o = b * ROWS
+        return pl.ds(o if isinstance(o, int)
+                     else pl.multiple_of(o, ROWS), ROWS)
+
+    def _sel(table, r):
+        v = jnp.int32(table[0])
+        for rr in range(1, len(table)):
+            v = jnp.where(r == rr, jnp.int32(table[rr]), v)
+        return v
+
+    def _sent_blocks(n_rows):
+        return [jnp.full((n_rows, LANES),
+                         SENT_HI if w == W - 1 else SENT_LO, jnp.int32)
+                for w in range(W)]
 
     def kernel(seg_ref, off_ref, *refs):
         # refs: W word carries in, st_in, res_in, tab_ref,
         #       W word carries out, st_out, res_out,
-        #       W VMEM word scratch, sstat SMEM
+        #       W rung-0 VMEM word scratch, W wide frontier, W its
+        #       entry copy, W wide sort buffer, sstat SMEM
         ws_in = refs[:W]
         st_in, res_in, tab_ref = refs[W], refs[W + 1], refs[W + 2]
         ws_out = refs[W + 3:2 * W + 3]
         st_out, res_out = refs[2 * W + 3], refs[2 * W + 4]
         wsc = refs[2 * W + 5:3 * W + 5]
-        sstat = refs[3 * W + 5]
+        fs = refs[3 * W + 5:4 * W + 5]
+        es = refs[4 * W + 5:5 * W + 5]
+        buf = refs[5 * W + 5:6 * W + 5]
+        sstat = refs[6 * W + 5]
         i = pl.program_id(0)
+
+        def blk(ref, b):
+            return ref[_at(b), :]
+
+        def put(ref, b, v):
+            ref[_at(b), :] = v
+
+        def stat_row(lanes):
+            """(1,128) row: ``lanes`` {lane: scalar}, then the rung
+            counters; 0 elsewhere."""
+            _, lane, _ = _iotas(rows)
+            lane = lane[0:1, :]
+            out = jnp.zeros((1, LANES), jnp.int32)
+            cells = dict(lanes)
+            for c in range(R + 1):
+                cells[STAT_RUNGS + c] = sstat[S_CNT + c]
+            for ln, v in cells.items():
+                out = jnp.where(lane == ln, v, out)
+            return out
+
+        def clear_wide():
+            sent = _sent_blocks(ROWS)
+            for w in range(W):
+                for bb in range(WIDE_BLOCKS):
+                    put(fs[w], bb, sent[w])
 
         @pl.when(i == 0)
         def _():
             for w in range(W):
-                wsc[w][:] = ws_in[w][:]
+                wsc[w][:] = ws_in[w][0:rows, :]
+                fs[w][:] = ws_in[w][rows:rows + WIDE_ROWS, :]
             res_out[:] = res_in[:]
             sstat[0] = st_in[0, 0]      # status
             sstat[1] = st_in[0, 1]      # fail seg (global)
             sstat[2] = st_in[0, 2]      # frontier count
             sstat[6] = st_in[0, 3]      # history counter (stream mode)
+            sstat[S_INV] = st_in[0, STAT_INV]
+            for c in range(R + 1):
+                sstat[S_CNT + c] = st_in[0, STAT_RUNGS + c]
 
         ok_p = seg_ref[i, 0]
         depth = seg_ref[i, 1]
+        # this row's segment number: invoke-only rows are left out
+        # (none on a stream of segments of at most K_MAX invokes)
+        seg_no = off_ref[0] + i - sstat[S_INV]
 
         @pl.when(ok_p == RESET)
         def _():
@@ -612,37 +841,33 @@ def _build_kernel(spec: SegKernelSpec):
 
             @pl.when(cnt >= 0)
             def _():
-                stat_row = jnp.where(
-                    lane[0:1, :] == 0, sstat[0],
-                    jnp.where(lane[0:1, :] == 1, sstat[1],
-                              jnp.where(lane[0:1, :] == 2, sstat[2],
-                                        0)))
-                res_out[pl.ds(cnt, 1), :] = stat_row
+                res_out[pl.ds(cnt, 1), :] = stat_row(
+                    {0: sstat[0], 1: sstat[1], 2: sstat[2]})
 
             sstat[6] = cnt + 1
             sstat[0] = VALID
             sstat[1] = -1
             sstat[2] = 1
+            for c in range(R + 1):
+                sstat[S_CNT + c] = 0
             at_root = (row == 0) & (lane == 0)
             for w in range(W):
                 sent = SENT_HI if w == W - 1 else SENT_LO
                 wsc[w][:] = jnp.where(at_root, root[w], sent)
+            clear_wide()
 
         live = (sstat[0] == VALID) & (ok_p >= 0)
+        wide_in = sstat[2] > F
+        sstat[S_REQ] = 0
 
-        @pl.when(live)
+        @pl.when(live & jnp.logical_not(wide_in))
         def _():
             row, lane, _ = _iotas(rows)
             ws = [wsc[w][:] for w in range(W)]
             table = tab_ref[:]
             stride = off_ref[1]      # runtime table row stride
             frow = row == 0
-            # --- invokes: slot p IDLE(1) -> tr+2 (delta tr+1) --------
-            for k in range(K):
-                p = seg_ref[i, 2 + k]
-                tr = seg_ref[i, 2 + K + k]
-                m = frow & (ws[-1] < SENT_HI) & (p >= 0)
-                ws = _slot_add_runtime(spec, ws, p, tr + 1, m)
+            ws = _apply_invokes(spec, ws, seg_ref, i, frow)
 
             # --- lazy compaction (round 5): the ok filter no longer
             # sorts survivors forward every segment — the frontier may
@@ -751,28 +976,318 @@ def _build_kernel(spec: SegKernelSpec):
             # (see the closure-entry cond above); unconditional
             # re-sorting here cost 28 stages on every segment
 
+            # an overflow commits nothing: the entry frontier stays in
+            # the scratch for the wide rungs to redo the segment from
             ovf = sstat[4] == 1
-            st_new = jnp.where(ovf, UNKNOWN,
-                               jnp.where(n2 == 0, INVALID, VALID))
-            sstat[1] = jnp.where(st_new == VALID, sstat[1],
-                                 off_ref[0] + i)
-            sstat[0] = st_new
-            sstat[2] = n2
+            sstat[S_REQ] = sstat[4]
+
+            @pl.when(jnp.logical_not(ovf))
+            def _():
+                st_new = jnp.where(n2 == 0, INVALID, VALID)
+                sstat[1] = jnp.where(st_new == VALID, sstat[1],
+                                     seg_no)
+                sstat[0] = st_new
+                sstat[2] = n2
+                for w in range(W):
+                    wsc[w][:] = ws[w]
+
+        # --- the wide rungs (r = 1..R, 128 << r configs) ---------------
+        # The frontier sits compact in the first 2**r rows of ``fs``
+        # (positions past its count hold the sentinel); rung r's sort
+        # buffer ``buf`` holds it and P candidate chunks of 2**r rows
+        # each, NB[r] (8,128) blocks in all.
+
+        def copy_blocks(src, dst, nblk):
+            def body(b, c):
+                for w in range(W):
+                    put(dst[w], b, blk(src[w], b))
+                return c
+            lax.fori_loop(0, nblk, body, 0)
+
+        def sort_blocks(nb):
+            """Ascending bitonic sort of ``buf``'s first ``nb`` blocks
+            (a runtime power of two)."""
+            def per_block(b, c):
+                ws = _sort_block([blk(buf[w], b) for w in range(W)],
+                                 b * N)
+                for w in range(W):
+                    put(buf[w], b, ws[w])
+                return c
+            lax.fori_loop(0, nb, per_block, 0)
+            log_nb = jnp.int32(0)
+            for x in range(LOG_NB):
+                log_nb = log_nb + jnp.where(nb > (1 << x), 1, 0)
+
+            def dir_of(b, k):
+                return (jnp.zeros((ROWS, LANES), jnp.int32)
+                        + ((b * N) & k)) == 0
+
+            def merge(lk, c):
+                k = jnp.left_shift(jnp.int32(N), lk)
+
+                def cross(s, c2):
+                    lj = lk - 1 - s
+                    jb = jnp.left_shift(jnp.int32(1), lj)
+
+                    def pair(t, c3):
+                        b = (jnp.left_shift(jnp.right_shift(t, lj),
+                                            lj + 1) | (t & (jb - 1)))
+                        a = [blk(buf[w], b) for w in range(W)]
+                        o = [blk(buf[w], b + jb) for w in range(W)]
+                        keep = _ws_less(a, o) == dir_of(b, k)
+                        for w in range(W):
+                            put(buf[w], b, jnp.where(keep, a[w], o[w]))
+                            put(buf[w], b + jb,
+                                jnp.where(keep, o[w], a[w]))
+                        return c3
+                    return lax.fori_loop(0, jnp.right_shift(nb, 1),
+                                         pair, c2)
+                lax.fori_loop(0, lk, cross, 0)
+
+                def within(b, c2):
+                    ws = _merge_block([blk(buf[w], b) for w in range(W)],
+                                      dir_of(b, k))
+                    for w in range(W):
+                        put(buf[w], b, ws[w])
+                    return c2
+                return lax.fori_loop(0, nb, within, c)
+            lax.fori_loop(1, log_nb + 1, merge, 0)
+
+        def dedup(nb):
+            """Sentinel the duplicates of the sorted ``buf`` and count
+            the unique valid keys."""
+            _, lane, flat = _iotas(ROWS)
+
+            def body(b, carry):
+                prev_blk, n = carry[:W], carry[W]
+                ws = [blk(buf[w], b) for w in range(W)]
+                prev = [jnp.where(flat == 0,
+                                  _fetch(pw, 1, lane, ROWS)[1],
+                                  _fetch(w, 1, lane, ROWS)[1])
+                        for w, pw in zip(ws, prev_blk)]
+                valid = ws[-1] < SENT_HI
+                keep = valid & ~(_ws_eq(ws, prev) & ((flat + b * N) > 0))
+                out = _sentinel(ws, ~keep)
+                for w in range(W):
+                    put(buf[w], b, out[w])
+                return tuple(ws) + (n + jnp.sum(keep.astype(jnp.int32)),)
+            init = tuple(_sent_blocks(ROWS)) + (jnp.int32(0),)
+            return lax.fori_loop(0, nb, body, init)[W]
+
+        def expand(r, nb, fb, table, stride):
+            """``buf`` <- the frontier (group 0) and, in group q+1,
+            each config with slot q linearized; sentinel past group
+            P. A frontier narrower than a block is tiled over it."""
+            m1 = jnp.minimum(jnp.left_shift(jnp.int32(1), r), ROWS) - 1
+            row, _, _ = _iotas(ROWS)
+
+            def body(b, c):
+                f = _tile_rows([blk(fs[w], b & (fb - 1))
+                                for w in range(W)], m1)
+                g = jnp.right_shift(row + b * ROWS, r)
+                out = _sentinel(f, g != 0)
+                fvalid = f[-1] < SENT_HI
+                s = _field(spec, f, spec.state_pos, spec.state_bits)
+                sbase = s * stride
+                for q in range(P):
+                    tq = _field(spec, f, spec.slot_pos[q],
+                                spec.slot_bits)
+                    idx = sbase + jnp.maximum(tq - 2, 0)
+                    s2 = _gather_table(table, idx, spec.table_rows,
+                                       ROWS)
+                    ok = fvalid & (tq >= 2) & (s2 >= 0)
+                    cand = _field_add(spec, f, spec.slot_pos[q], -tq)
+                    cand = _field_add(spec, cand, spec.state_pos, s2 - s)
+                    cand = _sentinel(cand, ~ok)
+                    m = g == q + 1
+                    out = [jnp.where(m, cc, o) for cc, o in zip(cand, out)]
+                for w in range(W):
+                    put(buf[w], b, out[w])
+                return c
+            lax.fori_loop(0, nb, body, 0)
+
+        def ok_filter(fb, ok_p):
+            """``buf``'s first ``fb`` blocks <- the frontier's configs
+            whose ok-slot linearized, slot cleared; returns their
+            count."""
+            def body(b, n):
+                ws = [blk(fs[w], b) for w in range(W)]
+                tq = _slot_field_runtime(spec, ws, ok_p)
+                ret = (ws[-1] < SENT_HI) & (tq == 0)
+                ws = _sentinel(_slot_add_runtime(spec, ws, ok_p, 1, ret),
+                               ~ret)
+                for w in range(W):
+                    put(buf[w], b, ws[w])
+                return n + jnp.sum(ret.astype(jnp.int32))
+            return lax.fori_loop(0, fb, body, jnp.int32(0))
+
+        def wide_invokes():
+            def body(b, c):
+                ws = _apply_invokes(spec, [blk(fs[w], b) for w in range(W)],
+                                    seg_ref, i, True)
+                for w in range(W):
+                    put(fs[w], b, ws[w])
+                    put(es[w], b, ws[w])
+                return c
+            lax.fori_loop(0, WIDE_BLOCKS, body, 0)
+
+        # --- invoke-only rows: apply the invokes, close nothing ------
+        inv_only = ok_p == INVOKE_ONLY
+
+        @pl.when(inv_only)
+        def _():
+            sstat[S_INV] = sstat[S_INV] + 1
+
+        @pl.when(inv_only & (sstat[0] == VALID)
+                 & jnp.logical_not(wide_in))
+        def _():
+            row, _, _ = _iotas(rows)
+            ws = _apply_invokes(spec, [wsc[w][:] for w in range(W)],
+                                seg_ref, i, row == 0)
             for w in range(W):
                 wsc[w][:] = ws[w]
+
+        @pl.when(inv_only & (sstat[0] == VALID) & wide_in)
+        def _():
+            wide_invokes()
+
+        @pl.when(live & (wide_in | (sstat[S_REQ] == 1)))
+        def _():
+            table = tab_ref[:]
+            stride = off_ref[1]
+
+            @pl.when(jnp.logical_not(wide_in))
+            def _():
+                # from rung 0: its row 0 (scattered is fine — every
+                # wide step is sentinel-mask-based) becomes block 0
+                row, _, _ = _iotas(ROWS)
+                clear_wide()
+                sent = _sent_blocks(ROWS)
+                for w in range(W):
+                    put(fs[w], 0, jnp.where(
+                        row == 0,
+                        jnp.broadcast_to(wsc[w][0:1, :], (ROWS, LANES)),
+                        sent[w]))
+
+            wide_invokes()
+            n_entry = sstat[2]
+            r0 = jnp.int32(1)
+            for rr in range(1, R + 1):
+                r0 = r0 + jnp.where(n_entry > (F << rr), 1, 0)
+            sstat[S_DONE] = 0
+
+            def rung(r, c):
+                @pl.when((sstat[S_DONE] == 0) & (r >= r0))
+                def _():
+                    nb, fb = _sel(NB, r), _sel(FB, r)
+                    cap = jnp.left_shift(jnp.int32(F), r)
+                    copy_blocks(es, fs, fb)
+                    sstat[S_CONT] = 1
+                    sstat[S_OVF] = 0
+                    sstat[S_NCUR] = n_entry
+                    sstat[S_CHG] = 0
+
+                    def step(it, c2):
+                        # iterations 0..depth-1: the closure, until a
+                        # fixed point or an overflow; iteration depth:
+                        # the ok filter, when the rung held
+                        closure = (it < depth) & (sstat[S_CONT] == 1)
+                        final = (it == depth) & (sstat[S_OVF] == 0)
+
+                        @pl.when(closure)
+                        def _():
+                            expand(r, nb, fb, table, stride)
+
+                        @pl.when(final)
+                        def _():
+                            sstat[S_NOK] = ok_filter(fb, ok_p)
+
+                        nbx = jnp.where(final, fb, nb)
+
+                        def sort_pass(ps, c3):
+                            # pass 0 sorts (then dedups, in the
+                            # closure); pass 1 compacts a grown union
+                            @pl.when(((ps == 0) & (closure | final))
+                                     | ((ps == 1) & closure
+                                        & (sstat[S_CHG] == 1)))
+                            def _():
+                                sort_blocks(nbx)
+
+                            @pl.when((ps == 0) & closure)
+                            def _():
+                                n2 = dedup(nb)
+                                ovf = n2 > cap
+                                chg = (n2 > sstat[S_NCUR]) & \
+                                    jnp.logical_not(ovf)
+                                sstat[S_OVF] = ovf.astype(jnp.int32)
+                                sstat[S_CHG] = chg.astype(jnp.int32)
+                                sstat[S_CONT] = chg.astype(jnp.int32)
+                                sstat[S_NCUR] = n2
+                            return c3
+                        lax.fori_loop(0, 2, sort_pass, 0)
+
+                        @pl.when((closure & (sstat[S_CHG] == 1)) | final)
+                        def _():
+                            copy_blocks(buf, fs, fb)
+
+                        @pl.when(final)
+                        def _():
+                            sstat[S_DONE] = 1
+                            sstat[S_RUNG] = r
+                        return c2
+                    lax.fori_loop(0, depth + 1, step, 0)
+                return c
+            lax.fori_loop(1, R + 1, rung, 0)
+
+            done = sstat[S_DONE] == 1
+
+            @pl.when(jnp.logical_not(done))
+            def _():
+                # past the top rung: UNKNOWN, as rung 0 alone said
+                sstat[0] = UNKNOWN
+                sstat[1] = seg_no
+                sstat[2] = sstat[S_NCUR]
+                sstat[S_CNT + R] = sstat[S_CNT + R] + 1
+
+            @pl.when(done)
+            def _():
+                n2 = sstat[S_NOK]
+                st_new = jnp.where(n2 == 0, INVALID, VALID)
+                sstat[1] = jnp.where(st_new == VALID, sstat[1],
+                                     seg_no)
+                sstat[0] = st_new
+                sstat[2] = n2
+                ix = S_CNT + sstat[S_RUNG] - 1
+                sstat[ix] = sstat[ix] + 1
+                row, _, _ = _iotas(rows)
+                sent = _sent_blocks(rows)
+
+                # back to rung 0 once the frontier fits it (its row 0
+                # <- block 0's first row); else rung 0's buffer is
+                # cleared, so one of the two always holds the frontier
+                @pl.when(n2 <= F)
+                def _():
+                    for w in range(W):
+                        wsc[w][:] = jnp.where(
+                            row == 0,
+                            jnp.broadcast_to(fs[w][0:1, :],
+                                             (rows, LANES)),
+                            sent[w])
+                    clear_wide()
+
+                @pl.when(n2 > F)
+                def _():
+                    for w in range(W):
+                        wsc[w][:] = sent[w]
 
         @pl.when(i == pl.num_programs(0) - 1)
         def _():
             for w in range(W):
-                ws_out[w][:] = wsc[w][:]
-            _, lane0, _ = _iotas(rows)
-            stat_row = jnp.where(
-                lane0[0:1, :] == 0, sstat[0],
-                jnp.where(lane0[0:1, :] == 1, sstat[1],
-                          jnp.where(lane0[0:1, :] == 2, sstat[2],
-                                    jnp.where(lane0[0:1, :] == 3,
-                                              sstat[6], 0))))
-            st_out[:] = stat_row
+                ws_out[w][0:rows, :] = wsc[w][:]
+                ws_out[w][rows:rows + WIDE_ROWS, :] = fs[w][:]
+            st_out[:] = stat_row({0: sstat[0], 1: sstat[1],
+                                  2: sstat[2], 3: sstat[6],
+                                  STAT_INV: sstat[S_INV]})
 
     return kernel
 
@@ -803,7 +1318,8 @@ def _chunk_call(spec: SegKernelSpec, b_pad: int = 8):
 
     kernel = _build_kernel(spec)
     W, rows = spec.n_words, spec.rows
-    word_spec = pl.BlockSpec((rows, LANES), lambda i, *s: (0, 0))
+    c_rows = carry_rows(spec)
+    word_spec = pl.BlockSpec((c_rows, LANES), lambda i, *s: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(spec.chunk,),
@@ -818,9 +1334,12 @@ def _chunk_call(spec: SegKernelSpec, b_pad: int = 8):
             pl.BlockSpec((b_pad, LANES), lambda i, *s: (0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)] * W
-        + [pltpu.SMEM((8,), jnp.int32)])
+        + [pltpu.VMEM((WIDE_ROWS, LANES), jnp.int32)] * (2 * W)
+        + [pltpu.VMEM((rung_blocks(spec.P, WIDE_RUNGS) * ROWS, LANES),
+                      jnp.int32)] * W
+        + [pltpu.SMEM((32,), jnp.int32)])
 
-    word_shape = jax.ShapeDtypeStruct((rows, LANES), jnp.int32)
+    word_shape = jax.ShapeDtypeStruct((c_rows, LANES), jnp.int32)
 
     def call(seg, off, ws, stat, res, table):
         """``ws`` is the list/tuple of word carries; returns
@@ -841,19 +1360,21 @@ def _chunk_call(spec: SegKernelSpec, b_pad: int = 8):
 
 def pack_segments(segs, spec: SegKernelSpec) -> np.ndarray:
     """SegmentStream -> (n_chunks, chunk, 2+2K) scalar array, padded
-    with dead segments (ok_proc = -1)."""
-    S = segs.ok_proc.shape[0]
+    with dead segments (ok_proc = -1). Segments wider than ``spec.K``
+    ride invoke-only rows (:func:`_kernel_rows`)."""
     K, chunk = spec.K, spec.chunk
+    okp, dep, ip, it = _kernel_rows(segs, K, chunk)
+    S = okp.shape[0]
     n_chunks = max(-(-S // chunk), 1)
     W = 2 + 2 * K
     out = np.zeros((n_chunks, chunk, W), np.int32)
     out[:, :, 0] = -1
     flat = out.reshape(n_chunks * chunk, W)
-    flat[:S, 0] = segs.ok_proc
-    flat[:S, 1] = segs.depth
-    k_in = segs.inv_proc.shape[1]
-    flat[:S, 2:2 + k_in] = segs.inv_proc
-    flat[:S, 2 + K:2 + K + k_in] = segs.inv_tr
+    flat[:S, 0] = okp
+    flat[:S, 1] = dep
+    k_in = ip.shape[1]
+    flat[:S, 2:2 + k_in] = ip
+    flat[:S, 2 + K:2 + K + k_in] = it
     if k_in < K:
         flat[:S, 2 + k_in:2 + K] = -1
     return out
@@ -925,6 +1446,7 @@ def check_device_pallas(succ: np.ndarray, segs, *, n_states: int,
         _, stat, _ = run(jnp.asarray(seg_chunks), tuple(ws0), stat0,
                          res0, table, n_transitions)
         stat = np.asarray(stat)
+    note_rungs(stat)
     return int(stat[0, 0]), int(stat[0, 1]), int(stat[0, 2])
 
 
@@ -951,27 +1473,30 @@ def pack_stream(segs_list, spec: SegKernelSpec):
     history b's first segment's global stream index."""
     B = len(segs_list)
     W = 2 + 2 * spec.K
-    sizes = [s.ok_proc.shape[0] for s in segs_list]
+    rows = [_kernel_rows(s, spec.K) for s in segs_list]
+    sizes = [r[0].shape[0] for r in rows]
     total = sum(sizes) + B + 1
     chunk = spec.chunk
     n_chunks = max(-(-total // chunk), 1)
     flat = np.zeros((n_chunks * chunk, W), np.int32)
     flat[:, 0] = -1                       # default: dead padding
     starts = np.zeros(B, np.int64)
-    pos = 0
-    for b, segs in enumerate(segs_list):
+    pos = skipped = 0
+    for b, (okp, dep, ip, it) in enumerate(rows):
         flat[pos, 0] = RESET
         pos += 1
-        starts[b] = pos
+        # in segment numbering: the kernel leaves invoke-only rows out
+        starts[b] = pos - skipped
         S = sizes[b]
-        k_in = segs.inv_proc.shape[1]
-        flat[pos:pos + S, 0] = segs.ok_proc
-        flat[pos:pos + S, 1] = segs.depth
-        flat[pos:pos + S, 2:2 + k_in] = segs.inv_proc
+        k_in = ip.shape[1]
+        flat[pos:pos + S, 0] = okp
+        flat[pos:pos + S, 1] = dep
+        flat[pos:pos + S, 2:2 + k_in] = ip
         if k_in < spec.K:
             flat[pos:pos + S, 2 + k_in:2 + spec.K] = -1
-        flat[pos:pos + S, 2 + spec.K:2 + spec.K + k_in] = segs.inv_tr
+        flat[pos:pos + S, 2 + spec.K:2 + spec.K + k_in] = it
         pos += S
+        skipped += int(np.count_nonzero(okp == INVOKE_ONLY))
     flat[pos, 0] = RESET                  # trailing flush
     return flat.reshape(n_chunks, chunk, W), starts
 
@@ -1004,7 +1529,7 @@ def check_device_pallas_stream(succ: np.ndarray, segs_list, *,
     import jax.numpy as jnp
 
     K = max((s.inv_proc.shape[1] for s in segs_list), default=1)
-    spec = spec_for(n_states, n_transitions, P, K + (K & 1))
+    spec = spec_for(n_states, n_transitions, P, kernel_K(K + (K & 1)))
     if spec is None:
         return None
     B = len(segs_list)
@@ -1028,6 +1553,7 @@ def check_device_pallas_stream(succ: np.ndarray, segs_list, *,
     try:
         for (res, starts), (start, end, _) in zip(pending, plan):
             res = np.asarray(res)   # blocks on THIS slice's device only
+            note_rungs(res[:end - start])
             out.extend(merge_stream_slice(res, starts, end - start))
     except Exception:
         clear_carry_pool()          # recycled-at-dispatch carries of a
@@ -1066,6 +1592,41 @@ def plan_shard_slices(B: int, D: int,
         raise ValueError(f"B={B} must be a positive multiple of D={D}")
     step = min(cap, max(B // D, 1)) * D
     return [(i, min(i + step, B)) for i in range(0, B, step)]
+
+
+#: segments finished on each wide rung (index r-1 for rung r) and past
+#: the top rung, summed over every verdict read back this process —
+#: the counters ride in the stat/results rows, so reading them costs
+#: no extra readback
+RUNG_SEGMENTS = [0] * WIDE_RUNGS
+PAST_TOP = 0
+
+
+def rung_counts(rows: np.ndarray) -> np.ndarray:
+    """Per-verdict rung counters from read-back stat/results rows
+    ``(n, 128)``: ``(n, WIDE_RUNGS + 1)`` — segments that finished on
+    rung 1..WIDE_RUNGS, then segments past the top rung."""
+    return np.asarray(rows)[:, STAT_RUNGS:STAT_PAST_TOP + 1]
+
+
+def note_rungs(rows: np.ndarray, sp=None) -> np.ndarray:
+    """Add read-back rows' rung counters to the process totals and
+    report them (``rung_segments`` per wide rung, ``past_top``): on
+    span ``sp`` when given, else as a zero-length ``pallas.rungs``
+    span. Returns the summed counters."""
+    global PAST_TOP
+    tot = rung_counts(rows).sum(axis=0)
+    for r in range(WIDE_RUNGS):
+        RUNG_SEGMENTS[r] += int(tot[r])
+    PAST_TOP += int(tot[WIDE_RUNGS])
+    args = {"rung_segments": [int(x) for x in tot[:WIDE_RUNGS]],
+            "past_top": int(tot[WIDE_RUNGS])}
+    if sp is not None:
+        sp.set(**args)
+    else:
+        now = _obs.monotonic()
+        _obs.record("pallas.rungs", now, now, **args)
+    return tot
 
 
 def merge_stream_slice(res: np.ndarray, starts, n: int):
@@ -1290,8 +1851,8 @@ def _prepare(succ, segs, n_states, n_transitions, P):
     the shape can't run fused."""
     import jax.numpy as jnp
 
-    K = segs.inv_proc.shape[1]
-    spec = spec_for(n_states, n_transitions, P, K)
+    spec = spec_for(n_states, n_transitions, P,
+                    kernel_K(segs.inv_proc.shape[1]))
     if spec is None:
         return None
     seg_chunks = pack_segments(segs, spec)
@@ -1344,7 +1905,10 @@ def check_device_pallas_chunked(succ: np.ndarray, segs, *,
             visited += int(st[0, 2]) * spec.chunk
             if int(st[0, 0]) != VALID:
                 break
-            prev_ws, done = ws, (c + 1) * spec.chunk
+            # segments behind this boundary: its rows less the
+            # invoke-only ones (a split segment never straddles it)
+            prev_ws = ws
+            done = (c + 1) * spec.chunk - int(st[0, STAT_INV])
             now = _obs.monotonic()
             if progress is not None and \
                     now - last >= progress_interval_s:
@@ -1355,13 +1919,14 @@ def check_device_pallas_chunked(succ: np.ndarray, segs, *,
                 n_reads += 1
                 pend = [sum(1 for t in sl if t >= 0) for _, sl in cfgs]
                 el = max(now - t_run, 1e-9)
-                progress(min((c + 1) * spec.chunk, s_real), s_real,
+                progress(min(done, s_real), s_real,
                          int(st[0, 2]),
                          {"visited_per_s": visited / el,
                           "segs_per_s": done / el,
                           "est_cost": estimated_cost(pend)})
                 last = now
         st = np.asarray(stat)      # the last chunk's read, cached
+        note_rungs(st)
         out = (int(st[0, 0]), int(st[0, 1]), int(st[0, 2]))
         if return_boundary:
             out += (([np.asarray(w) for w in prev_ws],
@@ -1372,20 +1937,25 @@ def check_device_pallas_chunked(succ: np.ndarray, segs, *,
 
 
 def decode_frontier(spec: SegKernelSpec, ws, P: int):
-    """Decode a kernel frontier (packed key word list, row 0) into host
-    configs ``(state, slots)`` in the :mod:`~.linear_host` encoding:
-    the slot field stores LIN=0 / IDLE=1 / tr+2, so subtracting 2 maps
-    straight to LIN=-2 / IDLE=-1 / tr. Padding slots beyond ``P`` are
-    dropped (always IDLE)."""
+    """Decode a kernel frontier (packed key word list: rung 0's row 0
+    and the wide rows past ``spec.rows`` — the kernel keeps the other
+    one all sentinel) into host configs ``(state, slots)`` in the
+    :mod:`~.linear_host` encoding: the slot field stores LIN=0 /
+    IDLE=1 / tr+2, so subtracting 2 maps straight to LIN=-2 / IDLE=-1
+    / tr. Padding slots beyond ``P`` are dropped (always IDLE)."""
+    keys = [np.concatenate([np.asarray(w)[0],
+                            np.asarray(w)[spec.rows:].reshape(-1)])
+            for w in ws]
+
     def field(pos, bits):
         word, sh = pos
-        return (ws[word][0] >> sh) & ((1 << bits) - 1)
+        return (keys[word] >> sh) & ((1 << bits) - 1)
 
     state = field(spec.state_pos, spec.state_bits)
     slots = [field(spec.slot_pos[q], spec.slot_bits)
              for q in range(min(P, spec.P))]
     out = set()
-    for lane in np.flatnonzero(ws[-1][0] < SENT_HI):
+    for lane in np.flatnonzero(keys[-1] < SENT_HI):
         out.add((int(state[lane]),
                  tuple(int(slots[q][lane]) - 2
                        for q in range(min(P, spec.P)))))

@@ -8,7 +8,8 @@ is O(delta), never O(history). Three rungs share one interface:
 
 - **kernel** (``pallas_seg``): the fused Mosaic kernel's (ws, stat)
   word carry, chunk calls offset into the session's global segment
-  stream. F is fixed at 128; overflow re-routes the session to the
+  stream. The kernel's frontier rungs reach 4096 configs; overflow
+  past its top rung re-routes the session to the
   next rung by replaying the RETAINED renamed segments (the one
   O(history) event a session can pay, amortized over its life).
 - **xla** (``stream_delta_chunk`` below — the bucketed, closed-site
@@ -302,7 +303,7 @@ class MxuCarry:
 class KernelCarry:
     """The fused-kernel rung: (ws, stat) word carry threaded through
     per-chunk Mosaic calls at the session's global segment offset.
-    F is the kernel's fixed 128; any overflow or growth event
+    The kernel's rungs go up to ``F_TOP``; any overflow or growth event
     re-routes (replay on the next rung)."""
 
     name = "stream-kernel"
@@ -342,7 +343,7 @@ class KernelCarry:
         return int(st[0, 0]), int(st[0, 1]), int(st[0, 2])
 
     def escalate(self) -> bool:
-        return False                    # F fixed at 128: re-route
+        return False                    # past the top rung: re-route
 
     def widen_slots(self, P2_new: int) -> bool:
         return False                    # spec bakes P: re-route

@@ -61,9 +61,10 @@ def _assert_parity(name, seed, verdicts, host_valid, host_index,
     for eng, (st, fa, n) in verdicts.items():
         assert st == base[0], (name, seed, eng, verdicts)
         if st == 0:
-            # the kernel's F is fixed at 128 — counts only compare at
-            # the same frontier capacity, so VALID counts are asserted
-            # between the same-F engines (xla/mxu)
+            # the kernel's frontier rungs differ from the XLA/MXU
+            # capacities — counts only compare at the same capacity,
+            # so VALID counts are asserted between the same-F engines
+            # (xla/mxu)
             if eng != "pallas-fused":
                 assert n == base[2], (name, seed, eng, verdicts)
         else:

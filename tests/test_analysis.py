@@ -144,6 +144,9 @@ def test_budget_accepts_every_production_tier():
     (dict(block=(3, 128)), "pallas-block-shape"),
     (dict(K=9), "pallas-k-cap"),
     (dict(F=64), "pallas-f-cap"),
+    (dict(f_top=8192), "pallas-f-cap"),        # past the top rung cap
+    (dict(f_top=3072), "pallas-f-cap"),        # not a doubling of 128
+    (dict(vmem_bytes=32 << 20), "pallas-vmem-budget"),
 ])
 def test_budget_rejects_known_bad(cfg, rule):
     findings = pallas_budget.check_config(**cfg)
@@ -157,6 +160,8 @@ def test_budget_rejects_known_bad(cfg, rule):
     dict(block=(8, 128)),
     dict(block=(16, 128)),
     dict(K=8, F=128),
+    dict(f_top=4096),               # the production top rung
+    dict(f_top=256, vmem_bytes=8 << 20),
 ])
 def test_budget_accepts_known_good(cfg):
     assert pallas_budget.check_config(**cfg) == []
@@ -173,6 +178,21 @@ def test_budget_grid_steps_are_the_dim_product():
     assert [f.rule for f in fs] == ["pallas-grid-steps"]
     assert pallas_budget.scan_file(
         "<mem>", src.replace("(64, 64)", "(8, 128)")) == []
+
+
+def test_budget_counts_the_wide_rungs_vmem():
+    """Every production tier's kernel fits the scoped VMEM budget with
+    the top rung's sort buffer; a (16,128)-tier kernel asks the
+    most."""
+    from comdb2_tpu.checker import pallas_seg as PS
+
+    tiers = pallas_budget.production_tiers()
+    sizes = {spec: pallas_budget.kernel_vmem_bytes(spec)
+             for _, _, _, spec in tiers}
+    assert max(sizes.values()) <= pallas_budget.VMEM_BUDGET_BYTES
+    widest = max(sizes, key=sizes.get)
+    assert widest.rows == 2 * PS.ROWS
+    assert pallas_budget.check_production() == []
 
 
 def test_budget_table_artifact():

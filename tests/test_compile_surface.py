@@ -131,7 +131,7 @@ def test_carry_reset_lowers_with_its_declared_signature():
         reset(ws, jnp.asarray(PS._init_stat()))
     recs = [r for r in g.records if r.name == "carry_reset"]
     assert [r.shapes for r in recs] == [
-        ((spec.rows, 128),) * spec.n_words + ((1, 128),)]
+        ((PS.carry_rows(spec), 128),) * spec.n_words + ((1, 128),)]
     assert not g.offenders(CS.static_inventory())
 
 

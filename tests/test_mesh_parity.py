@@ -345,30 +345,37 @@ def test_stream_sharded_single_dispatch_counters(interpret_kernel):
 
 
 def test_escalation_mid_batch_on_one_shard(interpret_kernel):
-    """One shard's history overflows the kernel's fixed F=128 while
-    the other shards stay clean: exactly that history re-runs through
-    the XLA sharded engine at the caller's F and every verdict stays
-    bit-identical with the all-XLA run."""
+    """One shard's history overflows the kernel's top frontier rung
+    (F_TOP) while the other shards stay clean: exactly that history
+    re-runs through the XLA sharded engine at the caller's larger F
+    and every verdict stays bit-identical with the all-XLA run."""
     from comdb2_tpu.ops import op as O
 
     def overflow_history(k):
-        h = [O.invoke(p, "write", p) for p in range(k)]
-        h += [O.ok(p, "write", p) for p in range(k)]
+        # 8 writes pending, the first returns, k - 8 more join: at
+        # most 8 invokes per segment, k - 1 writes pending together
+        h = [O.invoke(p, "write", p) for p in range(8)]
+        h += [O.ok(0, "write", 0)]
+        h += [O.invoke(p, "write", p) for p in range(8, k)]
+        h += [O.ok(p, "write", p) for p in range(1, k)]
         return h
 
     rng = random.Random(13)
     hs = [histgen.register_history(rng, n_procs=4, n_events=24,
                                    p_info=0.0) for _ in range(7)]
-    hs.append(overflow_history(6))          # 193-config closure
+    hs.append(overflow_history(11))         # 5248-config closure
+    F = 2 * PSEG.F_TOP
     batch = pack_batch(hs, M.cas_register())
     info: dict = {}
-    st, fa, n = check_batch(batch, F=256, mesh=_mesh(),
+    st, fa, n = check_batch(batch, F=F, mesh=_mesh(),
                             engine="stream", info=info)
     assert info["engine"] == "stream-sharded"
     esc = info.get("escalated")
     assert esc and esc["count"] == 1 and esc["engine"], info
-    solo = check_batch(pack_batch(hs, M.cas_register()), F=256,
-                       engine="keys")
+    # 11 processes pad the batch's slot axis to 16, past the keys
+    # engine's 62-bit layout: the MXU engine is the all-XLA oracle
+    solo = check_batch(pack_batch(hs, M.cas_register()), F=F,
+                       engine="mxu")
     np.testing.assert_array_equal(st, solo[0])
     np.testing.assert_array_equal(fa, solo[1])
 

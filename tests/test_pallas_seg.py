@@ -73,7 +73,10 @@ def test_initial_frontier_layout():
         spec = PS.spec_for(4, 4, P, 2)
         ws = PS.initial_frontier(spec)
         assert len(ws) == spec.n_words
-        assert ws[0].shape == (spec.rows, PS.LANES)
+        # the rung-0 buffer, then the rows a wide frontier crosses
+        # chunk calls in
+        assert ws[0].shape == (spec.rows + PS.WIDE_ROWS, PS.LANES)
+        assert PS.carry_rows(spec) == ws[0].shape[0]
         # exactly one valid lane (the top word carries the sentinel)
         assert int((ws[-1] < PS.SENT_HI).sum()) == 1
         # every slot field of the root config reads IDLE (1)
@@ -136,9 +139,10 @@ def test_check_batch_stream_engine_falls_back_on_cpu():
 
 
 def test_check_batch_stream_unknown_escalates(monkeypatch):
-    """Kernel UNKNOWNs (its frontier is fixed at 128) must be re-run
-    through the XLA engines at the caller's requested F, not surfaced
-    as spurious unknowns."""
+    """Kernel UNKNOWNs (past its top frontier rung) must be re-run
+    through the XLA engines at the caller's requested F when that
+    exceeds the top rung, not surfaced as spurious unknowns. The top
+    rung is shrunk to 128 here so a small F=256 request escalates."""
     import random
 
     import histgen
@@ -156,10 +160,12 @@ def test_check_batch_stream_unknown_escalates(monkeypatch):
         # pipeline slice: slice-local indices are batch indices)
         import numpy as np
 
-        res = np.array([[2, 0, 0] if b == 2 else [0, -1, 1]
-                        for b in range(len(segs_list))], np.int32)
+        res = np.zeros((len(segs_list), PS.LANES), np.int32)
+        res[:, :3] = [[2, 0, 0] if b == 2 else [0, -1, 1]
+                      for b in range(len(segs_list))]
         return res, np.zeros(len(segs_list), np.int64)
 
+    monkeypatch.setattr(B.PSEG, "F_TOP", PS.F)
     monkeypatch.setattr(B.PSEG, "available", lambda: True)
     monkeypatch.setattr(B.PSEG, "stream_dispatch", fake_dispatch)
     st, fa, n = B.check_batch(batch, F=256, engine="stream")

@@ -159,14 +159,17 @@ def test_f_escalation_wiring_with_fake_stream_engine(monkeypatch):
         # history 2 "overflows the kernel frontier"; others check out
         # (4 histories = one pipeline slice, so slice-local indices
         # are batch indices)
-        res = np.zeros((len(segs_list), 3), np.int32)
+        res = np.zeros((len(segs_list), PSEG.LANES), np.int32)
         for i in range(len(segs_list)):
             if i == 2:
-                res[i] = (LJ.UNKNOWN, -1, 0)
+                res[i, :3] = (LJ.UNKNOWN, -1, 0)
             else:
-                res[i] = (int(solo[i][0][0]), -1, int(solo[i][2][0]))
+                res[i, :3] = (int(solo[i][0][0]), -1,
+                              int(solo[i][2][0]))
         return res, np.zeros(len(segs_list), np.int64)
 
+    # the kernel's top rung shrunk to 128: F=1024 then lies above it
+    monkeypatch.setattr(PSEG, "F_TOP", PSEG.F)
     monkeypatch.setattr(PSEG, "available", lambda: True)
     monkeypatch.setattr(PSEG, "stream_dispatch", fake_dispatch)
 
@@ -179,7 +182,7 @@ def test_f_escalation_wiring_with_fake_stream_engine(monkeypatch):
     assert info.get("escalated", {}).get("count") == 1, info
     assert info["escalated"]["engine"] in ("keys", "flat", "vmap")
 
-    # at F == kernel budget there is nothing to escalate: the UNKNOWN
+    # at F == the top rung there is nothing to escalate: the UNKNOWN
     # must surface as-is (re-running at the same budget could only
     # reproduce the overflow)
     info2: dict = {}

@@ -77,7 +77,7 @@ def _scan_args(spec, b_pad, sharding, lead=()):
         return jax.ShapeDtypeStruct(shape, I32, sharding=shd)
 
     return (s(lead + (2, spec.chunk, seg_w)),
-            tuple(s(lead + (spec.rows, PS.LANES))
+            tuple(s(lead + (PS.carry_rows(spec), PS.LANES))
                   for _ in range(spec.n_words)),
             s(lead + (1, PS.LANES)), s(lead + (b_pad, PS.LANES)),
             s((spec.table_rows_pad, PS.LANES), rep), s((), rep))
