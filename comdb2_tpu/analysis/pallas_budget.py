@@ -143,15 +143,13 @@ def check_config(*, grid_steps: Optional[int] = None,
 
 def kernel_vmem_bytes(spec, b_pad: Optional[int] = None) -> int:
     """VMEM the fused kernel asks for at ``spec``: its scratch (rung-0
-    buffer, wide frontier and its entry copy, the top rung's sort
-    buffer) plus its blocks, double-buffered (key-word carries in and
-    out, stat rows, results of ``b_pad`` histories, the table)."""
+    buffer, wide frontier, the top rung's sort buffer) plus its blocks,
+    double-buffered (key-word carries in and out, stat rows, results of
+    ``b_pad`` histories, the table)."""
     PS = _pallas_seg()
     row = PS.LANES * 4
     b_pad = PS.MAX_STREAM_B if b_pad is None else b_pad
-    scratch = spec.n_words * row * (
-        spec.rows + 2 * PS.WIDE_ROWS
-        + PS.rung_blocks(spec.P, PS.WIDE_RUNGS) * PS.ROWS)
+    scratch = spec.n_words * row * sum(PS.scratch_rows(spec))
     blocks = row * (2 * spec.n_words * PS.carry_rows(spec)
                     + 2 + 2 * b_pad + spec.table_rows_pad)
     return scratch + 2 * blocks

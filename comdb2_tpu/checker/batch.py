@@ -498,7 +498,8 @@ def _stream_collect(pending, B):
     the failed scan's carries were recycled at dispatch time and must
     not seed the next same-shape dispatch. The ``batch.collect`` span
     carries the slices' rung counters (``rung_segments``,
-    ``past_top``), read back with the verdicts."""
+    ``past_top``, ``rung_handovers``, ``iters_carried``), read back
+    with the verdicts."""
     rs: list = [None] * B
     rows: list = []
     with _obs.span("batch.collect") as sp:

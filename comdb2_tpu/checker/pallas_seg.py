@@ -37,18 +37,22 @@ the top rung holds => UNKNOWN (the reference's OOM-abort contract,
 ``linear.clj:318-326``).
 
 Frontier rungs. Rung 0 is the buffer's row 0 (F = 128) and serves
-almost every segment. A segment whose closure overflows it is redone
-from its entry frontier on the wide rungs, r = 1..WIDE_RUNGS, which
-hold ``128 << r`` configs (256 .. 4096) in a block-structured VMEM
+almost every segment. The wide rungs, r = 1..WIDE_RUNGS, hold
+``128 << r`` configs (256 .. 4096) in a block-structured VMEM
 scratch: the frontier in its first ``2**r`` rows, candidate chunk q
 in the next ``2**r`` rows each, sorted by a bitonic network over
 (8,128) blocks (per-block passes, then cross-block compare-exchanges
-at a runtime block count) — still the exact sort-adjacency dedup. A
-rung that overflows hands the segment to the next; the next segment
-returns to rung 0 as soon as its frontier fits 128. The rung sizes
-are runtime values, so every rung shares one compiled kernel; a
-frontier wider than 128 crosses chunk calls in the carry's extra
-``WIDE_ROWS`` rows.
+at a runtime block count) — still the exact sort-adjacency dedup.
+Rung handover: the closure is monotone (S_{j+1} = S_j ∪ expand(S_j)),
+and a rung's sort buffer holds the frontier and all P candidate
+chunks, so a rung that overflows at iteration k has the whole union
+S_{k+1} deduped in hand. It carries that union, compacted, to the
+smallest rung that holds it, and the closure goes on there at
+iteration k+1; nothing is redone from the segment's entry frontier.
+Past the top rung the segment is UNKNOWN. The next segment returns to
+rung 0 as soon as its frontier fits 128. The rung sizes are runtime
+values, so every rung shares one compiled kernel; a frontier wider
+than 128 crosses chunk calls in the carry's extra ``WIDE_ROWS`` rows.
 Falls back unavailable (see :func:`spec_for`) when P, the key budget,
 or the table don't fit — the driver then uses the XLA engines.
 """
@@ -71,11 +75,17 @@ F_TOP = F << WIDE_RUNGS   # the top rung's capacity (4096)
 WIDE_ROWS = F_TOP // LANES        # carry rows of a wide frontier
 WIDE_BLOCKS = WIDE_ROWS // ROWS   # ... in (8,128) blocks
 #: stat-row lanes: segments finished on rung r (r = 1..WIDE_RUNGS) at
-#: STAT_RUNGS + r - 1, segments past the top rung at STAT_PAST_TOP
+#: STAT_RUNGS + r - 1, segments past the top rung at STAT_PAST_TOP,
+#: closed unions handed to a wider rung at STAT_HANDOVERS and the
+#: closure iterations they carried (not redone) at STAT_ITERS_CARRIED
 STAT_RUNGS = 4
 STAT_PAST_TOP = STAT_RUNGS + WIDE_RUNGS
+STAT_HANDOVERS = STAT_PAST_TOP + 1
+STAT_ITERS_CARRIED = STAT_PAST_TOP + 2
+#: per-history counters: the stat lanes STAT_RUNGS .. STAT_ITERS_CARRIED
+N_COUNTERS = STAT_ITERS_CARRIED - STAT_RUNGS + 1
 #: stat-row lane: invoke-only rows scanned so far (see INVOKE_ONLY)
-STAT_INV = STAT_PAST_TOP + 1
+STAT_INV = STAT_ITERS_CARRIED + 1
 K_MAX = 8                 # invokes per kernel row (SMEM row width)
 CHUNK = 1024              # segments per kernel call. SMEM-bounded in
                           # TWO ways: the scalar-prefetch array
@@ -290,6 +300,14 @@ def carry_rows(spec: SegKernelSpec) -> int:
     return spec.rows + WIDE_ROWS
 
 
+def scratch_rows(spec: SegKernelSpec) -> tuple:
+    """Rows of the kernel's VMEM scratch per key word: the (rows,128)
+    rung-0 buffer, the wide frontier (the top rung's ``WIDE_ROWS``),
+    and the top rung's sort buffer."""
+    return (spec.rows, WIDE_ROWS,
+            rung_blocks(spec.P, WIDE_RUNGS) * ROWS)
+
+
 def rung_blocks(P: int, r: int) -> int:
     """(8,128) blocks of rung ``r``'s sort buffer: the frontier's
     ``2**r`` rows and P candidate chunks of as many, padded to a power
@@ -312,9 +330,10 @@ def initial_frontier(spec: SegKernelSpec):
 
 def _init_stat() -> np.ndarray:
     """Initial (1, 128) stat row: [status, fail, n, hist-counter] in
-    lanes 0..3, then the per-rung segment counters (STAT_RUNGS ..
-    STAT_PAST_TOP) and the invoke-only rows scanned (STAT_INV), all
-    zero — the layout the kernel's sstat load/flush assumes."""
+    lanes 0..3, then the per-history counters (rung segments, past the
+    top, handovers, iterations carried: STAT_RUNGS ..
+    STAT_ITERS_CARRIED) and the invoke-only rows scanned (STAT_INV),
+    all zero — the layout the kernel's sstat load/flush assumes."""
     stat0 = np.zeros((1, LANES), np.int32)
     stat0[0, 0] = VALID
     stat0[0, 1] = -1
@@ -734,10 +753,13 @@ def _build_kernel(spec: SegKernelSpec):
     results buffer.
 
     Rungs: a segment whose entry frontier fits F runs on rung 0, the
-    (rows,128) buffer's row 0. A rung-0 overflow commits nothing and
-    the same grid step redoes the segment from its entry frontier on
-    the wide rungs (``wide_segment``); a frontier left wider than F
-    stays in the wide scratch, and the next segment starts there."""
+    (rows,128) buffer's row 0. When its closure overflows at iteration
+    k, the deduped union S_{k+1} is already sorted compact in the
+    buffer: the same grid step hands it to the wide frontier and goes
+    on at iteration k+1 on the smallest wide rung that holds it, and a
+    wide rung that overflows hands its union on the same way. A
+    frontier left wider than F stays in the wide scratch, and the next
+    segment starts there."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -749,13 +771,16 @@ def _build_kernel(spec: SegKernelSpec):
     LOG_NB = NB[R].bit_length() - 1
 
     root = _root_key(spec)
-    # SMEM slots past the rung-0 ones (0..6): the wide rungs' request,
-    # done, continue, overflow, count, changed, ok-count and final rung,
-    # the invoke-only rows scanned, then the counters (rung 1..R, past
-    # the top) from S_CNT
-    S_REQ, S_DONE, S_CONT, S_OVF, S_NCUR, S_CHG, S_NOK, S_RUNG, S_INV = \
-        range(7, 16)
-    S_CNT = 16
+    # SMEM slots past the rung-0 ones (0..6): the iteration the wide
+    # rungs start or go on at (> 0: a handed-over union waits in the
+    # wide frontier), continue (the last iteration grew the union),
+    # count, ok-count, the rung that holds the segment (R + 1: past the
+    # top), the invoke-only rows scanned, then the N_COUNTERS
+    # per-history counters from S_CNT
+    S_IT, S_CONT, S_NCUR, S_NOK, S_RUNG, S_INV = range(7, 13)
+    S_CNT = 13
+    S_HAND = S_CNT + STAT_HANDOVERS - STAT_RUNGS
+    S_CARRIED = S_CNT + STAT_ITERS_CARRIED - STAT_RUNGS
 
     def _at(b):
         o = b * ROWS
@@ -776,17 +801,16 @@ def _build_kernel(spec: SegKernelSpec):
     def kernel(seg_ref, off_ref, *refs):
         # refs: W word carries in, st_in, res_in, tab_ref,
         #       W word carries out, st_out, res_out,
-        #       W rung-0 VMEM word scratch, W wide frontier, W its
-        #       entry copy, W wide sort buffer, sstat SMEM
+        #       W rung-0 VMEM word scratch, W wide frontier,
+        #       W wide sort buffer, sstat SMEM
         ws_in = refs[:W]
         st_in, res_in, tab_ref = refs[W], refs[W + 1], refs[W + 2]
         ws_out = refs[W + 3:2 * W + 3]
         st_out, res_out = refs[2 * W + 3], refs[2 * W + 4]
         wsc = refs[2 * W + 5:3 * W + 5]
         fs = refs[3 * W + 5:4 * W + 5]
-        es = refs[4 * W + 5:5 * W + 5]
-        buf = refs[5 * W + 5:6 * W + 5]
-        sstat = refs[6 * W + 5]
+        buf = refs[4 * W + 5:5 * W + 5]
+        sstat = refs[5 * W + 5]
         i = pl.program_id(0)
 
         def blk(ref, b):
@@ -796,13 +820,13 @@ def _build_kernel(spec: SegKernelSpec):
             ref[_at(b), :] = v
 
         def stat_row(lanes):
-            """(1,128) row: ``lanes`` {lane: scalar}, then the rung
-            counters; 0 elsewhere."""
+            """(1,128) row: ``lanes`` {lane: scalar}, then the
+            per-history counters; 0 elsewhere."""
             _, lane, _ = _iotas(rows)
             lane = lane[0:1, :]
             out = jnp.zeros((1, LANES), jnp.int32)
             cells = dict(lanes)
-            for c in range(R + 1):
+            for c in range(N_COUNTERS):
                 cells[STAT_RUNGS + c] = sstat[S_CNT + c]
             for ln, v in cells.items():
                 out = jnp.where(lane == ln, v, out)
@@ -825,7 +849,7 @@ def _build_kernel(spec: SegKernelSpec):
             sstat[2] = st_in[0, 2]      # frontier count
             sstat[6] = st_in[0, 3]      # history counter (stream mode)
             sstat[S_INV] = st_in[0, STAT_INV]
-            for c in range(R + 1):
+            for c in range(N_COUNTERS):
                 sstat[S_CNT + c] = st_in[0, STAT_RUNGS + c]
 
         ok_p = seg_ref[i, 0]
@@ -848,7 +872,7 @@ def _build_kernel(spec: SegKernelSpec):
             sstat[0] = VALID
             sstat[1] = -1
             sstat[2] = 1
-            for c in range(R + 1):
+            for c in range(N_COUNTERS):
                 sstat[S_CNT + c] = 0
             at_root = (row == 0) & (lane == 0)
             for w in range(W):
@@ -858,7 +882,7 @@ def _build_kernel(spec: SegKernelSpec):
 
         live = (sstat[0] == VALID) & (ok_p >= 0)
         wide_in = sstat[2] > F
-        sstat[S_REQ] = 0
+        sstat[S_IT] = 0
 
         @pl.when(live & jnp.logical_not(wide_in))
         def _():
@@ -886,7 +910,8 @@ def _build_kernel(spec: SegKernelSpec):
                 lambda a: a, tuple(ws)))
 
             # --- closure: bounded fixed point ------------------------
-            # sstat[3]: continue flag, sstat[4]: overflow, sstat[5]: n
+            # sstat[3]: continue flag, sstat[4]: k + 1 after an
+            # overflow at iteration k (0: none), sstat[5]: n
             sstat[3] = 1
             sstat[4] = 0
             sstat[5] = sstat[2]
@@ -931,7 +956,9 @@ def _build_kernel(spec: SegKernelSpec):
                     ews, n2, ext = list(out[:W]), out[W], out[W + 1]
                     ovf = (n2 > F).astype(jnp.int32)
                     changed = (n2 > sstat[5]).astype(jnp.int32)
-                    sstat[4] = sstat[4] | ovf
+                    # an overflow ends the loop, so this store is
+                    # the last one that runs
+                    sstat[4] = ovf * (it + 1)
                     sstat[3] = changed & (1 - ovf)
                     sstat[5] = n2
 
@@ -963,6 +990,7 @@ def _build_kernel(spec: SegKernelSpec):
                                 tuple(cws))
 
             ws = list(lax.fori_loop(0, depth, body, tuple(ws)))
+            closed = ws
 
             # --- ok filter: keep configs whose ok-slot linearized ----
             tq_ok = _slot_field_runtime(spec, ws, ok_p)
@@ -976,13 +1004,7 @@ def _build_kernel(spec: SegKernelSpec):
             # (see the closure-entry cond above); unconditional
             # re-sorting here cost 28 stages on every segment
 
-            # an overflow commits nothing: the entry frontier stays in
-            # the scratch for the wide rungs to redo the segment from
-            ovf = sstat[4] == 1
-            sstat[S_REQ] = sstat[4]
-
-            @pl.when(jnp.logical_not(ovf))
-            def _():
+            def commit():
                 st_new = jnp.where(n2 == 0, INVALID, VALID)
                 sstat[1] = jnp.where(st_new == VALID, sstat[1],
                                      seg_no)
@@ -991,11 +1013,35 @@ def _build_kernel(spec: SegKernelSpec):
                 for w in range(W):
                     wsc[w][:] = ws[w]
 
+            def handover():
+                # an overflow at iteration k left the closed union
+                # S_{k+1} sorted compact in the buffer (the growth
+                # compaction): it becomes the wide frontier, and the
+                # wide rungs go on at iteration k+1
+                sent = _sent_blocks(WIDE_ROWS - rows)
+                for w in range(W):
+                    fs[w][0:rows, :] = closed[w]
+                    fs[w][rows:WIDE_ROWS, :] = sent[w]
+                sstat[S_IT] = sstat[4]
+                sstat[S_NCUR] = sstat[5]
+                sstat[S_HAND] = sstat[S_HAND] + 1
+                sstat[S_CARRIED] = sstat[S_CARRIED] + sstat[4]
+
+            lax.cond(sstat[4] > 0, handover, commit)
+
         # --- the wide rungs (r = 1..R, 128 << r configs) ---------------
         # The frontier sits compact in the first 2**r rows of ``fs``
         # (positions past its count hold the sentinel); rung r's sort
         # buffer ``buf`` holds it and P candidate chunks of 2**r rows
         # each, NB[r] (8,128) blocks in all.
+
+        def rung_of(n):
+            """The smallest wide rung that holds ``n`` configs (R + 1
+            past the top)."""
+            r = jnp.int32(1)
+            for rr in range(1, R + 1):
+                r = r + jnp.where(n > (F << rr), 1, 0)
+            return r
 
         def copy_blocks(src, dst, nblk):
             def body(b, c):
@@ -1127,7 +1173,6 @@ def _build_kernel(spec: SegKernelSpec):
                                     seg_ref, i, True)
                 for w in range(W):
                     put(fs[w], b, ws[w])
-                    put(es[w], b, ws[w])
                 return c
             lax.fori_loop(0, WIDE_BLOCKS, body, 0)
 
@@ -1151,48 +1196,34 @@ def _build_kernel(spec: SegKernelSpec):
         def _():
             wide_invokes()
 
-        @pl.when(live & (wide_in | (sstat[S_REQ] == 1)))
+        @pl.when(live & (wide_in | (sstat[S_IT] > 0)))
         def _():
             table = tab_ref[:]
             stride = off_ref[1]
 
-            @pl.when(jnp.logical_not(wide_in))
+            @pl.when(wide_in)
             def _():
-                # from rung 0: its row 0 (scattered is fine — every
-                # wide step is sentinel-mask-based) becomes block 0
-                row, _, _ = _iotas(ROWS)
-                clear_wide()
-                sent = _sent_blocks(ROWS)
-                for w in range(W):
-                    put(fs[w], 0, jnp.where(
-                        row == 0,
-                        jnp.broadcast_to(wsc[w][0:1, :], (ROWS, LANES)),
-                        sent[w]))
+                wide_invokes()
+                sstat[S_NCUR] = sstat[2]
 
-            wide_invokes()
-            n_entry = sstat[2]
-            r0 = jnp.int32(1)
-            for rr in range(1, R + 1):
-                r0 = r0 + jnp.where(n_entry > (F << rr), 1, 0)
-            sstat[S_DONE] = 0
+            sstat[S_RUNG] = rung_of(sstat[S_NCUR])
+            sstat[S_CONT] = 1
 
             def rung(r, c):
-                @pl.when((sstat[S_DONE] == 0) & (r >= r0))
+                @pl.when(sstat[S_RUNG] == r)
                 def _():
                     nb, fb = _sel(NB, r), _sel(FB, r)
                     cap = jnp.left_shift(jnp.int32(F), r)
-                    copy_blocks(es, fs, fb)
-                    sstat[S_CONT] = 1
-                    sstat[S_OVF] = 0
-                    sstat[S_NCUR] = n_entry
-                    sstat[S_CHG] = 0
 
                     def step(it, c2):
-                        # iterations 0..depth-1: the closure, until a
-                        # fixed point or an overflow; iteration depth:
-                        # the ok filter, when the rung held
-                        closure = (it < depth) & (sstat[S_CONT] == 1)
-                        final = (it == depth) & (sstat[S_OVF] == 0)
+                        # iterations up to depth-1: the closure, until a
+                        # fixed point or an overflow, which hands the
+                        # union to a wider rung; iteration depth: the
+                        # ok filter, on the rung that held
+                        here = sstat[S_RUNG] == r
+                        closure = here & (it < depth) & \
+                            (sstat[S_CONT] == 1)
+                        final = here & (it == depth)
 
                         @pl.when(closure)
                         def _():
@@ -1206,40 +1237,44 @@ def _build_kernel(spec: SegKernelSpec):
 
                         def sort_pass(ps, c3):
                             # pass 0 sorts (then dedups, in the
-                            # closure); pass 1 compacts a grown union
+                            # closure); pass 1 compacts a grown union,
+                            # the one handed over included
                             @pl.when(((ps == 0) & (closure | final))
                                      | ((ps == 1) & closure
-                                        & (sstat[S_CHG] == 1)))
+                                        & (sstat[S_CONT] == 1)))
                             def _():
                                 sort_blocks(nbx)
 
                             @pl.when((ps == 0) & closure)
                             def _():
                                 n2 = dedup(nb)
-                                ovf = n2 > cap
-                                chg = (n2 > sstat[S_NCUR]) & \
-                                    jnp.logical_not(ovf)
-                                sstat[S_OVF] = ovf.astype(jnp.int32)
-                                sstat[S_CHG] = chg.astype(jnp.int32)
-                                sstat[S_CONT] = chg.astype(jnp.int32)
+                                sstat[S_CONT] = (n2 > sstat[S_NCUR]).astype(
+                                    jnp.int32)
                                 sstat[S_NCUR] = n2
+                                # an overflow moves the segment to the
+                                # smallest rung that holds n2 (R + 1:
+                                # past the top), at iteration it + 1
+                                r2 = jnp.where(n2 > cap, rung_of(n2), r)
+                                sstat[S_RUNG] = r2
+                                sstat[S_IT] = it + 1
+                                hand = ((r2 != r) & (r2 <= R)).astype(
+                                    jnp.int32)
+                                sstat[S_HAND] = sstat[S_HAND] + hand
+                                sstat[S_CARRIED] = sstat[S_CARRIED] + \
+                                    hand * (it + 1)
                             return c3
                         lax.fori_loop(0, 2, sort_pass, 0)
 
-                        @pl.when((closure & (sstat[S_CHG] == 1)) | final)
+                        @pl.when((closure & (sstat[S_CONT] == 1)
+                                  & (sstat[S_RUNG] <= R)) | final)
                         def _():
-                            copy_blocks(buf, fs, fb)
-
-                        @pl.when(final)
-                        def _():
-                            sstat[S_DONE] = 1
-                            sstat[S_RUNG] = r
+                            copy_blocks(buf, fs, _sel(FB, sstat[S_RUNG]))
                         return c2
-                    lax.fori_loop(0, depth + 1, step, 0)
+                    lax.fori_loop(sstat[S_IT], depth + 1, step, 0)
                 return c
             lax.fori_loop(1, R + 1, rung, 0)
 
-            done = sstat[S_DONE] == 1
+            done = sstat[S_RUNG] <= R
 
             @pl.when(jnp.logical_not(done))
             def _():
@@ -1333,10 +1368,8 @@ def _chunk_call(spec: SegKernelSpec, b_pad: int = 8):
             pl.BlockSpec((1, LANES), lambda i, *s: (0, 0)),
             pl.BlockSpec((b_pad, LANES), lambda i, *s: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)] * W
-        + [pltpu.VMEM((WIDE_ROWS, LANES), jnp.int32)] * (2 * W)
-        + [pltpu.VMEM((rung_blocks(spec.P, WIDE_RUNGS) * ROWS, LANES),
-                      jnp.int32)] * W
+        scratch_shapes=[pltpu.VMEM((n, LANES), jnp.int32)
+                        for n in scratch_rows(spec) for _ in range(W)]
         + [pltpu.SMEM((32,), jnp.int32)])
 
     word_shape = jax.ShapeDtypeStruct((c_rows, LANES), jnp.int32)
@@ -1595,11 +1628,14 @@ def plan_shard_slices(B: int, D: int,
 
 
 #: segments finished on each wide rung (index r-1 for rung r) and past
-#: the top rung, summed over every verdict read back this process —
-#: the counters ride in the stat/results rows, so reading them costs
-#: no extra readback
+#: the top rung, closed unions handed to a wider rung, and the closure
+#: iterations those handovers carried (not redone), summed over every
+#: verdict read back this process — the counters ride in the
+#: stat/results rows, so reading them costs no extra readback
 RUNG_SEGMENTS = [0] * WIDE_RUNGS
 PAST_TOP = 0
+RUNG_HANDOVERS = 0
+ITERS_CARRIED = 0
 
 
 def rung_counts(rows: np.ndarray) -> np.ndarray:
@@ -1611,16 +1647,23 @@ def rung_counts(rows: np.ndarray) -> np.ndarray:
 
 def note_rungs(rows: np.ndarray, sp=None) -> np.ndarray:
     """Add read-back rows' rung counters to the process totals and
-    report them (``rung_segments`` per wide rung, ``past_top``): on
-    span ``sp`` when given, else as a zero-length ``pallas.rungs``
-    span. Returns the summed counters."""
-    global PAST_TOP
+    report them (``rung_segments`` per wide rung, ``past_top``,
+    ``rung_handovers``, ``iters_carried``): on span ``sp`` when given,
+    else as a zero-length ``pallas.rungs`` span. Returns the summed
+    rung counters (:func:`rung_counts`)."""
+    global PAST_TOP, RUNG_HANDOVERS, ITERS_CARRIED
+    rows = np.asarray(rows)
     tot = rung_counts(rows).sum(axis=0)
+    hand = int(rows[:, STAT_HANDOVERS].sum())
+    carried = int(rows[:, STAT_ITERS_CARRIED].sum())
     for r in range(WIDE_RUNGS):
         RUNG_SEGMENTS[r] += int(tot[r])
     PAST_TOP += int(tot[WIDE_RUNGS])
+    RUNG_HANDOVERS += hand
+    ITERS_CARRIED += carried
     args = {"rung_segments": [int(x) for x in tot[:WIDE_RUNGS]],
-            "past_top": int(tot[WIDE_RUNGS])}
+            "past_top": int(tot[WIDE_RUNGS]),
+            "rung_handovers": hand, "iters_carried": carried}
     if sp is not None:
         sp.set(**args)
     else:

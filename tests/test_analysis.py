@@ -193,6 +193,14 @@ def test_budget_counts_the_wide_rungs_vmem():
     widest = max(sizes, key=sizes.get)
     assert widest.rows == 2 * PS.ROWS
     assert pallas_budget.check_production() == []
+    # per key word: rung 0's 16 rows, the wide frontier's 32 and the
+    # top rung's 64-block sort buffer at P = 10; no entry copy
+    spec = PS.spec_for(8, 14, 10, 8)
+    assert PS.scratch_rows(spec) == (16, 32, 512)
+    row = PS.LANES * 4
+    blocks = row * (2 * 2 * (16 + 32) + 2 + 2 * 8 + spec.table_rows_pad)
+    assert pallas_budget.kernel_vmem_bytes(spec, b_pad=8) == \
+        2 * row * (16 + 32 + 512) + 2 * blocks
 
 
 def test_budget_table_artifact():

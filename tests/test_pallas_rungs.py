@@ -1,8 +1,9 @@
 """The fused kernel's frontier rungs, in Pallas interpret mode on the
 CPU: rung 0 (F = 128) answers exactly as the kernel did before the
-wide rungs existed, a segment whose closure passes 128 is redone on a
-wide rung and keeps an exact verdict, and past the top rung the
-segment says UNKNOWN and the checker's ladder still answers exactly.
+wide rungs existed, a segment whose closure passes 128 hands its
+closed union to the wide rung that holds it and keeps an exact
+verdict, and past the top rung the segment says UNKNOWN and the
+checker's ladder still answers exactly.
 Verdicts and fail indices are held to the benchmark's plain reference
 (``benchmark/reference.py``, ``benchmark/reference_keyed.py``), which
 imports nothing of the program. Interpret compiles cost tens of
@@ -43,12 +44,12 @@ def _plain(history):
     return [(op.process, op.type, op.f, op.value) for op in history]
 
 
-def _closure_sizes(history, full=False):
+def _closure_sizes(history):
     """The size of the closed configuration set at each ok completion,
     by the reference's own search: its step function and closure, as
     ``reference.check`` runs them. The reference stops expanding a
-    configuration once it linearized the returning call; ``full``
-    expands every configuration, as the kernel does, so it counts at
+    configuration once it linearized the returning call; the kernel
+    expands every configuration (``_closure_levels``), so it counts at
     least as many."""
     configs = {(None, frozenset())}
     calls, call_of, sizes = {}, {}, []
@@ -61,7 +62,7 @@ def _closure_sizes(history, full=False):
         seen, todo = set(configs), list(configs)
         while todo:
             state, done = todo.pop()
-            if c in done and not full:
+            if c in done:
                 continue
             for k, (kf, kv) in calls.items():
                 if k in done:
@@ -75,6 +76,77 @@ def _closure_sizes(history, full=False):
         configs = {(s, d - {c}) for s, d in seen if c in d}
         del calls[c]
     return sizes
+
+
+def _closure_levels(history):
+    """Per ok completion, ``(depth, levels)``: the pending calls, and
+    the size of the kernel's closure after each iteration (``levels[0]``
+    is the entry set; every pending call expands, up to ``depth``
+    iterations or a fixed point). Stops after an ok that leaves no
+    configuration. A call takes its completion's value, as the
+    kernel's transitions do."""
+    value, opened = {}, {}
+    for i, (p, t, _, v) in enumerate(history):
+        if t == "invoke":
+            value[i], opened[p] = v, i
+        else:
+            value[opened.pop(p)] = v
+    configs = {(None, frozenset())}
+    calls, call_of, out = {}, {}, []
+    for i, (p, t, f, v) in enumerate(history):
+        if t == "invoke":
+            calls[i] = (f, value[i])
+            call_of[p] = i
+            continue
+        c = call_of.pop(p)
+        closed, levels = set(configs), [len(configs)]
+        for _ in range(len(calls)):
+            grown = set(closed)
+            for state, done in closed:
+                for k, (kf, kv) in calls.items():
+                    legal, nxt = reference._step(state, kf, kv, None)
+                    if k not in done and legal:
+                        grown.add((nxt, done | {k}))
+            if len(grown) == len(closed):
+                break
+            closed = grown
+            levels.append(len(closed))
+        out.append((len(calls), levels))
+        configs = {(s, d - {c}) for s, d in closed if c in d}
+        del calls[c]
+        if not configs:
+            break
+    return out
+
+
+def _rung_of(n):
+    """The smallest wide rung that holds ``n`` configs (past the top:
+    WIDE_RUNGS + 1)."""
+    return next((r for r in range(1, PS.WIDE_RUNGS + 1) if PS.F << r >= n),
+                PS.WIDE_RUNGS + 1)
+
+
+def _rung_counters(history):
+    """The kernel's per-history counters, from the closure levels
+    alone: (segments per wide rung .. past the top, handovers,
+    iterations carried). A segment starts on the rung its entry set
+    needs; an overflow at iteration j hands the union to the rung that
+    holds it, carrying j + 1 iterations."""
+    rungs = [0] * (PS.WIDE_RUNGS + 1)
+    hand = carried = 0
+    for _, levels in _closure_levels(history):
+        r = 0 if levels[0] <= PS.F else _rung_of(levels[0])
+        for j, n in enumerate(levels[1:]):
+            if n > PS.F << r:
+                r = _rung_of(n)
+                if r > PS.WIDE_RUNGS:
+                    break
+                hand, carried = hand + 1, carried + j + 1
+        if r:
+            rungs[r - 1] += 1
+        if r > PS.WIDE_RUNGS:
+            break
+    return rungs, hand, carried
 
 
 def concurrent_writes(k: int, read_value=None):
@@ -93,30 +165,58 @@ def concurrent_writes(k: int, read_value=None):
     return h
 
 
-@pytest.mark.parametrize("k,invalid", [(8, False), (8, True), (12, False)],
-                         ids=["k8-wide-rung", "k8-invalid", "k12-past-top"])
+def overflow_at_depth():
+    """Five pending writes and a pending read of 0: the closure passes
+    128 only on its last iteration (124 configs, then 129), so rung 0
+    overflows at the depth bound and the wide rung that takes the
+    union runs only the ok filter. The read's invoke carries its value,
+    so the closure helpers see the kernel's transition."""
+    w = [0, 3, 2, 1, 4]
+    h = [O.invoke(p, "write", v) for p, v in enumerate(w)]
+    h += [O.invoke(5, "read", 0)]
+    h += [O.ok(p, "write", v) for p, v in enumerate(w)]
+    return h + [O.ok(5, "read", 0)]
+
+
+@pytest.mark.parametrize("k,invalid", [(8, False), (8, True), (12, False),
+                                       (0, False)],
+                         ids=["k8-wide-rung", "k8-invalid", "k12-past-top",
+                              "overflow-at-depth"])
 def test_concurrent_writes_cross_the_rungs(interpret_kernel, k, invalid):
     """k concurrent pending writes: by the reference's own search the
     closure passes 128 at k = 8 and passes the top rung at k = 12. The
-    wide rungs give the kernel's verdict at k = 8; at k = 12 the kernel
-    says UNKNOWN past its top rung, and ``Linearizable().check`` on the
-    device path still answers exactly, through the XLA ladder."""
+    wide rungs give the kernel's verdict at k = 8, where the first
+    segment's union is handed from rung 0 to rung 1, then to 3, then
+    to 4, at its last iteration; at k = 12 the kernel says UNKNOWN past
+    its top rung, and ``Linearizable().check`` on the device path still
+    answers exactly, through the XLA ladder. ``overflow-at-depth``
+    overflows rung 0 on its last closure iteration."""
     from comdb2_tpu.checker.checkers import Linearizable
 
-    h = concurrent_writes(k, read_value=99 if invalid else None)
+    h = (concurrent_writes(k, read_value=99 if invalid else None) if k
+         else overflow_at_depth())
     plain = _plain(h)
     peak = max(_closure_sizes(plain))
-    full = max(_closure_sizes(plain, full=True))
-    assert PS.F < peak <= full
-    assert (peak > PS.F_TOP) == (k == 12)
-    assert (full > PS.F_TOP) == (k == 12)
+    full = max(lv[-1] for _, lv in _closure_levels(plain))
+    if k:
+        assert PS.F < peak <= full
+        assert (peak > PS.F_TOP) == (k == 12)
+        assert (full > PS.F_TOP) == (k == 12)
+    else:
+        depth, levels = _closure_levels(plain)[0]
+        assert len(levels) == depth + 1
+        assert levels[-2] <= PS.F < levels[-1] == full
     want = reference.check(plain)
     rungs0, past0 = list(PS.RUNG_SEGMENTS), PS.PAST_TOP
+    hand0, carried0 = PS.RUNG_HANDOVERS, PS.ITERS_CARRIED
     got = Linearizable(backend="device").check({}, M.cas_register(), h)
     rungs = [a - b for a, b in zip(PS.RUNG_SEGMENTS, rungs0)]
+    hand = PS.RUNG_HANDOVERS - hand0
+    carried = PS.ITERS_CARRIED - carried0
     assert got["valid?"] is want[0]
     if not want[0]:
         assert got["op-index"] == want[1]
+    assert hand >= 1 and carried >= 1
     if k == 12:
         assert PS.PAST_TOP - past0 == 1
         assert got["engines_tried"][0] == {
@@ -126,10 +226,12 @@ def test_concurrent_writes_cross_the_rungs(interpret_kernel, k, invalid):
         assert PS.PAST_TOP == past0
         # the kernel's widest closure finished on the rung that holds
         # it, and no wider
-        r_full = next(r for r in range(1, PS.WIDE_RUNGS + 1)
-                      if PS.F << r >= full)
+        r_full = _rung_of(full)
         assert rungs[r_full - 1] >= 1 and not any(rungs[r_full:]), \
             (full, rungs)
+    if not k:
+        # one handover, carrying every iteration of the segment
+        assert (hand, carried) == (1, depth)
 
 
 def _keyed(seed, keys, ops, twin_key):
@@ -197,6 +299,7 @@ def _rung0_histories(tier):
 def test_rung0_outputs_are_the_pre_rung_kernels(interpret_kernel, tier):
     rows = RUNG0_TIERS[tier][2]
     rungs0 = list(PS.RUNG_SEGMENTS)
+    counters0 = (PS.RUNG_HANDOVERS, PS.ITERS_CARRIED)
     for h, want in zip(_rung0_histories(tier), RUNG0_GOLDEN[tier]):
         packed = pack_history(h)
         mm = make_memo(M.cas_register(), packed)
@@ -208,6 +311,7 @@ def test_rung0_outputs_are_the_pre_rung_kernels(interpret_kernel, tier):
                                    n_states=16, n_transitions=32, P=P)
         assert r == want
     assert PS.RUNG_SEGMENTS == rungs0          # no segment left rung 0
+    assert (PS.RUNG_HANDOVERS, PS.ITERS_CARRIED) == counters0
 
 
 def test_rung0_stream_outputs_are_the_pre_rung_kernels(interpret_kernel):
@@ -215,10 +319,57 @@ def test_rung0_stream_outputs_are_the_pre_rung_kernels(interpret_kernel):
 
     batch = pack_batch(_rung0_histories("p5"), M.cas_register())
     segs_list, P = _stream_segments(batch)
+    counters0 = (list(PS.RUNG_SEGMENTS), PS.PAST_TOP, PS.RUNG_HANDOVERS,
+                 PS.ITERS_CARRIED)
     got = PS.check_device_pallas_stream(
         batch.memo.succ, segs_list, P=P, n_states=batch.memo.n_states,
         n_transitions=batch.memo.n_transitions)
     assert [tuple(g) for g in got] == RUNG0_GOLDEN["p5"]
+    assert (list(PS.RUNG_SEGMENTS), PS.PAST_TOP, PS.RUNG_HANDOVERS,
+            PS.ITERS_CARRIED) == counters0
+
+
+def test_wide_stream_stat_rows_match_single_runs(interpret_kernel):
+    """Histories that go wide, one with nine invokes in a segment (so
+    invoke-only rows, as ``_wide_stream`` builds them), streamed in one
+    dispatch: each history's results row (status, fail, n and every
+    counter) equals its own stat row when it runs alone, and the
+    counters equal those the closure levels give (``_rung_counters``):
+    rungs, handovers and carried iterations, past the top included."""
+    import numpy as np
+
+    from comdb2_tpu.checker.batch import _stream_segments, pack_batch
+
+    nine = [O.invoke(p, "write", p) for p in range(9)]
+    nine += [O.ok(p, "write", p) for p in range(9)]
+    nine += [O.invoke(0, "read", None), O.ok(0, "read", 8)]
+    hs = [concurrent_writes(7), concurrent_writes(7, read_value=99),
+          overflow_at_depth(), nine, concurrent_writes(12)]
+    batch = pack_batch(hs, M.cas_register())
+    segs_list, P = _stream_segments(batch, k_pad=9)
+    mm = batch.memo
+    spec = PS.spec_for(mm.n_states, mm.n_transitions, P, PS.K_MAX)
+    res, starts = PS.stream_dispatch(mm.succ, segs_list, spec,
+                                     mm.n_states, mm.n_transitions)
+    res = np.asarray(res)
+    lanes = slice(PS.STAT_RUNGS, PS.STAT_ITERS_CARRIED + 1)
+    for b, (h, segs) in enumerate(zip(hs, segs_list)):
+        before = (list(PS.RUNG_SEGMENTS), PS.PAST_TOP, PS.RUNG_HANDOVERS,
+                  PS.ITERS_CARRIED)
+        alone = PS.check_device_pallas(
+            mm.succ, segs, n_states=mm.n_states,
+            n_transitions=mm.n_transitions, P=P)
+        counters = [a - c for a, c in zip(PS.RUNG_SEGMENTS, before[0])]
+        counters += [PS.PAST_TOP - before[1],
+                     PS.RUNG_HANDOVERS - before[2],
+                     PS.ITERS_CARRIED - before[3]]
+        assert PS.merge_stream_slice(res[b:b + 1], starts[b:b + 1],
+                                     1)[0] == alone, b
+        assert res[b, lanes].tolist() == counters, b
+        rungs, hand, carried = _rung_counters(_plain(h))
+        assert counters == rungs + [hand, carried], b
+    assert res[:len(hs), PS.STAT_HANDOVERS].all()
+    assert res[len(hs) - 1, PS.STAT_PAST_TOP] == 1
 
 
 @pytest.mark.parametrize("P", [1, 2, 7, 8, 10, 15])
