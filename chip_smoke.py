@@ -13,12 +13,12 @@ peak device bytes are informational, not metrics):
 
 - ``device``: the TPU the run holds.
 - ``north-star``: the 50k-op cas-register history of BASELINE.json
-  configs[3] (built as bench.py's 50k cell builds it) through
-  ``checker.linear.analysis`` at concurrency 5 and 10; each must come
+  configs[3] (``ops.synth.register_history``, two events per op)
+  through ``checker.linear.analysis`` at concurrency 5 and 10; each must come
   back valid from the fused kernel, and its seeded corrupted twin (one
   read of a never-written value) invalid.
 - ``batch``: 256 histories x 800 events through
-  ``check_batch(engine="auto")`` on the stream kernel, every 8th a
+  ``check_batch`` on the stream kernel, every 8th a
   corrupted twin; verdicts must match construction everywhere and the
   host oracle ``linear_host.check`` on a seeded sample.
 - ``served``: a ``VerifierDaemon`` on a thread, ``ServiceClient`` over
@@ -47,8 +47,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 N_OPS = 50_000                 # BASELINE.json configs[3]
-N_EVENTS = 2 * N_OPS           # bench.py's 50k cell
-B_HISTS, B_EVENTS = 256, 800   # bench.py's 256x cell
+N_EVENTS = 2 * N_OPS           # an invoke and a completion per op
+B_HISTS, B_EVENTS = 256, 800   # 256 histories x 400 ops
 MULTI_HISTS = 1024
 MULTI_DEVICES = 4
 TWIN_EVERY = 8                 # batch lanes i % 8 == 3 are corrupted
@@ -117,7 +117,7 @@ def corrupt(history, rng: random.Random):
 
 
 def register_batch(seed: int, n: int):
-    """bench.py's 256x histories, every TWIN_EVERY-th corrupted;
+    """The batch phase's histories, every TWIN_EVERY-th corrupted;
     returns (histories, corrupted lane mask)."""
     from comdb2_tpu.ops.synth import register_history
 
@@ -137,7 +137,7 @@ def phase_north_star(seed: int, n_events: int, devices) -> None:
     from comdb2_tpu.models.model import cas_register
     from comdb2_tpu.ops.synth import register_history
 
-    # (procs, max_pending): bench.py's p5 and p10 cells
+    # (procs, max_pending): concurrency 5, and 10 with at most 5 open
     for k, (procs, max_pending) in enumerate(((5, None), (10, 5))):
         with Phase("north-star", devices) as ph:
             rng = random.Random(seed + 100 + k)
@@ -175,7 +175,7 @@ def phase_batch(seed: int, n: int, devices) -> None:
         hs, bad = register_batch(seed + 200, n)
         batch = pack_batch(hs, cas_register())
         info: dict = {}
-        status, _, _ = check_batch(batch, engine="auto", info=info)
+        status, _, _ = check_batch(batch, info=info)
         status = np.asarray(status)
         want = np.where(bad, LJ.INVALID, LJ.VALID)
         rng = random.Random(seed + 201)

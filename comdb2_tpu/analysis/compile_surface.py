@@ -299,12 +299,9 @@ def static_inventory() -> Inventory:
     S = Axis("S", "pow2", 1, L["service_S"][1] << 4)
     K = Axis("K", "pow2", 1, 8)
     B = Axis("B", "pow2", *L["batch_B"])
-    n_pad = Axis("n_pad", "pow2", 1, L["service_n_pad"][1] << 4)
 
     xla_batch_seg = (
         (memo, memo), (S, B, K), (S, B, K), (S, B), (S,))
-    xla_batch_vmap = (
-        (memo, memo), (B, n_pad), (B, n_pad), (B, n_pad))
 
     n_chunks = Axis("n_chunks", "linear", 1, 1 << 16)
     chunk = Axis("chunk", "enum", values=L["kernel_chunks"] + (16,))
@@ -361,7 +358,7 @@ def static_inventory() -> Inventory:
     # segment, so the engine's K axis runs to MAX_P (the kernel's
     # K <= 8 cap is a Mosaic budget, not an XLA/MXU one)
     mxu_K = Axis("mxu_K", "pow2", 1, L["mxu_P"][1])
-    # batch form: succ + (S, B, K) segment tensors, like keys/flat
+    # batch form: succ + (S, B, K) segment tensors, like keys
     mxu_batch_tmpl = ((mxu_S, mxu_T), (S, B, mxu_K), (S, B, mxu_K),
                       (S, B), (S,))
     # single-history form (the driver's non-chunked path)
@@ -526,14 +523,15 @@ def static_inventory() -> Inventory:
         ),
         Site(
             key="xla-batch-engines",
-            jit_names=("check_device_keys", "check_device_flat",
-                       "check_device_seg_batch",
+            jit_names=("check_device_keys", "check_device_seg_batch",
                        "check_device_keys_sharded"),
             note="batched XLA engines (checker/linear_jax): segment "
                  "tensors (S, B, K) with every axis pow2 "
                  "(segment_batch pads, service buckets floor), memo "
-                 "table dims pow2 (pad_succ). "
-                 "`check_device_keys_sharded` shard_maps the keys/flat "
+                 "table dims pow2 (pad_succ). `check_device_seg_batch` "
+                 "is the last resort, the segmented search vmapped "
+                 "over lanes. "
+                 "`check_device_keys_sharded` shard_maps the keys "
                  "body over the mesh batch axis: global shapes are "
                  "identical (B pow2, padded to a multiple of D), each "
                  "shard compiles B/D lanes",
@@ -548,7 +546,7 @@ def static_inventory() -> Inventory:
                  "closure for wide-P histories — packed-word frontier, "
                  "bf16/f32 one-hot expansion on the MXU, exact "
                  "packed-key lexsort dedup. Batch form takes the same "
-                 "(S, B, K) segment tensors as keys/flat; table dims "
+                 "(S, B, K) segment tensors as keys; table dims "
                  "are pow2 inside the matmul caps (S_CAP x T_CAP). "
                  "The chunk form's carry exposes the frontier as "
                  "n_words (F,) word columns with F drawn from the "
@@ -649,25 +647,19 @@ def static_inventory() -> Inventory:
             axes_doc=(wl_B, wl_R, wl_N, wl_V),
         ),
         Site(
-            key="xla-batch-vmap",
-            jit_names=("check_device_batch",),
-            note="vmap fallback engine: dense step streams (B, n_pad), "
-                 "both axes pow2 (make_stream pads, service n_pad "
-                 "bucket)",
-            templates=(xla_batch_vmap,),
-            axes_doc=(memo, B, n_pad),
-        ),
-        Site(
             key="xla-driver-engines",
             jit_names=("check_device", "check_device_seg",
                        "check_device_seg_chunk", "check_device_seg2",
-                       "check_device_seg2_chunk", "pending_histogram"),
-            note="single-history adaptive driver (checker/linear.py "
-                 "and bench.py's 50k control): compiles per history "
-                 "shape BY DESIGN — an OPEN site, outside the closure "
-                 "guarantee. The per-item-dispatch lint rule keeps "
-                 "serving traffic off this path; the closed serving "
-                 "surface is the batch/stream/txn sites above",
+                       "check_device_seg2_chunk", "pending_histogram",
+                       "check_device_batch"),
+            note="single-history adaptive driver (checker/linear.py) "
+                 "and the event-level test oracle "
+                 "(`check_device_batch`, dense step streams): compile "
+                 "per history shape BY DESIGN — an OPEN site, outside "
+                 "the closure guarantee. The per-item-dispatch lint "
+                 "rule keeps serving traffic off this path; the "
+                 "closed serving surface is the batch/stream/txn "
+                 "sites above",
             open_site=True,
             bound_note="open (one program per history shape; "
                        "single-history driver path only)",
@@ -752,10 +744,10 @@ def _witness_specs():
         return jax.eval_shape(fn, st((16, 16)), st((8, 4, 2)),
                               st((8, 4, 2)), st((8, 4)), st((8,)))
 
-    def flat_witness():
+    def seg_batch_witness():
         from ..checker import linear_jax as LJ
 
-        fn = functools.partial(LJ.check_device_flat, B=4, F=64, P=2,
+        fn = functools.partial(LJ.check_device_seg_batch, F=64, P=2,
                                n_states=16, n_transitions=16)
         return jax.eval_shape(fn, st((16, 16)), st((8, 4, 2)),
                               st((8, 4, 2)), st((8, 4)), st((8,)))
@@ -903,8 +895,8 @@ def _witness_specs():
     def keys_sharded_witness():
         from ..checker import linear_jax as LJ
 
-        fn = LJ._sharded_keys_fn(_witness_mesh(), "batch", "keys",
-                                 4, 64, 2, 16, 16)
+        fn = LJ._sharded_keys_fn(_witness_mesh(), "batch", 4, 64, 2,
+                                 16, 16)
         return jax.eval_shape(fn, st((16, 16)), st((8, 4, 2)),
                               st((8, 4, 2)), st((8, 4)), st((8,)))
 
@@ -930,8 +922,8 @@ def _witness_specs():
          "check_device_keys at (ns,nt)=(16,16) S=8 B=4 K=2",
          keys_witness),
         ("xla-batch-engines",
-         "check_device_flat at (ns,nt)=(16,16) S=8 B=4 K=2",
-         flat_witness),
+         "check_device_seg_batch at (ns,nt)=(16,16) S=8 B=4 K=2",
+         seg_batch_witness),
         ("xla-batch-engines",
          "check_device_keys_sharded: same shapes, D=1 mesh rung",
          keys_sharded_witness),
@@ -1192,11 +1184,9 @@ SHAPE_SINKS: Dict[str, dict] = {
                                      "n_transitions_pad",
                                      "p_eff_pad")},
     "segment_batch": {"kwargs": ("s_pad", "k_pad")},
-    "pack_batch": {"kwargs": ("n_pad",)},
     "make_segments": {"kwargs": ("s_pad", "k_pad")},
     "pad_succ": {"kwargs": ("s_pad", "t_pad"), "pos": (1, 2)},
     "check_device_keys": {"kwargs": ("n_states", "n_transitions")},
-    "check_device_flat": {"kwargs": ("n_states", "n_transitions")},
     "check_device_seg_batch": {"kwargs": ("n_states",
                                           "n_transitions")},
     "check_device_mxu_batch": {"kwargs": ("n_states",

@@ -41,9 +41,9 @@ short story per rule id:
   (counterexample decode, report rendering — suppression-listed).
 - ``vmap-sharded-oracle`` — ``linear_jax.check_sharded`` (the vmap
   engine shard_mapped over a mesh) is a TEST ORACLE only: vmap lowers
-  ~20x worse per lane than the flat-batch encodings, so sharding it
+  ~20x worse per lane than the key-packed encoding, so sharding it
   scales a pessimized program. Production mesh traffic rides the
-  stream/keys/flat sharded engines through ``check_batch``; any
+  stream/keys sharded engines through ``check_batch``; any
   non-test call site routing serving traffic back onto the oracle is
   a finding (round 7 removed the last one).
 - ``raw-clock-in-pipeline`` — ``time.time()``/``time.monotonic()``/
@@ -539,7 +539,7 @@ def lint_file(path: str, source: Optional[str] = None, *,
                 "check_sharded is a test oracle — vmap lowers ~20x "
                 "worse per lane, so sharding it scales a pessimized "
                 "program; route mesh traffic through checker.batch."
-                "check_batch (stream/keys/flat sharded engines)"))
+                "check_batch (stream/keys sharded engines)"))
 
     if not in_tests:
         # tests legitimately compare per-item vs batched results; the

@@ -2,14 +2,23 @@
 
 The device analog of ``jepsen.independent``'s per-key partitioning
 (``independent.clj:252-300``): N short histories (e.g. one per register
-key) are checked as ONE vmapped/sharded device computation. This module
+key) are checked as ONE batched/sharded device computation. This module
 owns the host-side glue: interning every history's transitions into a
-single shared table, memoizing the model once over that union, and
-padding per-history step streams to a common length.
+single shared table, memoizing the model once over that union, building
+each history's per-ok segments, and choosing the engine.
+
+The engine ladder is decided in one place (:func:`_check_batch_begin`),
+from the shapes alone: the fused Pallas kernel wherever its spec
+serves the batch; else the XLA engines on the shared (S, B, K) segment
+tensors (:func:`_xla_begin`) — the MXU frontier engine for wide slot
+counts, the key-packed engine while its 62-bit key fits, and the
+vmapped segmented search as the last resort. Kernel overflows escalate
+through the same XLA selection.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Union
 
@@ -45,11 +54,8 @@ class PackedBatch:
 
     packeds: List[PackedHistory]
     memo: MemoizedModel
-    kind: np.ndarray   # int32[N, n_pad]
-    proc: np.ndarray   # int32[N, n_pad]
-    tr: np.ndarray     # int32[N, n_pad] — ids into the shared table
-    P: int             # max process count (slot width)
-    remaps: List[np.ndarray] = None  # per-history local→union trans ids
+    P: int                     # max process count (slot width)
+    remaps: List[np.ndarray]   # per-history local→union trans ids
 
     def __len__(self) -> int:
         return len(self.packeds)
@@ -113,25 +119,14 @@ def _segments_of(p, s_pad: int = 0, k_pad: int = 0):
 @_obs.traced("batch.pack")
 def pack_batch(histories: Sequence[Union[Sequence[Op], PackedHistory]],
                model: Model,
-               max_states: int = 1 << 20,
-               n_pad: int = 0,
-               build_streams: bool = True) -> PackedBatch:
-    """Pack histories for :func:`~.linear_jax.check_device_batch` /
-    :func:`~.linear_jax.check_device_keys_sharded`.
+               max_states: int = 1 << 20) -> PackedBatch:
+    """Pack histories for :func:`check_batch`.
 
     Transition ids are re-interned into one union table so all histories
     share a single memoized model; the BFS depth bound is the max
     invocation count over the batch (exact per history — a history can't
-    linearize more ops than it invoked; see ``memoize_model``).
-
-    ``build_streams=False`` skips the dense per-op (N, n_pad) stream
-    tensors that only the vmap fallback uses — at pod-scale batches
-    (4096 × 2k ops) they cost hundreds of host MB the
-    stream/keys/flat engines never read. Such a batch checks with
-    ``engine="stream"``/``"keys"``/``"flat"``, and kernel UNKNOWNs
-    still escalate through keys/flat (they re-segment from
-    ``packeds``); only a vmap-path escalation is unavailable (those
-    lanes then stay ``unknown``).
+    linearize more ops than it invoked; see ``memoize_model``). Segments
+    are built later, per engine, from ``packeds`` and ``remaps``.
     """
     packeds = [h if isinstance(h, PackedHistory) else pack_history(list(h))
                for h in histories]
@@ -149,27 +144,8 @@ def pack_batch(histories: Sequence[Union[Sequence[Op], PackedHistory]],
     n_inv = max((int(((p.type == INVOKE) & ~p.fails).sum())
                  for p in packeds), default=0)
     mm = memoize_model(model, union, max_states=max_states, max_depth=n_inv)
-
     P = max((len(p.process_table) for p in packeds), default=1)
-    if not build_streams:
-        empty = np.zeros((len(packeds), 0), np.int32)
-        return PackedBatch(packeds=packeds, memo=mm, kind=empty,
-                           proc=empty, tr=empty, P=P, remaps=remaps)
-    n_pad = max(n_pad, _next_pow2(max((len(p) for p in packeds), default=1)))
-    kinds, procs, trs = [], [], []
-    for p, remap in zip(packeds, remaps):
-        s = LJ.make_stream(p, n_pad=n_pad)
-        kind = np.asarray(s.kind)
-        tr = np.asarray(s.tr).copy()
-        mask = kind == LJ.K_INVOKE
-        if remap.size:
-            tr[mask] = remap[tr[mask]]
-        kinds.append(kind)
-        procs.append(np.asarray(s.proc))
-        trs.append(tr)
-    return PackedBatch(packeds=packeds, memo=mm,
-                       kind=np.stack(kinds), proc=np.stack(procs),
-                       tr=np.stack(trs), P=P, remaps=remaps)
+    return PackedBatch(packeds=packeds, memo=mm, P=P, remaps=remaps)
 
 
 def pack_batch_masked(parent: PackedHistory, masks: Sequence,
@@ -186,22 +162,19 @@ def pack_batch_masked(parent: PackedHistory, masks: Sequence,
     ``memo`` must be memoized over the parent's transitions with a
     depth bound >= the parent's invoke count (a candidate can't
     linearize more ops than the parent invoked, so one memo serves
-    every round). Packed with the ``build_streams=False`` layout —
-    candidates check through the stream/keys/flat engines."""
+    every round)."""
     from ..ops.columnar import subset_packed
 
     packeds = [subset_packed(parent, m) for m in masks]
     ident = np.arange(len(parent.transition_table), dtype=np.int32)
-    empty = np.zeros((len(packeds), 0), np.int32)
-    return PackedBatch(packeds=packeds, memo=memo, kind=empty,
-                       proc=empty, tr=empty,
+    return PackedBatch(packeds=packeds, memo=memo,
                        P=max(len(parent.process_table), 1),
                        remaps=[ident] * len(packeds))
 
 
 @dataclass
 class SegmentBatch:
-    """Per-ok segment tensors for the flat engine: (S, B, K) layouts."""
+    """Per-ok segment tensors for the XLA engines: (S, B, K) layouts."""
 
     inv_proc: np.ndarray   # int32[S, B, K]
     inv_tr: np.ndarray     # int32[S, B, K] — union transition ids
@@ -258,8 +231,8 @@ def segment_batch(batch: PackedBatch,
 
 
 @_obs.traced("batch.remap")
-def _build_streams(batch: PackedBatch, indices, s_pad: int = 0,
-                   k_pad: int = 0):
+def _remapped_streams(batch: PackedBatch, indices, s_pad: int = 0,
+                      k_pad: int = 0):
     """Union-remapped, slot-renamed SegmentStreams for a SUBSET of the
     batch — the unit of the pipelined dispatch (``_stream_stage``
     builds slice i+1 on the host while the device runs slice i).
@@ -337,8 +310,8 @@ def _stream_segments(batch: PackedBatch, s_pad: int = 0,
     cached = getattr(batch, "_stream_seg_cache", None)
     if cached is not None and cached[0] == (s_pad, k_pad):
         return cached[1]
-    out, p_eff = _build_streams(batch, range(len(batch.packeds)),
-                                s_pad=s_pad, k_pad=k_pad)
+    out, p_eff = _remapped_streams(batch, range(len(batch.packeds)),
+                                   s_pad=s_pad, k_pad=k_pad)
     batch._stream_seg_cache = ((s_pad, k_pad), (out, p_eff))
     return out, p_eff
 
@@ -466,9 +439,9 @@ def _stream_stage(batch: PackedBatch, succ, sizes, s_pad, k_pad,
     p_eff_all = 1
     dead = False
     for start, end, _dix in plan:
-        streams, pe = _build_streams(batch,
-                                     range(start, min(end, B)),
-                                     s_pad=s_pad, k_pad=k_pad)
+        streams, pe = _remapped_streams(batch,
+                                        range(start, min(end, B)),
+                                        s_pad=s_pad, k_pad=k_pad)
         all_streams.extend(streams)
         p_eff_all = max(p_eff_all, pe)
         if dead:
@@ -543,7 +516,7 @@ def _shard_histories(pending, B: int) -> dict:
 
 
 def check_batch(batch: PackedBatch, F: int = 256, mesh=None,
-                batch_axis: str = "batch", engine: str = "auto",
+                batch_axis: str = "batch",
                 info: Optional[dict] = None, s_pad: int = 0,
                 k_pad: int = 0, n_states_pad: int = 0,
                 n_transitions_pad: int = 0, p_eff_pad: int = 0):
@@ -559,14 +532,14 @@ def check_batch(batch: PackedBatch, F: int = 256, mesh=None,
     counts, ``pad_succ`` widens the table to match, and padding
     segments are no-ops to every engine."""
     return check_batch_async(
-        batch, F=F, mesh=mesh, batch_axis=batch_axis, engine=engine,
+        batch, F=F, mesh=mesh, batch_axis=batch_axis,
         info=info, s_pad=s_pad, k_pad=k_pad,
         n_states_pad=n_states_pad,
         n_transitions_pad=n_transitions_pad, p_eff_pad=p_eff_pad)()
 
 
 def check_batch_async(batch: PackedBatch, F: int = 256, mesh=None,
-                      batch_axis: str = "batch", engine: str = "auto",
+                      batch_axis: str = "batch",
                       info: Optional[dict] = None, s_pad: int = 0,
                       k_pad: int = 0, n_states_pad: int = 0,
                       n_transitions_pad: int = 0, p_eff_pad: int = 0):
@@ -581,20 +554,17 @@ def check_batch_async(batch: PackedBatch, F: int = 256, mesh=None,
     batch stream path additionally pipelines within one call: the host
     segments/packs dispatch slice i+1 while the device runs slice i.
 
-    engine: "stream" runs all histories through the fused Pallas
-    kernel as a sliced sequence of streamed scans (fastest on TPU —
-    measured ~6x the keys engine); "keys" keeps the frontier as packed
-    int32 key pairs — config mutation is bit arithmetic, dedup one
-    sort; "flat" folds all frontiers into one explicit tensor with the
-    batch id as the top sort key; "vmap" is the per-lane fallback;
-    "auto" picks the best available whose budget fits.
+    The engine follows from the shapes (module docstring): "stream"
+    (the fused Pallas kernel; "stream-sharded" under a mesh), else
+    "mxu", "keys" ("keys-sharded") or the last-resort "vmap".
 
     info: optional dict — receives {"engine": name} for the path
-    actually executed (observability; tests and bench assert on it);
-    populated at stage time.
+    actually executed (observability; tests assert on it), populated
+    at stage time, and ``info["escalated"]`` when kernel overflows
+    were re-run through the XLA engines.
     """
     fin = _check_batch_begin(
-        batch, F=F, mesh=mesh, batch_axis=batch_axis, engine=engine,
+        batch, F=F, mesh=mesh, batch_axis=batch_axis,
         info=info, s_pad=s_pad, k_pad=k_pad,
         n_states_pad=n_states_pad,
         n_transitions_pad=n_transitions_pad, p_eff_pad=p_eff_pad)
@@ -615,23 +585,18 @@ def check_batch_async(batch: PackedBatch, F: int = 256, mesh=None,
     return finalize
 
 
-def _check_batch_begin(batch: PackedBatch, F: int, mesh,
-                       batch_axis: str, engine: str,
-                       info: Optional[dict], s_pad: int, k_pad: int,
-                       n_states_pad: int, n_transitions_pad: int,
-                       p_eff_pad: int):
-    """Engine selection + host packing + async device dispatch;
-    returns the finalize closure (readback, fail-index decode, kernel
-    overflow escalation)."""
-    # declared table sizes may be floored (bucketed) above the real
-    # counts: ids stay below the real counts, so widening the fields
-    # and the padded table is a pure relabeling of the key layout
+def _layout(batch: PackedBatch, mesh, batch_axis: str,
+            n_states_pad: int, n_transitions_pad: int):
+    """``(succ, sizes, D, B_pad)``: the padded successor table, the
+    declared table sizes, the mesh's shard count and the batch width
+    padded for it. Declared table sizes may be floored (bucketed)
+    above the real counts: ids stay below the real counts, so widening
+    the fields and the padded table is a pure relabeling of the key
+    layout."""
     n_states = max(batch.memo.n_states, n_states_pad)
     n_transitions = max(batch.memo.n_transitions, n_transitions_pad)
     succ = LJ.pad_succ(batch.memo.succ, _next_pow2(n_states),
                        _next_pow2(n_transitions))
-    P = _next_pow2(batch.P, 2)
-    B = len(batch)
     sizes = {"n_states": n_states, "n_transitions": n_transitions}
     D = int(mesh.shape[batch_axis]) if mesh is not None else 1
     if D > 1 and (D & (D - 1)):
@@ -643,156 +608,132 @@ def _check_batch_begin(batch: PackedBatch, F: int, mesh,
     # shapes remain bucketed (B_pad/D is the shape each shard
     # compiles for — the shard-extended PROGRAMS.md inventory). Pad
     # lanes are SENTINEL histories, excluded from every verdict and
-    # metric by the [:B] slice — info records the factor so callers
-    # can audit that dead work never surfaces in per-batch totals.
-    B_pad = B
-    if D > 1:
-        B_pad = max(_next_pow2(B), D)
+    # metric by the [:B] slice
+    B_pad = max(_next_pow2(len(batch)), D) if D > 1 else len(batch)
+    return succ, sizes, D, B_pad
+
+
+def _check_batch_begin(batch: PackedBatch, F: int, mesh,
+                       batch_axis: str, info: Optional[dict],
+                       s_pad: int, k_pad: int, n_states_pad: int,
+                       n_transitions_pad: int, p_eff_pad: int):
+    """Engine selection + host packing + async device dispatch;
+    returns the finalize closure (readback, fail-index decode, kernel
+    overflow escalation)."""
+    succ, sizes, D, B_pad = _layout(batch, mesh, batch_axis,
+                                    n_states_pad, n_transitions_pad)
+    B = len(batch)
     if info is not None:
+        # info records the pad factor so callers can audit that dead
+        # sentinel work never surfaces in per-batch totals
         info["batch"] = {"b": B, "b_pad": B_pad, "pad": B_pad - B,
                          "shards": max(D, 1)}
+    xla = functools.partial(
+        _xla_begin, F=F, mesh=mesh, batch_axis=batch_axis,
+        s_pad=s_pad, k_pad=k_pad, n_states_pad=n_states_pad,
+        n_transitions_pad=n_transitions_pad)
+    # gate BEFORE the O(total-ops) segment pass so a shape that can
+    # never run fused (table too big, K too wide — checked at P=1, the
+    # minimum) skips the host work. P itself is NOT final here: slot
+    # renaming can shrink it below the tier bound, so P-ineligible
+    # shapes still try the pass when everything else fits
+    if (PSEG.spec_for(sizes["n_states"], sizes["n_transitions"], 1, 8)
+            is None or not PSEG.available()):
+        return xla(batch, info=info)
+    # the padded succ, not the raw memo table: the kernel's flat-table
+    # stride is the declared n_transitions, which may be floored above
+    # the real column count; p_eff_pad floors the slot count so a
+    # serving layer bucketing by effective concurrency compiles one
+    # kernel per bucket
+    pending, segs_list = _stream_stage(
+        batch, succ, sizes, s_pad, k_pad, p_eff_pad, mesh,
+        B_pad=B_pad, batch_axis=batch_axis)
+    if pending is None:
+        # the kernel rejected the renamed shape: the XLA engines reuse
+        # the streams it already built
+        return xla(batch, info=info, streams=segs_list)
+    if info is not None:
+        # label by the route actually taken: a 1-device mesh rides
+        # the plain single-device stream dispatch, not shard_map
+        info["engine"] = "stream" if D <= 1 else "stream-sharded"
+        # per-dispatch transfer accounting (docs/observability.md):
+        # host-to-device bytes are a first-class cost — summed from
+        # the actual staged tensors
+        info["transfer_bytes"] = {
+            "h2d": int(succ.nbytes) + _stream_nbytes(segs_list),
+            "d2h": B * _D2H_BYTES_PER_LANE}
 
-    def note(name: str) -> None:
+    @_obs.traced("batch.finalize")
+    def finalize_stream():
+        # sentinel-pad verdicts (always VALID) are sliced off HERE,
+        # before escalation/metrics — a pad history can never surface
+        # as a verdict, counterexample, or shrink candidate
+        if D > 1 and info is not None:
+            info["shard_histories"] = _shard_histories(pending, B)
+        rs = _stream_collect(pending, B_pad if D > 1 else B)[:B]
+        status = np.array([r[0] for r in rs], np.int32)
+        fail_at = np.array([
+            segs_list[b].seg_index[rs[b][1]] if rs[b][1] >= 0
+            else -1 for b in range(B)], np.int64)
+        n_final = np.array([r[2] for r in rs], np.int32)
+        # the kernel's frontier tops out at F_TOP: histories that
+        # overflowed it get the larger requested budget F through the
+        # XLA engines instead of surfacing spurious UNKNOWNs. The
+        # sub-batch picks its engine from ITS size — at pod-scale
+        # batches the full-B budgets never fit while a handful of
+        # overflowed histories easily do
+        unk = escalation_indices(status, F, PSEG.F_TOP)
+        if not unk.size:
+            return status, fail_at, n_final
+        sub = PackedBatch(packeds=[batch.packeds[i] for i in unk],
+                          memo=batch.memo, P=batch.P,
+                          remaps=[batch.remaps[i] for i in unk])
+        sub_info: dict = {}
+        st2, fa2, n2 = xla(sub, info=sub_info)()
+        if info is not None:
+            # the label must not claim the kernel checked them all
+            info["escalated"] = {"engine": sub_info.get("engine"),
+                                 "count": int(unk.size)}
+        return merge_escalation(status, fail_at, n_final, unk,
+                                st2, fa2, n2)
+
+    return finalize_stream
+
+
+def _xla_begin(batch: PackedBatch, *, F: int, mesh, batch_axis: str,
+               info: Optional[dict], s_pad: int, k_pad: int,
+               n_states_pad: int, n_transitions_pad: int,
+               streams: Optional[list] = None):
+    """The XLA half of the ladder, on one (S, B, K) segment batch:
+    the MXU frontier engine for wide slot counts, else the key-packed
+    engine (sharded under a mesh) while its key fits the per-shard
+    batch, else the vmapped segmented search. The MXU engine and the
+    last resort run one device; a mesh caller landing there is told
+    so by ``info["mesh_dropped"]``. Returns the finalize closure."""
+    succ, sizes, D, B_pad = _layout(batch, mesh, batch_axis,
+                                    n_states_pad, n_transitions_pad)
+    B = len(batch)
+    P = _next_pow2(batch.P, 2)
+    sb = segment_batch(batch, streams=streams, s_pad=s_pad, k_pad=k_pad)
+    segs = (sb.inv_proc, sb.inv_tr, sb.ok_proc, sb.depth)
+    if info is not None:
+        info["transfer_bytes"] = {
+            "h2d": int(succ.nbytes) + sum(int(a.nbytes) for a in segs),
+            "d2h": B * _D2H_BYTES_PER_LANE}
+
+    def note(name: str, single_device: bool = False) -> None:
         if info is not None:
             info["engine"] = name
+            if single_device and mesh is not None:
+                info["mesh_dropped"] = True
 
-    def pick_xla_engine(b=None):
-        # under a mesh each device sees B_pad/D histories — the fits
-        # budgets apply to the per-shard batch. ``b`` overrides the
-        # batch size (escalated sub-batches are far smaller than the
-        # full batch, so their budgets fit where the batch's don't).
-        # Wide P goes to the MXU frontier engine first: past the
-        # crossover (mxu.MIN_P) its matmul expansion is P-independent
-        # while the keys/flat per-iteration cost scales with P — and
-        # most wide-P shapes don't fit the 62-bit key budgets at all
-        if b is None:
-            b = B_pad // D if D > 1 else B
-        if MXU.serves(sizes["n_states"], sizes["n_transitions"], P):
-            return "mxu"
-        if LJ.KeyLayout(b, sizes["n_states"],
-                        sizes["n_transitions"], P).fits:
-            return "keys"
-        if LJ.flat_pack_bits(b, sizes["n_states"],
-                             sizes["n_transitions"], P)[3]:
-            return "flat"
-        return "vmap"
-
-    def stream_fits():
-        # gate BEFORE the O(total-ops) segment pass so a shape that
-        # can never run fused (table too big, K too wide — checked at
-        # P=1, the minimum) skips the host work. P itself is NOT final
-        # here: slot renaming in _stream_segments can shrink it below
-        # the tier bound, so P-ineligible shapes still try the pass
-        # when everything else fits.
-        return (PSEG.spec_for(sizes["n_states"],
-                              sizes["n_transitions"], 1, 8)
-                is not None and PSEG.available())
-
-    if engine == "auto":
-        engine = "stream" if stream_fits() else pick_xla_engine()
-    prebuilt_streams = None      # reused by keys/flat when the kernel
-    if engine == "stream":       # path rejects an already-built batch
-        pending = None
-        if stream_fits():
-            # the padded succ, not the raw memo table: the kernel's
-            # flat-table stride is the declared n_transitions, which
-            # may be floored above the real column count; p_eff_pad
-            # floors the slot count so a serving layer bucketing by
-            # effective concurrency compiles one kernel per bucket
-            pending, segs_list = _stream_stage(
-                batch, succ, sizes, s_pad, k_pad, p_eff_pad, mesh,
-                B_pad=B_pad, batch_axis=batch_axis)
-            prebuilt_streams = segs_list
-        if pending is not None:
-            # label by the route actually taken: a 1-device mesh rides
-            # the plain single-device stream dispatch, not shard_map
-            note("stream" if D <= 1 else "stream-sharded")
-            if info is not None:
-                # per-dispatch transfer accounting (docs/observability
-                # .md): host-to-device bytes are a first-class
-                # cost — summed from the actual staged tensors
-                info["transfer_bytes"] = {
-                    "h2d": int(succ.nbytes)
-                    + _stream_nbytes(segs_list),
-                    "d2h": B * _D2H_BYTES_PER_LANE}
-
-            @_obs.traced("batch.finalize")
-            def finalize_stream():
-                # sentinel-pad verdicts (always VALID) are sliced off
-                # HERE, before escalation/metrics — a pad history can
-                # never surface as a verdict, counterexample, or
-                # shrink candidate
-                if D > 1 and info is not None:
-                    info["shard_histories"] = _shard_histories(
-                        pending, B)
-                rs = _stream_collect(pending,
-                                     B_pad if D > 1 else B)[:B]
-                status = np.array([r[0] for r in rs], np.int32)
-                fail_at = np.array([
-                    segs_list[b].seg_index[rs[b][1]] if rs[b][1] >= 0
-                    else -1 for b in range(B)], np.int64)
-                n_final = np.array([r[2] for r in rs], np.int32)
-                # the kernel's frontier tops out at F_TOP: histories
-                # that overflowed it get a larger requested budget F
-                # through the XLA engines instead of surfacing
-                # spurious UNKNOWNs
-                unk = escalation_indices(status, F, PSEG.F_TOP)
-                # the sub-batch is sized by the overflow count, so
-                # pick the escalation engine from THAT size — at
-                # pod-scale batches the full-B budgets never fit while
-                # a handful of overflowed histories easily do
-                sub_b = (-(-int(unk.size) // D) if D > 1
-                         else int(unk.size))
-                esc_engine = pick_xla_engine(max(sub_b, 1))
-                if unk.size and batch.kind.shape[1] == 0 \
-                        and esc_engine == "vmap":
-                    # packed with build_streams=False and only the
-                    # vmap path could take the overflow: those
-                    # histories must stay unknown — record that
-                    # escalation was REQUESTED but impossible so
-                    # callers can tell this apart from "no overflow"
-                    # (ADVICE r4)
-                    if info is not None:
-                        info["escalated"] = {"engine": None,
-                                             "count": int(unk.size)}
-                    unk = np.empty(0, np.int64)
-                if unk.size:
-                    sub = PackedBatch(
-                        packeds=[batch.packeds[i] for i in unk],
-                        memo=batch.memo,
-                        kind=batch.kind[unk], proc=batch.proc[unk],
-                        tr=batch.tr[unk], P=batch.P,
-                        remaps=[batch.remaps[i] for i in unk])
-                    sub_info: dict = {}
-                    st2, fa2, n2 = check_batch(
-                        sub, F=F, mesh=mesh, engine=esc_engine,
-                        info=sub_info, s_pad=s_pad, k_pad=k_pad,
-                        n_states_pad=n_states_pad,
-                        n_transitions_pad=n_transitions_pad,
-                        p_eff_pad=p_eff_pad)
-                    status2, fail_at2, n_final2 = merge_escalation(
-                        status, fail_at, n_final, unk, st2, fa2, n2)
-                    if info is not None:  # the label must not claim
-                        info["escalated"] = {   # the kernel checked
-                            "engine": sub_info.get("engine"),  # all
-                            "count": int(unk.size)}
-                    return status2, fail_at2, n_final2
-                return status, fail_at, n_final
-
-            return finalize_stream
-        engine = pick_xla_engine()
-    if engine == "mxu":
-        # the MXU frontier engine's batched form: packed-word frontier,
-        # matmul expansion, exact packed-key dedup — same (S, B, K)
-        # segment tensors as keys/flat. No shard_map form yet: a mesh
-        # caller runs one device and says so (like the vmap fallback)
-        assert MXU.fits(sizes["n_states"], sizes["n_transitions"],
-                        P), \
-            "mxu engine requires the table caps and a lossless " \
-            "PackPlan (see mxu.fits)"
-        note("mxu")
-        if mesh is not None and info is not None:
-            info["mesh_dropped"] = True
+    # past the crossover (mxu.MIN_P) the MXU engine's matmul expansion
+    # is P-independent while the keys per-iteration cost scales with
+    # P — and most wide-P shapes don't fit the 62-bit key at all.
+    # Under a mesh each device sees B_pad/D histories: the key budget
+    # applies to the per-shard batch
+    if MXU.serves(sizes["n_states"], sizes["n_transitions"], P):
+        note("mxu", single_device=True)
         # bucket the caller's F to the engine's CAPACITIES ladder —
         # the PROGRAMS.md mxu-frontier site declares F as a closed
         # enum, and F is jit-static but invisible in the input avals,
@@ -801,83 +742,32 @@ def _check_batch_begin(batch: PackedBatch, F: int, mesh,
         F_mxu = MXU.bucket_F(F)
         if info is not None:
             info["frontier_capacity"] = F_mxu
-        sb = segment_batch(batch, streams=prebuilt_streams,
-                           s_pad=s_pad, k_pad=k_pad)
-        if info is not None:
-            info["transfer_bytes"] = {
-                "h2d": int(succ.nbytes) + int(sb.inv_proc.nbytes)
-                + int(sb.inv_tr.nbytes) + int(sb.ok_proc.nbytes)
-                + int(sb.depth.nbytes),
-                "d2h": B * _D2H_BYTES_PER_LANE}
-        status_d, fail_seg_d, n_final_d = MXU.check_device_mxu_batch(
-            succ, sb.inv_proc, sb.inv_tr, sb.ok_proc, sb.depth,
-            B=B, F=F_mxu, P=P, **sizes)
-
-        @_obs.traced("batch.finalize")
-        def finalize_mxu():
-            status = np.asarray(status_d)[:B]
-            fail_seg = np.asarray(fail_seg_d)[:B]
-            fail_at = np.array([
-                sb.seg_index[b, fail_seg[b]] if fail_seg[b] >= 0
-                else -1 for b in range(B)], np.int64)
-            return status, fail_at, np.asarray(n_final_d)[:B]
-
-        return finalize_mxu
-    if engine in ("keys", "flat"):
-        note(engine if mesh is None else engine + "-sharded")
-        sb = segment_batch(batch, streams=prebuilt_streams,
-                           s_pad=s_pad, k_pad=k_pad)
-        if info is not None:
-            info["transfer_bytes"] = {
-                "h2d": int(succ.nbytes) + int(sb.inv_proc.nbytes)
-                + int(sb.inv_tr.nbytes) + int(sb.ok_proc.nbytes)
-                + int(sb.depth.nbytes),
-                "d2h": B * _D2H_BYTES_PER_LANE}
+        out = MXU.check_device_mxu_batch(succ, *segs, B=B, F=F_mxu, P=P,
+                                         **sizes)
+    elif LJ.KeyLayout(B_pad // D, sizes["n_states"],
+                      sizes["n_transitions"], P).fits:
         if mesh is not None:
-            ip, it, op_, dp = _pad_batch_axis(sb, B_pad - B)
-            status_d, fail_seg_d, n_final_d = \
-                LJ.check_device_keys_sharded(
-                    mesh, succ, ip, it, op_, dp, B=B_pad, F=F, P=P,
-                    batch_axis=batch_axis, engine=engine, **sizes)
+            note("keys-sharded")
+            out = LJ.check_device_keys_sharded(
+                mesh, succ, *_pad_batch_axis(sb, B_pad - B), B=B_pad,
+                F=F, P=P, batch_axis=batch_axis, **sizes)
         else:
-            fn = (LJ.check_device_keys if engine == "keys"
-                  else LJ.check_device_flat)
-            status_d, fail_seg_d, n_final_d = fn(
-                succ, sb.inv_proc, sb.inv_tr, sb.ok_proc, sb.depth,
-                B=B, F=F, P=P, **sizes)
+            note("keys")
+            out = LJ.check_device_keys(succ, *segs, B=B, F=F, P=P,
+                                       **sizes)
+    else:
+        note("vmap", single_device=True)
+        out = LJ.check_device_seg_batch(succ, *segs, F=F, P=P, **sizes)
 
-        @_obs.traced("batch.finalize")
-        def finalize_xla():
-            status = np.asarray(status_d)[:B]
-            fail_seg = np.asarray(fail_seg_d)[:B]
-            fail_at = np.array([
-                sb.seg_index[b, fail_seg[b]] if fail_seg[b] >= 0
-                else -1 for b in range(B)], np.int64)
-            return status, fail_at, np.asarray(n_final_d)[:B]
+    @_obs.traced("batch.finalize")
+    def finalize_xla():
+        status, fail_seg, n_final = (np.asarray(x)[:B] for x in out)
+        fail_at = np.array([
+            sb.seg_index[b, fail_seg[b]] if fail_seg[b] >= 0
+            else -1 for b in range(B)], np.int64)
+        return status, fail_at, n_final
 
-        return finalize_xla
-    if batch.kind.shape[1] == 0:
-        raise ValueError(
-            "batch was packed with build_streams=False; the vmap path "
-            "needs the dense step streams")
-    # vmap is a SINGLE-DEVICE last resort only. The vmap-sharded route
-    # (linear_jax.check_sharded) was removed from the production path:
-    # vmap lowers ~20x worse per lane, so sharding it scales a
-    # pessimized program — check_sharded survives as a test oracle and
-    # the vmap-sharded-oracle analysis rule keeps serving traffic off
-    # it. A mesh caller landing here runs one device and says so.
-    note("vmap")
-    if mesh is not None and info is not None:
-        info["mesh_dropped"] = True
-    if info is not None:
-        info["transfer_bytes"] = {
-            "h2d": int(succ.nbytes) + int(batch.kind.nbytes)
-            + int(batch.proc.nbytes) + int(batch.tr.nbytes),
-            "d2h": B * _D2H_BYTES_PER_LANE}
-    out = LJ.check_device_batch(succ, batch.kind, batch.proc,
-                                batch.tr, F=F, P=P, **sizes)
-    return _obs.traced("batch.finalize")(
-        lambda: tuple(np.asarray(x) for x in out))
+    return finalize_xla
 
 
 def escalation_indices(status: np.ndarray, F: int,

@@ -267,8 +267,8 @@ def _dedup_compact(states, slots, valid, F, plan=None, okp=None):
     n = jnp.sum(keep)
     # measured on v5e: a second small argsort beats cumsum+scatter
     # compaction here (~9.5k vs ~7.3k ops/s on the 50k bench); the
-    # flat-batch engines use scatter because their row counts are
-    # larger and block-structured
+    # keys engine uses scatter because its row counts are larger
+    # and block-structured
     order2 = jnp.argsort(~keep, stable=True)[:F]
     sel = order[order2]
     return states[sel], slots[sel], keep[order2], n, n > F
@@ -919,9 +919,16 @@ def check_device_seg_chunk(succ, inv_proc, inv_tr, ok_proc, depth,
 def check_device_seg_batch(succ, inv_proc, inv_tr, ok_proc, depth, *,
                            F: int, P: int, n_states=None,
                            n_transitions=None):
+    """The batch path's last resort: the single-history segmented
+    search vmapped over the lanes of the same (S, B, K) segment
+    tensors the keys and MXU engines take. No packed-key budget, so it
+    serves any table size and slot width; exact at ``F``, UNKNOWN on
+    overflow. ``depth`` (S,) is the per-segment maximum over lanes, a
+    bound on every lane's closure. Returns per-lane (status[B],
+    fail_segment[B], n_final[B])."""
     bits = _bits_for(n_states, n_transitions, P)
-    fn = functools.partial(_check_impl_seg, F=F, P=P, bits=bits)
-    return jax.vmap(lambda a, b, c, d: fn(succ, a, b, c, d))(
+    fn = functools.partial(_check_impl_seg, succ, F=F, P=P, bits=bits)
+    return jax.vmap(fn, in_axes=(1, 1, 1, None))(
         inv_proc, inv_tr, ok_proc, depth)
 
 
@@ -984,212 +991,12 @@ def check_device_seg2_chunk(succ, inv_proc, inv_tr, ok_proc, depth,
     return carry2
 
 
-# --- flat-batch engine: B histories, one frontier tensor, no vmap ----------
+# --- key-packed batch engine: the frontier IS the sort key -----------------
 #
-# vmapping _check_impl lowers poorly on TPU (batched gathers/sorts cost
-# ~20x per lane); instead the B frontiers live in ONE flat (B*F)-row
-# tensor with the batch id packed into the top bits of the sort key.
-# Every step is then plain big-array ops: one 2-key sort, one cumsum,
-# gathers/scatters — exactly what the hardware is good at. Batch
-# boundaries after the sort are *fixed* (each batch contributes exactly
-# F*(P+1) rows, valid or not), so per-batch compaction is arithmetic on
-# row indices, not segmented reductions.
-
-def flat_pack_bits(B: int, n_states: int, n_transitions: int, P: int):
-    """Bit budget including the batch id + invalid flag. Returns
-    (batch_bits, state_bits, slot_bits, fits); simulates the same
-    greedy word split as :func:`_flat_sort_keys` so per-word overflow
-    (fragmentation) is caught, not just the total."""
-    batch_bits = max(int(np.ceil(np.log2(max(B, 2)))), 1)
-    state_bits = max(int(np.ceil(np.log2(max(n_states, 2)))), 1)
-    slot_bits = max(int(np.ceil(np.log2(max(n_transitions + 2, 2)))), 1)
-    widths = [batch_bits, 1, state_bits] + [slot_bits] * P
-    _, hi_bits = _greedy_split(widths)
-    fits = hi_bits <= 30 and all(b <= 30 for b in widths)
-    return batch_bits, state_bits, slot_bits, fits
-
-
-def _flat_sort_keys(batch, states, slots, valid, bits):
-    """(hi, lo) int32 keys: batch | invalid | state | slots, split so
-    each word stays below 31 bits. Invalid rows' state/slot fields are
-    zeroed: an invalid candidate carries state -1 from the expansion,
-    and a negative field would sign-corrupt the batch bits — pushing
-    the row across block boundaries and shifting other batches' valid
-    rows out of their fixed blocks."""
-    batch_bits, state_bits, slot_bits = bits
-    P = slots.shape[1]
-    st_f = jnp.where(valid, states, 0)
-    fields = [(batch, batch_bits), ((~valid).astype(jnp.int32), 1),
-              (st_f, state_bits)] + \
-        [(jnp.where(valid, slots[:, q] + 2, 0), slot_bits)
-         for q in range(P)]
-    lo = jnp.zeros_like(states)
-    lo_bits = 0
-    i = len(fields) - 1
-    while i >= 0 and lo_bits + fields[i][1] <= 31:
-        lo = lo | (fields[i][0] << lo_bits)
-        lo_bits += fields[i][1]
-        i -= 1
-    hi = jnp.zeros_like(states)
-    hi_bits = 0
-    while i >= 0:
-        hi = hi | (fields[i][0] << hi_bits)
-        hi_bits += fields[i][1]
-        i -= 1
-    return hi, lo
-
-
-def _flat_dedup_compact(batch, states, slots, valid, B, F, bits):
-    """Sort all rows by (batch, validity, config); dedup adjacent equal
-    configs; compact each batch's survivors into its F-row block.
-    Row count R per batch is fixed, so batch b owns sorted rows
-    [b*R, (b+1)*R). Returns (states, slots, valid, n_per_batch[B],
-    overflow[B]) with frontier shape (B*F, ...)."""
-    R = states.shape[0] // B
-    hi, lo = _flat_sort_keys(batch, states, slots, valid, bits)
-    order = jnp.lexsort((lo, hi))
-    h, l = hi[order], lo[order]
-    va = valid[order]
-    st = states[order]
-    sl = slots[order]
-    pad = jnp.zeros(1, bool)
-    same = jnp.concatenate([pad, (h[1:] == h[:-1]) & (l[1:] == l[:-1])
-                            & va[:-1]])
-    keep = va & ~same
-    c = jnp.cumsum(keep)                    # inclusive
-    e = c - keep                            # exclusive
-    row = jnp.arange(states.shape[0])
-    block = row // R
-    base = e.reshape(B, R)[:, 0]            # kept-count before each block
-    rank = e - base[block]
-    n_b = c.reshape(B, R)[:, -1] - base     # kept rows per batch
-    target = jnp.where(keep & (rank < F), block * F + rank, B * F)
-    out_st = jnp.zeros(B * F + 1, jnp.int32).at[target].set(st,
-                                                            mode="drop")
-    P = slots.shape[1]
-    out_sl = jnp.zeros((B * F + 1, P), jnp.int32).at[target].set(
-        sl, mode="drop")
-    slot_row = jnp.arange(B * F)
-    out_va = (slot_row % F) < jnp.minimum(n_b, F)[slot_row // F]
-    return (out_st[:B * F], out_sl[:B * F], out_va,
-            jnp.minimum(n_b, F), n_b > F)
-
-
-def _flat_closure(succ, batch, states, slots, valid, n_b, B, F, P, bits,
-                  max_iter=None):
-    """Fixed point of single-call linearization over the flat frontier.
-    All batches iterate in lockstep; the loop exits when no batch's
-    frontier grew (or the exact pending-depth bound is reached)."""
-    if max_iter is None:
-        max_iter = P + 1
-    cand_batch = jnp.arange(B * F * P, dtype=jnp.int32) // (F * P)
-    all_batch = jnp.concatenate([batch, cand_batch])
-
-    def cond(c):
-        _, _, _, _, _, changed, it = c
-        return changed & (it < max_iter)
-
-    def body(c):
-        st, sl, va, n, ovf_sticky, _, it = c
-        c_st, c_sl, c_va = _expand(succ, st, sl, va)
-        all_st = jnp.concatenate([st, c_st])
-        all_sl = jnp.concatenate([sl, c_sl])
-        all_va = jnp.concatenate([va, c_va])
-        st2, sl2, va2, n2, ovf = _flat_dedup_compact(
-            all_batch, all_st, all_sl, all_va, B, F, bits)
-        # overflow is sticky: a truncated frontier stays unsound for
-        # this batch even if later iterations fit again
-        ovf2 = ovf_sticky | ovf
-        changed = jnp.any(n2 > n) | jnp.any(ovf)
-        return st2, sl2, va2, n2, ovf2, changed, it + 1
-
-    init = body((states, slots, valid, n_b,
-                 jnp.zeros(B, bool), jnp.bool_(True), jnp.int32(0)))
-    st, sl, va, n, ovf, _, _ = lax.while_loop(cond, body, init)
-    return st, sl, va, n, ovf
-
-
-def _make_flat_step(succ, B, F, P, K, bits):
-    rows = jnp.arange(B * F, dtype=jnp.int32)
-    batch = rows // F
-
-    def step(carry, seg):
-        states, slots, valid, n_b, status, fail_at = carry
-        # (B,K),(B,K),(B,),(),()
-        inv_proc, inv_tr, ok_proc, sidx, depth = seg
-
-        live_b = (status == VALID) & (ok_proc >= 0)
-        live_row = live_b[batch]
-
-        sl = slots
-        for k in range(K):                       # K static, unrolled
-            p_row = inv_proc[batch, k]
-            tr_row = inv_tr[batch, k]
-            set_mask = live_row & (p_row >= 0)
-            col = jnp.maximum(p_row, 0)
-            sl = jnp.where(set_mask[:, None],
-                           sl.at[rows, col].set(
-                               jnp.where(set_mask, tr_row,
-                                         sl[rows, col])),
-                           sl)
-
-        st2, sl2, va2, n2, ovf = _flat_closure(
-            succ, batch, states, sl, valid, n_b, B, F, P, bits,
-            max_iter=depth)
-        okp_row = jnp.maximum(ok_proc, 0)[batch]
-        returned = va2 & (sl2[rows, okp_row] == LIN)
-        sl3 = sl2.at[rows, okp_row].set(
-            jnp.where(returned, IDLE, sl2[rows, okp_row]))
-        n3 = jnp.sum(returned.reshape(B, F), axis=1)
-
-        st_new = jnp.where(ovf, UNKNOWN,
-                           jnp.where(n3 == 0, INVALID, VALID)
-                           ).astype(jnp.int32)
-        status2 = jnp.where(live_b, st_new, status)
-        fail2 = jnp.where(live_b & (st_new != VALID), sidx, fail_at)
-
-        keep_row = live_row & (status2[batch] == VALID)
-        states_o = jnp.where(keep_row, st2, states)
-        slots_o = jnp.where(keep_row[:, None], sl3, slots)
-        valid_o = jnp.where(keep_row, returned, valid)
-        n_o = jnp.where(live_b & (status2 == VALID), n3, n_b)
-        return (states_o, slots_o, valid_o, n_o, status2, fail2), None
-
-    return step
-
-
-@functools.partial(jax.jit, static_argnames=("B", "F", "P", "n_states",
-                                             "n_transitions"))
-def check_device_flat(succ, inv_proc, inv_tr, ok_proc, depth, *,
-                      B: int, F: int, P: int, n_states: int,
-                      n_transitions: int):
-    """Check B histories as one flat device computation.
-
-    seg arrays: inv_proc/inv_tr (S, B, K), ok_proc (S, B); returns
-    per-batch (status[B], fail_segment[B], n_final[B]). Requires the
-    packed-key budget to fit (see :func:`flat_pack_bits`)."""
-    bb, sb, tb, fits = flat_pack_bits(B, n_states, n_transitions, P)
-    assert fits, "flat engine requires the packed-key budget to fit"
-    bits = (bb, sb, tb)
-    S = inv_proc.shape[0]
-    K = inv_proc.shape[2]
-    rows = B * F
-    states = jnp.zeros(rows, jnp.int32)
-    slots = jnp.full((rows, P), IDLE, jnp.int32)
-    valid = (jnp.arange(rows) % F) == 0
-    carry = (states, slots, valid, jnp.ones(B, jnp.int32),
-             jnp.full(B, VALID, jnp.int32), jnp.full(B, -1, jnp.int32))
-    segs = (inv_proc, inv_tr, ok_proc, jnp.arange(S, dtype=jnp.int32),
-            depth)
-    step = _make_flat_step(succ, B, F, P, K, bits)
-    (states, slots, valid, n_b, status, fail_at), _ = lax.scan(
-        step, carry, segs)
-    return status, fail_at, n_b
-
-
-# --- key-packed flat engine: the frontier IS the sort key ------------------
-#
-# The fastest form: each config is ONLY its packed (hi, lo) int32 pair
+# B histories in one flat frontier tensor with the batch id packed into
+# the top bits of the sort key, so every step is plain big-array ops
+# (vmapping the single-history search lowers ~20x worse per lane on
+# TPU). Each config is ONLY its packed (hi, lo) int32 pair
 # — state and slots are bit fields, never materialized as arrays.
 # Invoking, linearizing, and returning ops are field arithmetic
 # (deltas shifted into place); deduplication sorts the keys themselves.
@@ -1391,9 +1198,12 @@ def _k_closure(succ, lay, hi, lo, valid, n_b, inv_hi_all, inv_lo_all,
 def check_device_keys(succ, inv_proc, inv_tr, ok_proc, depth, *,
                       B: int, F: int, P: int, n_states: int,
                       n_transitions: int):
-    """The key-packed flat engine: B histories, frontier = (hi, lo)
-    int32 pairs, one sort per closure iteration. Same inputs/outputs as
-    :func:`check_device_flat`."""
+    """The key-packed batch engine: B histories, frontier = (hi, lo)
+    int32 pairs, one sort per closure iteration.
+
+    seg arrays: inv_proc/inv_tr (S, B, K), ok_proc (S, B), depth (S,);
+    returns per-lane (status[B], fail_segment[B], n_final[B]). Requires
+    :class:`KeyLayout` to fit."""
     lay = KeyLayout(B, n_states, n_transitions, P)
     assert lay.fits, "key layout must fit 62 bits"
     S, _, K = inv_proc.shape
@@ -1479,41 +1289,38 @@ def check_device_keys(succ, inv_proc, inv_tr, ok_proc, depth, *,
 
 # --- batched (independent histories) ---------------------------------------
 
-#: sharded keys/flat dispatches this process — one per
+#: sharded keys dispatches this process — one per
 #: :func:`check_device_keys_sharded` call (ONE fused program covering
-#: every shard); ``scripts/bench_multichip.py`` asserts the
-#: single-dispatch-per-batch discipline on the measured delta, the
-#: way ``txn.closure_jax.DISPATCHES`` is asserted
+#: every shard), counted the way ``txn.closure_jax.DISPATCHES`` is
 DISPATCHES = 0
 
 @functools.partial(jax.jit, static_argnames=("F", "P", "n_states",
                                              "n_transitions"))
 def check_device_batch(succ, kind, proc, tr, *, F: int, P: int,
                        n_states=None, n_transitions=None):
-    """vmap over a batch of histories sharing one successor table — the
-    TPU analog of ``independent/checker``'s per-key partitioning
-    (``independent.clj:252-300``): thousands of per-key histories check
-    in one launch."""
+    """TEST ORACLE — the event-level search vmapped over dense step
+    streams (B, n_pad) from :func:`make_stream`, sharing one successor
+    table. The batch path runs on segment tensors instead
+    (``checker.batch``); this is the independent device cross-check
+    the tests compare it against."""
     bits = _bits_for(n_states, n_transitions, P)
     fn = functools.partial(_check_impl, succ, F=F, P=P, bits=bits)
     return jax.vmap(fn)(kind, proc, tr)
 
 
 @functools.lru_cache(maxsize=64)
-def _sharded_keys_fn(mesh, batch_axis: str, engine: str, B: int,
-                     F: int, P: int, n_states: int,
-                     n_transitions: int):
-    """One NAMED jitted shard_map program per (mesh, engine, shape)
-    class — the compile-surface guard keys observed lowerings by jit
-    name, and an eagerly-applied shard_map would log an anonymous
-    wrapper (same reason ``txn.closure_jax._jitted`` uses a named
-    wrapper). The per-shard body is the keys/flat engine at B/D."""
+def _sharded_keys_fn(mesh, batch_axis: str, B: int, F: int, P: int,
+                     n_states: int, n_transitions: int):
+    """One NAMED jitted shard_map program per (mesh, shape) class —
+    the compile-surface guard keys observed lowerings by jit name, and
+    an eagerly-applied shard_map would log an anonymous wrapper (same
+    reason ``txn.closure_jax._jitted`` uses a named wrapper). The
+    per-shard body is the keys engine at B/D."""
     from jax.sharding import PartitionSpec as PS
 
     D = mesh.shape[batch_axis]
-    base = check_device_keys if engine == "keys" else check_device_flat
-    fn = functools.partial(base, B=B // D, F=F, P=P, n_states=n_states,
-                           n_transitions=n_transitions)
+    fn = functools.partial(check_device_keys, B=B // D, F=F, P=P,
+                           n_states=n_states, n_transitions=n_transitions)
     sm = jax.shard_map(
         lambda s, ip, it, op, dp: fn(s, ip, it, op, dp),
         mesh=mesh,
@@ -1536,16 +1343,15 @@ def _sharded_keys_fn(mesh, batch_axis: str, engine: str, B: int,
 def check_device_keys_sharded(mesh, succ, inv_proc, inv_tr, ok_proc,
                               depth, *, B: int, F: int, P: int,
                               n_states: int, n_transitions: int,
-                              batch_axis: str = "batch",
-                              engine: str = "keys"):
-    """shard_map the keys/flat engine over the mesh's batch axis: each
+                              batch_axis: str = "batch"):
+    """shard_map the keys engine over the mesh's batch axis: each
     device runs its own flat batch of B/D histories — pure data
     parallelism over ICI, zero cross-device collectives (the device
     form of ``independent/checker``'s per-key partitioning,
     ``independent.clj:252-300``; SURVEY §2.5 item 8).
 
     Round 1 routed every mesh run to the vmapped per-lane engine
-    (~20x worse per lane); this keeps the fast flat engines under
+    (~20x worse per lane); this keeps the key-packed engine under
     sharding. B must be divisible by the mesh axis size (callers pad
     with sentinel histories — ``checker.batch`` pads B to a pow2
     multiple of D so per-shard shapes stay inside the bucketed
@@ -1553,8 +1359,8 @@ def check_device_keys_sharded(mesh, succ, inv_proc, inv_tr, ok_proc,
     global DISPATCHES
     D = mesh.shape[batch_axis]
     assert B % D == 0, (B, D)
-    fn = _sharded_keys_fn(mesh, batch_axis, engine, B, F, P,
-                          n_states, n_transitions)
+    fn = _sharded_keys_fn(mesh, batch_axis, B, F, P, n_states,
+                          n_transitions)
     DISPATCHES += 1
     return fn(succ, inv_proc, inv_tr, ok_proc, depth)
 
@@ -1565,10 +1371,11 @@ def check_sharded(mesh, succ, kind, proc, tr, *, F: int, P: int,
     """TEST ORACLE ONLY — the vmap engine sharded over a device mesh.
 
     Removed from the production batch path (round 7): vmap lowers ~20x
-    worse per lane than the flat-batch encodings (CLAUDE.md), so
+    worse per lane than the key-packed encoding (CLAUDE.md), so
     sharding it scales a pessimized program; ``check_batch`` routes
-    mesh traffic through the stream/keys/flat sharded engines instead
-    and degrades to SINGLE-device vmap when nothing else fits. This
+    mesh traffic through the stream/keys sharded engines instead and
+    degrades to the SINGLE-device segmented vmap
+    (:func:`check_device_seg_batch`) when nothing else fits. This
     stays as an independent cross-check for the mesh parity suite (a
     second sharded code path with unrelated lowering). The
     ``vmap-sharded-oracle`` analysis rule flags any non-test caller.

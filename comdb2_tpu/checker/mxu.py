@@ -50,7 +50,7 @@ Design:
 Shape discipline: F and the memo dims ride the usual pow2 buckets
 (``pad_succ``); P is even-bucketed by the driver like the XLA engines;
 the batch form's tensors are the same ``(S, B, K)`` family the
-keys/flat engines use, so the serving layer's closed-program-set
+keys engine uses, so the serving layer's closed-program-set
 rules apply unchanged (PROGRAMS.md `mxu-frontier` site).
 
 Crossover (docs/architecture.md has the arithmetic): below P = 16 the
@@ -442,7 +442,7 @@ def check_device_mxu_batch(succ, inv_proc, inv_tr, ok_proc, depth, *,
                            n_transitions: int):
     """The batched MXU engine: B histories, packed-word frontier,
     matmul expansion. Same tensors and outputs as
-    :func:`~.linear_jax.check_device_flat` — seg arrays
+    :func:`~.linear_jax.check_device_keys` — seg arrays
     inv_proc/inv_tr (S, B, K), ok_proc (S, B); returns per-batch
     ``(status[B], fail_segment[B], n_final[B])``."""
     carry = _device_carry(_plan_for(n_states, n_transitions, P), B, F)

@@ -27,8 +27,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="0 = pick a free port (printed in the "
                         "ready line)")
     p.add_argument("--model", default="cas-register")
-    p.add_argument("--engine", default="auto",
-                   choices=["auto", "stream", "keys", "flat", "vmap"])
     p.add_argument("--backend", default="auto",
                    choices=["auto", "cpu", "tpu"],
                    help="JAX platform: auto = as JAX is configured; "
@@ -123,8 +121,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     limits = ServiceLimits(max_ops=args.max_ops,
                            max_segments=args.max_segments)
     core = VerifierCore(
-        model=args.model, engine=args.engine,
-        F=args.frontier, batch_cap=args.batch_cap,
+        model=args.model, F=args.frontier, batch_cap=args.batch_cap,
         max_queue=args.max_queue, limits=limits,
         inject_dispatch_latency_s=args.inject_dispatch_latency_ms
         / 1e3, shards=args.shards,

@@ -7,9 +7,10 @@ set of shapes no matter what traffic arrives. Every admitted history
 is quantized onto a bucket whose axes mirror what actually reaches
 the jit boundaries in :func:`comdb2_tpu.checker.batch.check_batch`:
 
-- ``n_pad``  — the op-stream pad (pow2, floor 16): the vmap engine's
-  scan length.
-- ``S``      — padded segment count (pow2, floor 8): the keys/flat
+- ``n_pad``  — the op-stream pad (pow2, floor 16). No engine reads it
+  since the batch path runs on segments alone; it stays a bucket axis
+  because it decides which requests coalesce.
+- ``S``      — padded segment count (pow2, floor 8): the XLA
   engines' scan length and the streamed kernel's chunk budget.
 - ``K``      — padded invokes-per-segment (pow2, floor 2).
 - ``P``      — the slot-tensor width the engines compile for. This is

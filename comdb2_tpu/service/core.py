@@ -58,7 +58,7 @@ from .bucketing import (Bucket, ServiceLimits, StreamBucket,
 #: begin), host_pack (columnar pack/segment/remap + stage), device
 #: (dispatch -> readback complete, including the async overlap window
 #: and any injected dispatch latency), finalize (readback -> reply) —
-#: so scripts/bench_service.py can assert the sum against latency_ms.
+#: so a client can check the sum against latency_ms.
 #: EVERY completed request observes all four (absent stages count as
 #: 0 — deadline expiries are pure queue wait), so the four histograms
 #: and the latency histogram share one count.
@@ -153,8 +153,7 @@ class VerifierCore:
     daemon owns the clock so tests can drive launch budgets and
     deadlines deterministically."""
 
-    def __init__(self, model: str = "cas-register",
-                 engine: str = "auto", F: int = 1024,
+    def __init__(self, model: str = "cas-register", F: int = 1024,
                  batch_cap: int = 64, max_queue: int = 256,
                  limits: Optional[ServiceLimits] = None,
                  max_host_configs: int = 1 << 20,
@@ -169,7 +168,6 @@ class VerifierCore:
         if model not in MODELS:
             raise ValueError(f"unknown model {model!r}")
         self.model = model
-        self.engine = engine
         self.F = F
         self.batch_cap = batch_cap
         self.max_queue = max_queue
@@ -763,7 +761,6 @@ class VerifierCore:
                         "seed verdict is True — only INVALID "
                         "histories shrink", rid)
                 job = Shrinker(ops, MODELS[model](), F=self.F,
-                               engine=self.engine,
                                max_batch=self.batch_cap,
                                round_cap=round_cap,
                                mesh=self.mesh)
@@ -1434,12 +1431,11 @@ class VerifierCore:
         try:
             with obs.span("stage", kind="check", bucket=bucket.key,
                           b=len(items), b_prog=b_prog, rids=rids):
-                batch = pack_batch(packeds, MODELS[model_name](),
-                                   n_pad=bucket.n_pad)
+                batch = pack_batch(packeds, MODELS[model_name]())
                 ns = _next_pow2(batch.memo.n_states)
                 nt = _next_pow2(batch.memo.n_transitions)
                 fin = check_batch_async(
-                    batch, F=self.F, engine=self.engine, info=info,
+                    batch, F=self.F, info=info,
                     mesh=self.mesh,
                     s_pad=bucket.S, k_pad=bucket.K,
                     n_states_pad=ns, n_transitions_pad=nt,
@@ -1470,7 +1466,7 @@ class VerifierCore:
                 return
             self._sleep_remaining_latency(t_staged)
             t_done = obs.monotonic()
-            eng = info.get("engine", self.engine)
+            eng = info["engine"]
             xfer = info.get("transfer_bytes") or {}
             self._account_dispatch(bucket.key, t_staged, t_done,
                                    eng, xfer, rids)
@@ -2035,7 +2031,6 @@ class VerifierCore:
                 "restores": self.sessions.restores,
             },
             "model": self.model,
-            "engine": self.engine,
             "shards": self.shards,
             "frontier": self.F,
             "batch_cap": self.batch_cap,

@@ -284,9 +284,27 @@ class VerifierDaemon:
     #: it is treated as gone.
     SEND_TIMEOUT_S = 5.0
 
+    @staticmethod
+    def _peer_closed(sock: socket.socket) -> bool:
+        """Whether the client has closed its end. A reply sent before
+        the selector reads that EOF would still "succeed" (TCP answers
+        the first write to a closed peer with a reset, not an error),
+        so it would count as delivered to nobody. Pending pipelined
+        requests peek as data: that client is alive."""
+        try:
+            return sock.recv(1, socket.MSG_PEEK) == b""
+        except (BlockingIOError, InterruptedError):
+            return False
+        except OSError:
+            return True
+
     def _send(self, conn: Optional[_Conn], obj: dict) -> None:
         if conn is None or conn.sock.fileno() < 0:
             self._dropped_replies += 1
+            return
+        if self._peer_closed(conn.sock):
+            self._dropped_replies += 1
+            self._close(conn)
             return
         try:
             conn.sock.settimeout(self.SEND_TIMEOUT_S)

@@ -271,7 +271,7 @@ class Shrinker(DdminEngine):
 
     def __init__(self, history: Union[Sequence[Op], PackedHistory],
                  model: Union[Model, str, None] = None, *,
-                 F: int = 1024, engine: str = "auto", mesh=None,
+                 F: int = 1024, mesh=None,
                  max_states: int = 1 << 20,
                  max_batch: int = MAX_BATCH,
                  round_cap: Optional[int] = None):
@@ -281,7 +281,6 @@ class Shrinker(DdminEngine):
         self.packed = (history if isinstance(history, PackedHistory)
                        else pack_history(list(history)))
         self.F = F
-        self.engine = engine
         self.mesh = mesh
         self.max_batch = max_batch
         self.atoms, self.pinned = atoms_of(self.packed)
@@ -306,7 +305,7 @@ class Shrinker(DdminEngine):
     def _statuses(self, cand_sets: List[List[int]]) -> np.ndarray:
         return check_candidates(
             self.packed, [self.mask_of(s) for s in cand_sets],
-            self.memo, F=self.F, engine=self.engine, mesh=self.mesh,
+            self.memo, F=self.F, mesh=self.mesh,
             max_batch=self.max_batch, counters=self.counters)
 
     def _test(self, cand_sets: List[List[int]]) -> np.ndarray:
@@ -350,8 +349,7 @@ class Shrinker(DdminEngine):
 
 def minimize(history, *, checker: str = "linear",
              model: Union[Model, str, None] = None,
-             realtime: bool = False, F: int = 1024,
-             engine: str = "auto", mesh=None,
+             realtime: bool = False, F: int = 1024, mesh=None,
              max_states: int = 1 << 20,
              deadline_s: Optional[float] = None,
              max_rounds: int = 100_000) -> ShrinkResult:
@@ -370,7 +368,7 @@ def minimize(history, *, checker: str = "linear",
 
         job = TxnShrinker(history, realtime=realtime, mesh=mesh)
     elif checker == "linear":
-        job = Shrinker(history, model, F=F, engine=engine, mesh=mesh,
+        job = Shrinker(history, model, F=F, mesh=mesh,
                        max_states=max_states)
     else:
         raise ValueError(f"no shrinker for checker {checker!r}")

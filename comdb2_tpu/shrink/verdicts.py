@@ -52,8 +52,7 @@ def bucket_of(n_rows: int) -> int:
 
 
 def check_candidates(parent: PackedHistory, masks: Sequence[np.ndarray],
-                     memo: MemoizedModel, *, F: int = 1024,
-                     engine: str = "auto", mesh=None,
+                     memo: MemoizedModel, *, F: int = 1024, mesh=None,
                      max_batch: int = MAX_BATCH,
                      counters: Optional[dict] = None) -> np.ndarray:
     """Verdict-test B candidate row masks of one packed parent.
@@ -85,7 +84,7 @@ def check_candidates(parent: PackedHistory, masks: Sequence[np.ndarray],
             try:
                 batch = pack_batch_masked(parent, cand, memo)
                 status, _, _ = check_batch(
-                    batch, F=F, engine=engine, mesh=mesh,
+                    batch, F=F, mesh=mesh,
                     n_states_pad=ns, n_transitions_pad=nt)
                 out[chunk] = status[:len(chunk)]
             except Exception:           # noqa: BLE001 — engine blowup
@@ -101,8 +100,7 @@ def check_candidates(parent: PackedHistory, masks: Sequence[np.ndarray],
 def check_candidate(parent: PackedHistory, mask: np.ndarray,
                     memo: MemoizedModel, **kw) -> int:
     """ONE candidate, one dispatch — the serial control the batched
-    path exists to beat (``scripts/bench_shrink.py`` measures the
-    gap). Production code must batch a round's candidates through
+    path exists to beat. Production code must batch a round's candidates through
     :func:`check_candidates` instead; the ``per-item-dispatch``
     analysis rule flags loops over this entry point."""
     return int(check_candidates(parent, [mask], memo, **kw)[0])

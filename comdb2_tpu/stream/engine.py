@@ -653,7 +653,8 @@ def pick_rung(n_states: int, n_transitions: int, P2: int, K: int,
               engine: str = "auto") -> str:
     """Rung policy, mirroring the driver ladder: kernel when the
     fused spec serves the shape, MXU for wide P, XLA otherwise.
-    ``engine`` forces a specific rung (tests / ``--engine``)."""
+    ``engine`` forces a specific rung (tests / a session's ``rung``
+    request field)."""
     if engine in ("kernel", "mxu", "xla"):
         return engine
     if P2 <= 2 * PSEG.ROWS - 1 and K <= 8 \

@@ -20,9 +20,9 @@ red test (or a failed bench run), not a 38-minute mystery:
 - :func:`CompileGuard.offenders` / :func:`assert_closed` check the
   observed set against the static inventory — tier-1 runs a
   mixed-shape workload under the guard and asserts observed ⊆
-  declared; ``bench.py`` and the bench scripts do the same on real
-  runs (env ``COMDB2_TPU_COMPILE_GUARD=0`` disables the bench
-  assertion, never the capture).
+  declared; the bench scripts do the same on real runs (env
+  ``COMDB2_TPU_COMPILE_GUARD=0`` disables the bench assertion, never
+  the capture).
 
 Usage::
 

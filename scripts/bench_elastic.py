@@ -45,18 +45,61 @@ import argparse
 import json
 import os
 import random
+import shutil
+import socket
 import subprocess
 import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "scripts"))
 
-from bench_routing import (find_ct_pmux, free_port,  # noqa: E402
-                           start_pmux)
-
 SIZE_CLASSES = (10, 18, 30, 60, 140, 180)
+
+
+def free_port() -> int:
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
+
+
+def find_ct_pmux() -> str:
+    """The pmux binary: a prior native build, or a direct g++ build
+    (the same line scripts/check.sh falls back to)."""
+    for cand in ("native/build/ct_pmux", "native/build-asan/ct_pmux"):
+        p = os.path.join(REPO, cand)
+        if os.path.exists(p):
+            return p
+    if shutil.which("g++") is None:
+        raise SystemExit("no ct_pmux build and no g++ to make one")
+    out = os.path.join(tempfile.mkdtemp(prefix="ct_pmux_"), "ct_pmux")
+    subprocess.run(
+        ["g++", "-O1", "-Wall", "-Inative/include",
+         "native/src/pmux_main.cpp", "-o", out, "-lpthread"],
+        cwd=REPO, check=True)
+    return out
+
+
+def start_pmux(binary: str, port: int):
+    proc = subprocess.Popen([binary, "-p", str(port)],
+                            stdout=subprocess.DEVNULL,
+                            stderr=subprocess.DEVNULL)
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        try:
+            socket.create_connection(("127.0.0.1", port),
+                                     timeout=0.2).close()
+            return proc
+        except OSError:
+            time.sleep(0.1)
+    proc.kill()
+    proc.wait(timeout=30)   # no init reaper: reap before raising
+    raise SystemExit("ct_pmux never came up")
+
 
 DAEMON_ARGS = ["--backend", "cpu", "--no-prime", "--frontier", "64",
                "--fill-ms", "5"]

@@ -407,9 +407,8 @@ EOF
 stage multichip "multichip dryrun (8-device CPU mesh, interpret kernel)"
 # the full sharded checking step on the forced 8-device CPU mesh:
 # shard_map stream path (fused kernel in interpret mode), kernel/XLA
-# bit-parity, escalation on one shard, in-place ladder, wide-P — the
-# same gate MULTICHIP_r0N.json records (runs in a subprocess so the
-# corrected env lands before any jax import)
+# bit-parity, escalation on one shard, in-place ladder, wide-P (runs
+# in a subprocess so the corrected env lands before any jax import)
 run env JAX_PLATFORMS=cpu python -c \
     "import __graft_entry__ as g; g.dryrun_multichip(8)"
 

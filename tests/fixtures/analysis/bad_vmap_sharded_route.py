@@ -1,8 +1,8 @@
 """Seeded violation: production code routing mesh traffic onto the
 vmap-sharded TEST ORACLE. ``linear_jax.check_sharded`` shard_maps the
-vmap engine, which lowers ~20x worse per lane than the flat-batch
-encodings — round 7 removed the last production route; serving
-traffic goes through ``checker.batch.check_batch``'s stream/keys/flat
+vmap engine, which lowers ~20x worse per lane than the key-packed
+encoding — round 7 removed the last production route; serving
+traffic goes through ``checker.batch.check_batch``'s stream/keys
 sharded engines."""
 
 

@@ -205,10 +205,12 @@ def test_check_batch_verdict_parity_legacy_vs_columnar(monkeypatch):
         h = register_history(rng, n_procs=3, n_events=40, values=3,
                              p_info=0.0)
         hs.append(mutate(rng, h) if i % 2 else h)
+    info: dict = {}
     col = check_batch(pack_batch([list(h) for h in hs],
-                                 cas_register()), F=64, engine="keys")
+                                 cas_register()), F=64, info=info)
+    assert info["engine"] == "keys"
     monkeypatch.setenv("COMDB2_TPU_LEGACY_PACK", "1")
     leg = check_batch(pack_batch([list(h) for h in hs],
-                                 cas_register()), F=64, engine="keys")
+                                 cas_register()), F=64)
     for a, b in zip(col, leg):
         assert np.array_equal(np.asarray(a), np.asarray(b))

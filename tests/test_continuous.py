@@ -114,8 +114,10 @@ def test_idle_launch_answers_serial_callers():
 def test_ring_bounds_staged_dispatches():
     """More launchable buckets than ring slots: the ring finalizes
     oldest-first on overflow, every reply still arrives, and the
-    occupancy gauge ends at zero."""
-    core = _core(batch_cap=8, ring_depth=2)
+    occupancy gauge ends at zero. The fill window outlasts the test,
+    so every launch is the flush's idle launch, never a deadline one
+    however long the submits take."""
+    core = _core(batch_cap=8, ring_depth=2, fill_window_s=3600.0)
     sizes = (16, 40, 88, 150)             # 4 distinct shape buckets
     for i, n_events in enumerate(sizes):
         _submit(core, register_history(random.Random(51 + i), 3,
@@ -190,15 +192,14 @@ def test_donated_carry_parity_stream():
     PS.use_interpret(True)
     try:
         assert PS.donation_active()
-        r_don = B.check_batch(B.pack_batch(hs, model), F=64,
-                              engine="stream")
+        info: dict = {}
+        r_don = B.check_batch(B.pack_batch(hs, model), F=64, info=info)
+        assert info["engine"] == "stream"
         reuses0 = PS.CARRY_REUSES
-        r_don2 = B.check_batch(B.pack_batch(hs, model), F=64,
-                               engine="stream")
+        r_don2 = B.check_batch(B.pack_batch(hs, model), F=64)
         assert PS.CARRY_REUSES > reuses0   # the pool served a rerun
         PS.use_carry_donation(False)
-        r_plain = B.check_batch(B.pack_batch(hs, model), F=64,
-                                engine="stream")
+        r_plain = B.check_batch(B.pack_batch(hs, model), F=64)
     finally:
         PS.use_carry_donation(True)
         PS.use_interpret(False)

@@ -14,6 +14,7 @@ from comdb2_tpu.ops import op as O
 from comdb2_tpu.ops.packed import pack_history
 
 import histgen
+from engine_oracles import last_resort_nt
 
 
 def device_check(model, history, F=64):
@@ -166,10 +167,16 @@ def test_device_batch():
     assert got == want
 
 
-def test_flat_engine_matches_host_fuzz():
-    """The flat-batch engine (one frontier tensor, batch id in the sort
-    key) must agree with the host engine on random mixed batches."""
+def test_last_resort_matches_host_fuzz(monkeypatch):
+    """The batch path's last resort (the segmented search vmapped over
+    lanes) must agree with the host engine on random mixed batches.
+    Five or more processes at a 64-transition table are past the keys
+    engine's 62-bit key, so check_batch itself routes them there; info
+    ops can widen a process table past the MXU crossover, so that
+    engine is switched off."""
     from comdb2_tpu.checker.batch import pack_batch, check_batch
+
+    monkeypatch.setenv("COMDB2_TPU_MXU", "0")
 
     model = M.cas_register()
     for round_ in range(4):
@@ -177,7 +184,7 @@ def test_flat_engine_matches_host_fuzz():
         for seed in range(12):
             rng = random.Random(77_000 + round_ * 100 + seed)
             h = histgen.register_history(
-                rng, n_procs=rng.randint(2, 4),
+                rng, n_procs=rng.randint(5, 8),
                 n_events=rng.randint(5, 24),
                 p_info=0.1 if seed % 3 == 0 else 0.0)
             if seed % 2:
@@ -187,14 +194,19 @@ def test_flat_engine_matches_host_fuzz():
             mm = make_memo(model, packed)
             want.append(linear_host.check(mm, packed).valid)
         batch = pack_batch(histories, model)
-        status, fail_at, n = check_batch(batch, F=128, engine="flat")
+        info = {}
+        status, fail_at, n = check_batch(
+            batch, F=1024, n_transitions_pad=last_resort_nt(batch),
+            info=info)
+        assert info["engine"] == "vmap", info
         got = [s == LJ.VALID for s in status]
         assert got == want, (round_, got, want)
 
 
 def test_flat_engines_overflow_unknown():
     """A batch lane whose frontier exceeds F must come back UNKNOWN,
-    not a wrong definite verdict — in both flat engines."""
+    not a wrong definite verdict — in the keys engine and in the last
+    resort."""
     from comdb2_tpu.checker.batch import pack_batch, check_batch
 
     model = M.cas_register()
@@ -206,8 +218,11 @@ def test_flat_engines_overflow_unknown():
     small = histgen.register_history(random.Random(1), n_procs=2,
                                      n_events=8, p_info=0.0)
     batch = pack_batch([wide, small], model)
-    for engine in ("flat", "keys"):
-        status, _, _ = check_batch(batch, F=2, engine=engine)
+    for engine, nt in (("keys", 0), ("vmap", last_resort_nt(batch))):
+        info = {}
+        status, _, _ = check_batch(batch, F=2, n_transitions_pad=nt,
+                                   info=info)
+        assert info["engine"] == engine, info
         assert status[0] == LJ.UNKNOWN, (engine, status)
         assert status[1] in (LJ.VALID, LJ.UNKNOWN)
 
@@ -218,9 +233,7 @@ def test_pack_bits_rejects_fragmented_budgets():
     sb, tb, fits = LJ.pack_bits(1 << 20, (1 << 20) - 2, 2)
     assert not fits                     # 20+20+20: hi word gets 40 bits
     assert LJ.pack_bits(8, 30, 8)[2]    # 3 + 8*5 splits fine
-    bb, stb, slb, ffits = LJ.flat_pack_bits(2, 1 << 18, (1 << 20) - 2, 2)
-    assert not ffits
-    # and KeyLayout agrees where flat_pack_bits says no
+    # KeyLayout (batch field included) says no to 18+20+20+20 bits
     assert not LJ.KeyLayout(2, 1 << 18, (1 << 20) - 2, 2).fits
 
 
@@ -258,15 +271,22 @@ def test_malformed_history_isolated_in_batch():
     from comdb2_tpu.checker.batch import pack_batch, check_batch
     from comdb2_tpu.ops import op as O
 
-    good = [O.invoke(0, "write", 1), O.ok(0, "write", 1)]
+    # five processes: wide enough that a 64-transition table is past
+    # the keys engine's key, so the last resort is reachable too
+    good = ([O.invoke(p, "write", 1) for p in range(5)]
+            + [O.ok(p, "write", 1) for p in range(5)])
     bad = [O.invoke(0, "write", 1), O.invoke(0, "write", 2),
            O.ok(0, "write", 2)]
     # build the malformed history by hand: complete() would raise
     bad = [op.with_(index=i) for i, op in enumerate(bad)]
     packed_bad = pack_history(bad, completed=True)
     batch = pack_batch([good, packed_bad, good], M.cas_register())
-    for engine in ("keys", "flat", "vmap"):
-        status, fail_at, n = check_batch(batch, F=32, engine=engine)
+    for engine, nt in (("keys", 0), ("vmap", last_resort_nt(batch))):
+        info = {}
+        status, fail_at, n = check_batch(batch, F=32,
+                                         n_transitions_pad=nt,
+                                         info=info)
+        assert info["engine"] == engine, info
         assert status[0] == LJ.VALID and status[2] == LJ.VALID
         assert status[1] == LJ.UNKNOWN, (engine, status)
         assert fail_at[1] == -1 and n[1] == 0
@@ -291,7 +311,9 @@ def test_keys_engine_matches_host_fuzz():
             mm = make_memo(model, packed)
             want.append(linear_host.check(mm, packed).valid)
         batch = pack_batch(histories, model)
-        status, fail_at, n = check_batch(batch, F=128, engine="keys")
+        info = {}
+        status, fail_at, n = check_batch(batch, F=128, info=info)
+        assert info["engine"] == "keys", info
         got = [s == LJ.VALID for s in status]
         assert got == want, (round_, got, want)
 
@@ -318,9 +340,9 @@ def test_device_batch_sharded_mesh():
 
 
 def test_sharded_engines_match_solo():
-    """Sharded keys/flat runs (8-device CPU mesh, B not divisible by
-    the axis) must return the same verdicts and fail indices as the
-    single-device engines on mixed valid/invalid/info histories."""
+    """Sharded keys runs (8-device CPU mesh, B not divisible by the
+    axis) must return the same verdicts and fail indices as the
+    single-device engine on mixed valid/invalid/info histories."""
     import jax
     from jax.sharding import Mesh
     from comdb2_tpu.checker.batch import pack_batch, check_batch
@@ -338,17 +360,16 @@ def test_sharded_engines_match_solo():
         histories.append(h)
     batch = pack_batch(histories, model)
     mesh = Mesh(np.array(jax.devices()), ("batch",))
-    solo_status, solo_fail, solo_n = check_batch(batch, F=64,
-                                                 engine="keys")
-    for engine in ("keys", "flat"):
-        info = {}
-        status, fail_at, n = check_batch(batch, F=64, mesh=mesh,
-                                         engine=engine, info=info)
-        assert info["engine"] == f"{engine}-sharded", info
-        assert status.shape == (13,)
-        assert list(status) == list(solo_status), (engine, status)
-        assert list(fail_at) == list(solo_fail), (engine, fail_at)
-        assert list(n) == list(solo_n), (engine, n)
+    info = {}
+    solo_status, solo_fail, solo_n = check_batch(batch, F=64, info=info)
+    assert info["engine"] == "keys", info
+    info = {}
+    status, fail_at, n = check_batch(batch, F=64, mesh=mesh, info=info)
+    assert info["engine"] == "keys-sharded", info
+    assert status.shape == (13,)
+    assert list(status) == list(solo_status), status
+    assert list(fail_at) == list(solo_fail), fail_at
+    assert list(n) == list(solo_n), n
 
 
 def test_dedup_survives_sentinel_collisions():

@@ -8,8 +8,8 @@ kernel escalation mid-batch on one shard, and the compile guard
 proving observed lowerings stay inside the shard-extended
 PROGRAMS.md inventory. The fused kernel's sharded semantics run here
 through Pallas interpret mode (exact kernel as XLA ops; Mosaic is
-TPU-only) — the real-chip twin is ``scripts/bench_multichip.py`` and
-the ``multichip`` stage of ``check.sh``.
+TPU-only) — the real-chip twin is ``python chip_smoke.py --multichip``
+and the ``multichip`` stage of ``check.sh``.
 """
 
 import random
@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import histgen
+from engine_oracles import dense_streams, keys_verdicts, last_resort_nt
 from comdb2_tpu.checker import batch as CB
 from comdb2_tpu.checker import linear_jax as LJ
 from comdb2_tpu.checker import pallas_seg as PSEG
@@ -81,16 +82,32 @@ def test_merge_stream_shards_reassembles_slice_order():
 # --- register/cas + keyed parity over the XLA sharded engines ----------
 
 
-@pytest.mark.parametrize("engine", ["keys", "flat"])
+@pytest.mark.parametrize("engine", ["keys", "vmap"])
 def test_register_parity_b_not_divisible(engine):
     """13 mixed valid/invalid/info histories over 8 shards: verdicts
-    bit-identical with the single-device engine, pads invisible."""
+    bit-identical with the single-device run, pads invisible. The
+    last resort ("vmap", reached through a table too wide for the
+    keys engine's key) runs one device under the mesh and says so,
+    and agrees with the keys engine's verdicts."""
     batch = pack_batch(_mixed_histories(13), M.cas_register())
-    solo = check_batch(batch, F=64, engine=engine)
+    nt = last_resort_nt(batch) if engine == "vmap" else 0
+    solo_info: dict = {}
+    solo = check_batch(batch, F=64, n_transitions_pad=nt,
+                       info=solo_info)
+    assert solo_info["engine"] == engine
     info: dict = {}
-    st, fa, n = check_batch(batch, F=64, engine=engine, mesh=_mesh(),
-                            info=info)
-    assert info["engine"] == f"{engine}-sharded"
+    st, fa, n = check_batch(batch, F=64, mesh=_mesh(),
+                            n_transitions_pad=nt, info=info)
+    if engine == "vmap":
+        assert info["engine"] == "vmap" and info["mesh_dropped"]
+        keys = keys_verdicts(batch, 64)
+        np.testing.assert_array_equal(st, keys[0])
+        np.testing.assert_array_equal(fa, keys[1])
+        ok = st == LJ.VALID
+        np.testing.assert_array_equal(n[ok], keys[2][ok])
+    else:
+        assert info["engine"] == "keys-sharded"
+        assert "mesh_dropped" not in info
     assert info["batch"] == {"b": 13, "b_pad": 16, "pad": 3,
                              "shards": 8}
     assert st.shape == (13,)            # pads can never surface
@@ -110,10 +127,12 @@ def test_register_parity_vs_vmap_oracle():
                        1 << (batch.memo.n_transitions - 1).bit_length())
     P = max(batch.P, 2) + (max(batch.P, 2) & 1)
     st_o, _, n_o = (np.asarray(x) for x in LJ.check_sharded(
-        _mesh(), succ, batch.kind, batch.proc, batch.tr, F=64, P=P,
+        _mesh(), succ, *dense_streams(batch), F=64, P=P,
         n_states=batch.memo.n_states,
         n_transitions=batch.memo.n_transitions))
-    st, _, n = check_batch(batch, F=64, engine="keys", mesh=_mesh())
+    info: dict = {}
+    st, _, n = check_batch(batch, F=64, mesh=_mesh(), info=info)
+    assert info["engine"] == "keys-sharded"
     np.testing.assert_array_equal(st, st_o)
     ok = st == LJ.VALID
     np.testing.assert_array_equal(n[ok], n_o[ok])
@@ -140,8 +159,10 @@ def test_keyed_parity():
     subs = [subhistory(k, wrapped) for k in history_keys(wrapped)]
     assert len(subs) >= 4
     batch = pack_batch(subs, M.cas_register())
-    solo = check_batch(batch, F=64, engine="keys")
-    st, fa, n = check_batch(batch, F=64, engine="keys", mesh=_mesh())
+    solo = check_batch(batch, F=64)
+    info: dict = {}
+    st, fa, n = check_batch(batch, F=64, mesh=_mesh(), info=info)
+    assert info["engine"] == "keys-sharded"
     np.testing.assert_array_equal(st, solo[0])
     np.testing.assert_array_equal(fa, solo[1])
     np.testing.assert_array_equal(n, solo[2])
@@ -152,10 +173,11 @@ def test_all_shard_sizes_match():
     (dispatch-width scaling changes shapes, never answers)."""
     batch = pack_batch(_mixed_histories(11, seed=90_000),
                        M.cas_register())
-    solo = check_batch(batch, F=64, engine="keys")
+    solo = check_batch(batch, F=64)
     for d in (1, 2, 4, 8):
-        st, fa, n = check_batch(batch, F=64, engine="keys",
-                                mesh=_mesh(d))
+        info: dict = {}
+        st, fa, n = check_batch(batch, F=64, mesh=_mesh(d), info=info)
+        assert info["engine"] == "keys-sharded", f"D={d}"
         np.testing.assert_array_equal(st, solo[0], err_msg=f"D={d}")
         np.testing.assert_array_equal(fa, solo[1], err_msg=f"D={d}")
         np.testing.assert_array_equal(n, solo[2], err_msg=f"D={d}")
@@ -164,7 +186,7 @@ def test_all_shard_sizes_match():
 def test_non_pow2_mesh_rejected():
     with pytest.raises(ValueError, match="power of two"):
         check_batch(pack_batch(_mixed_histories(4), M.cas_register()),
-                    F=64, engine="keys", mesh=_mesh(3))
+                    F=64, mesh=_mesh(3))
 
 
 # --- txn closure parity ------------------------------------------------
@@ -242,8 +264,7 @@ def test_shrink_parity_mesh():
     seed, _ = inject_anomaly(base, "stale-read")
 
     def run(mesh):
-        job = Shrinker(seed, "cas-register", F=64, engine="keys",
-                       mesh=mesh)
+        job = Shrinker(seed, "cas-register", F=64, mesh=mesh)
         while not job.step():
             pass
         assert job.error is None
@@ -267,8 +288,8 @@ def test_pads_never_surface_anywhere():
     hs = _mixed_histories(3, seed=55_000)
     batch = pack_batch(hs, M.cas_register())
     info: dict = {}
-    st, fa, n = check_batch(batch, F=64, engine="keys", mesh=_mesh(),
-                            info=info)
+    st, fa, n = check_batch(batch, F=64, mesh=_mesh(), info=info)
+    assert info["engine"] == "keys-sharded"
     assert info["batch"] == {"b": 3, "b_pad": 8, "pad": 5,
                              "shards": 8}
     assert st.shape == fa.shape == n.shape == (3,)
@@ -293,8 +314,7 @@ def test_shrink_candidates_exclude_pads():
                          max_depth=len(seed))
     full = np.ones(len(parent), bool)
     masks = [full.copy() for _ in range(3)]
-    st = check_candidates(parent, masks, memo, F=64, engine="keys",
-                          mesh=_mesh())
+    st = check_candidates(parent, masks, memo, F=64, mesh=_mesh())
     assert st.shape == (3,)
     assert (st == LJ.INVALID).all()
 
@@ -323,21 +343,22 @@ def test_stream_sharded_single_dispatch_counters(interpret_kernel):
     d0, m0 = PSEG.DISPATCHES, PSEG.MOSAIC_BUILDS
     info: dict = {}
     st_s, fa_s, n_s = check_batch(batch, F=PSEG.F, mesh=_mesh(),
-                                  engine="stream", info=info)
+                                  info=info)
     assert info["engine"] == "stream-sharded"
     assert PSEG.DISPATCHES - d0 == 1        # one slice -> ONE dispatch
     builds_first = PSEG.MOSAIC_BUILDS - m0
     # a second run at another D must reuse the per-shard program
     batch2 = pack_batch(hs, M.cas_register())
     m1 = PSEG.MOSAIC_BUILDS
+    info2: dict = {}
     st2, fa2, n2 = check_batch(batch2, F=PSEG.F, mesh=_mesh(4),
-                               engine="stream")
+                               info=info2)
+    assert info2["engine"] == "stream-sharded"
     assert PSEG.MOSAIC_BUILDS - m1 <= builds_first
     np.testing.assert_array_equal(st_s, st2)
     np.testing.assert_array_equal(fa_s, fa2)
     # keys parity (counts compare on VALID only)
-    st_k, fa_k, n_k = check_batch(batch, F=PSEG.F, mesh=_mesh(),
-                                  engine="keys")
+    st_k, fa_k, n_k = keys_verdicts(batch, PSEG.F, mesh=_mesh(2))
     np.testing.assert_array_equal(st_s, st_k)
     np.testing.assert_array_equal(fa_s, fa_k)
     ok = st_s == LJ.VALID
@@ -367,15 +388,18 @@ def test_escalation_mid_batch_on_one_shard(interpret_kernel):
     F = 2 * PSEG.F_TOP
     batch = pack_batch(hs, M.cas_register())
     info: dict = {}
-    st, fa, n = check_batch(batch, F=F, mesh=_mesh(),
-                            engine="stream", info=info)
+    st, fa, n = check_batch(batch, F=F, mesh=_mesh(), info=info)
     assert info["engine"] == "stream-sharded"
     esc = info.get("escalated")
     assert esc and esc["count"] == 1 and esc["engine"], info
     # 11 processes pad the batch's slot axis to 16, past the keys
-    # engine's 62-bit layout: the MXU engine is the all-XLA oracle
+    # engine's 62-bit layout: the MXU engine is the all-XLA oracle,
+    # which check_batch picks once the kernel is off
+    PSEG.use_interpret(False)
+    solo_info: dict = {}
     solo = check_batch(pack_batch(hs, M.cas_register()), F=F,
-                       engine="mxu")
+                       info=solo_info)
+    assert solo_info["engine"] == "mxu"
     np.testing.assert_array_equal(st, solo[0])
     np.testing.assert_array_equal(fa, solo[1])
 
@@ -399,10 +423,11 @@ def test_guard_closed_over_mesh_workload():
             batch = pack_batch(hs, M.cas_register())
             ns = next_pow2(batch.memo.n_states)
             nt = next_pow2(batch.memo.n_transitions)
-            for engine in ("keys", "flat"):
-                check_batch(batch, F=64, engine=engine, mesh=mesh,
-                            s_pad=8, k_pad=2, n_states_pad=ns,
-                            n_transitions_pad=nt)
+            info: dict = {}
+            check_batch(batch, F=64, mesh=mesh, s_pad=8, k_pad=2,
+                        n_states_pad=ns, n_transitions_pad=nt,
+                        info=info)
+            assert info["engine"] == "keys-sharded"
         CJ.closure_diag_batch(np.zeros((3, 4, 32, 32), bool),
                               mesh=mesh)
     off = g.offenders(inv)

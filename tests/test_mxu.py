@@ -295,8 +295,7 @@ def test_batch_auto_picks_mxu_and_matches_driver():
     bad = SC.wide_register_batch_packed(67, 1, n_waves=2, n_chain=14,
                                         n_free=2, values=17,
                                         violation=True)
-    batch = pack_batch(ps + bad, M.cas_register(),
-                       build_streams=False)
+    batch = pack_batch(ps + bad, M.cas_register())
     info = {}
     st, fa, nf = check_batch(batch, F=1024, info=info)
     assert info["engine"] == "mxu"
@@ -316,8 +315,10 @@ def test_batch_lowerings_stay_inside_inventory():
 
     ps = SC.wide_register_batch_packed(71, 2, n_waves=2, n_chain=14,
                                        n_free=2, values=17)
-    batch = pack_batch(ps, M.cas_register(), build_streams=False)
+    batch = pack_batch(ps, M.cas_register())
+    info = {}
     with CG.guard() as g:
-        st, _, _ = check_batch(batch, F=1024, engine="mxu")
+        st, _, _ = check_batch(batch, F=1024, info=info)
+    assert info["engine"] == "mxu"
     assert st.tolist() == [LJ.VALID] * 2
     g.assert_closed(static_inventory())

@@ -117,25 +117,25 @@ def test_pack_stream_layout():
 
 
 def test_check_batch_stream_engine_falls_back_on_cpu():
-    """engine='auto' must not pick the stream engine where Mosaic is
-    unavailable; an explicit engine='stream' request must still produce
-    correct verdicts through the fallback."""
+    """check_batch must not pick the stream engine where Mosaic is
+    unavailable; it must still produce the keys engine's verdicts
+    through the XLA ladder."""
     import random
 
     import histgen
+    from engine_oracles import keys_verdicts
     from comdb2_tpu.checker.batch import pack_batch, check_batch
 
     rng = random.Random(5)
     hs = [histgen.register_history(rng, n_procs=2, n_events=12,
                                    p_info=0.0) for _ in range(6)]
     batch = pack_batch(hs, M.cas_register())
-    st, fa, n = check_batch(batch, engine="stream")
-    st2, fa2, n2 = check_batch(batch, engine="keys")
-    assert (st == st2).all() and (n == n2).all()
-    # auto must not pick the stream engine here (no Mosaic): same
-    # verdicts via the XLA ladder
-    st3, _, n3 = check_batch(batch)
-    assert (st3 == st2).all() and (n3 == n2).all()
+    assert not PS.available()
+    info: dict = {}
+    st, fa, n = check_batch(batch, info=info)
+    assert info["engine"] == "keys"
+    st2, fa2, n2 = keys_verdicts(batch, 256)
+    assert (st == st2).all() and (fa == fa2).all() and (n == n2).all()
 
 
 def test_check_batch_stream_unknown_escalates(monkeypatch):
@@ -152,7 +152,7 @@ def test_check_batch_stream_unknown_escalates(monkeypatch):
     hs = [histgen.register_history(rng, n_procs=2, n_events=16,
                                    p_info=0.0) for _ in range(5)]
     batch = B.pack_batch(hs, M.cas_register())
-    want = B.check_batch(batch, engine="keys")
+    want = B.check_batch(batch)             # the XLA ladder: no kernel
 
     def fake_dispatch(succ, segs_list, spec, n_states, n_transitions,
                       device=None):
@@ -168,9 +168,65 @@ def test_check_batch_stream_unknown_escalates(monkeypatch):
     monkeypatch.setattr(B.PSEG, "F_TOP", PS.F)
     monkeypatch.setattr(B.PSEG, "available", lambda: True)
     monkeypatch.setattr(B.PSEG, "stream_dispatch", fake_dispatch)
-    st, fa, n = B.check_batch(batch, F=256, engine="stream")
+    info: dict = {}
+    st, fa, n = B.check_batch(batch, F=256, info=info)
+    assert info["engine"] == "stream"
+    assert info["escalated"] == {"engine": "keys", "count": 1}
     assert (st == want[0]).all()          # UNKNOWN replaced by verdict
     assert n[2] == want[2][2]             # escalated lane's real count
+
+
+def test_masked_batch_overflow_escalates_to_the_last_resort(monkeypatch):
+    """Shrink candidates (``pack_batch_masked``) whose kernel UNKNOWN
+    escalates to a shape only the last resort serves get their exact
+    verdict, re-segmented from ``packeds`` like every other lane. Six
+    processes at a 64-transition table are past the keys engine's key
+    and below the MXU crossover."""
+    import random
+
+    import histgen
+    from engine_oracles import last_resort_nt
+    from comdb2_tpu.checker import batch as B
+    from comdb2_tpu.checker import linear_host
+    from comdb2_tpu.models.memo import memoize_model, transitions_of
+    from comdb2_tpu.ops.columnar import subset_packed
+    from comdb2_tpu.shrink.core import atoms_of
+
+    rng = random.Random(8)
+    parent = pack_history(histgen.mutate(rng, histgen.register_history(
+        rng, n_procs=6, n_events=30, p_info=0.0)))
+    memo = memoize_model(M.cas_register(), transitions_of(parent),
+                         max_depth=len(parent))
+    atoms, pinned = atoms_of(parent)
+    half = pinned.copy()
+    for a in atoms[:len(atoms) // 2]:
+        half[a] = True
+    masks = [np.ones(len(parent), bool), half] * 2
+    batch = B.pack_batch_masked(parent, masks, memo)
+    nt = last_resort_nt(batch)
+    sub = subset_packed(parent, masks[1])
+    want = linear_host.check(make_memo(M.cas_register(), sub), sub)
+
+    def fake_dispatch(succ, segs_list, spec, n_states, n_transitions,
+                      device=None):
+        # lane 1 pretends to overflow the kernel's top rung
+        res = np.zeros((len(segs_list), PS.LANES), np.int32)
+        res[:, :3] = [[2, 0, 0] if b == 1 else [0, -1, 1]
+                      for b in range(len(segs_list))]
+        return res, np.zeros(len(segs_list), np.int64)
+
+    monkeypatch.setattr(B.PSEG, "F_TOP", PS.F)
+    monkeypatch.setattr(B.PSEG, "available", lambda: True)
+    monkeypatch.setattr(B.PSEG, "stream_dispatch", fake_dispatch)
+    info: dict = {}
+    st, fa, n = B.check_batch(batch, F=256, n_transitions_pad=nt,
+                              info=info)
+    assert info["engine"] == "stream"
+    assert info["escalated"] == {"engine": "vmap", "count": 1}
+    assert st.tolist() == [LJ.VALID, LJ.VALID if want.valid
+                           else LJ.INVALID, LJ.VALID, LJ.VALID]
+    if not want.valid:
+        assert fa[1] == want.op_index
 
 
 def test_check_device_pallas_none_when_unfit():
@@ -242,6 +298,7 @@ def test_interpret_kernel_stream_sharded_matches_keys(interpret_kernel):
     import jax
     from jax.sharding import Mesh
 
+    from engine_oracles import keys_verdicts
     from comdb2_tpu.checker.batch import check_batch, pack_batch
 
     hs = _parity_histories() * 2                # 10 histories
@@ -249,12 +306,11 @@ def test_interpret_kernel_stream_sharded_matches_keys(interpret_kernel):
     mesh = Mesh(np.asarray(jax.devices()[:8]), ("batch",))
     info_s: dict = {}
     st_s, fa_s, n_s = check_batch(batch, F=PS.F, mesh=mesh,
-                                  engine="stream", info=info_s)
+                                  info=info_s)
     assert info_s["engine"] == "stream-sharded"
-    info_k: dict = {}
-    st_k, fa_k, n_k = check_batch(batch, F=PS.F, mesh=mesh,
-                                  engine="keys", info=info_k)
-    assert info_k["engine"] == "keys-sharded"
+    # the keys engine sharded over a mesh that divides the batch
+    st_k, fa_k, n_k = keys_verdicts(
+        batch, PS.F, Mesh(np.asarray(jax.devices()[:2]), ("batch",)))
     np.testing.assert_array_equal(st_s, st_k)
     np.testing.assert_array_equal(fa_s, fa_k)
     # n is only defined on VALID verdicts (on INVALID the kernel

@@ -74,6 +74,8 @@ def test_keys_engine_with_pallas_flag_matches(monkeypatch, request):
         p = pack_history(h)
         want.append(linear_host.check(make_memo(model, p), p).valid)
     batch = pack_batch(hs, model)
-    st, _, _ = check_batch(batch, F=128, engine="keys")
+    info = {}
+    st, _, _ = check_batch(batch, F=128, info=info)
+    assert info["engine"] == "keys"
     got = [bool(s == 0) if s != 2 else "unknown" for s in st]
     assert got == want

@@ -189,7 +189,7 @@ def test_analysis_wide_p_low_concurrency_invalid_counterexample():
 
 
 def test_segment_batch_accepts_prebuilt_renamed_streams():
-    """The keys/flat fallback reuses the stream path's already-built
+    """The XLA fallback reuses the stream path's already-built
     (union-remapped, slot-renamed) streams instead of re-running the
     O(total-ops) segment pass — verdicts must match the from-scratch
     SegmentBatch through the keys engine."""

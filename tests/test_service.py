@@ -220,6 +220,31 @@ def _spawn_daemon(*extra):
     return proc, ready["port"]
 
 
+def test_daemon_peer_closed_before_reply():
+    """A reply due to a client that already closed its end counts as
+    dropped: the first write to such a socket raises nothing (TCP
+    answers it with a reset), so the daemon looks for the EOF first.
+    A live client, idle or with pipelined requests unread, is not
+    taken for gone."""
+    from comdb2_tpu.service.daemon import VerifierDaemon
+
+    gone, peer = socket.socketpair()
+    live, live_peer = socket.socketpair()
+    busy, busy_peer = socket.socketpair()
+    try:
+        for s in (gone, live, busy):
+            s.setblocking(False)
+        peer.close()
+        busy_peer.sendall(b'{"op": "ping"}\n')
+        assert VerifierDaemon._peer_closed(gone)
+        assert not VerifierDaemon._peer_closed(live)
+        assert not VerifierDaemon._peer_closed(busy)
+        assert busy.recv(64) == b'{"op": "ping"}\n'   # peek kept it
+    finally:
+        for s in (gone, live, live_peer, busy, busy_peer):
+            s.close()
+
+
 def test_daemon_end_to_end(tmp_path):
     from comdb2_tpu.service.client import ServiceClient, ServiceError
 
@@ -240,7 +265,9 @@ def test_daemon_end_to_end(tmp_path):
             {"op": "check", "history": history_to_edn(h)}) +
             "\n").encode())
         s.close()
-        deadline = time.monotonic() + 10
+        # the drop is counted when the reply is due, after a check a
+        # loaded machine may take tens of seconds to run
+        deadline = time.monotonic() + 120
         while time.monotonic() < deadline:
             st = c.status()["status"]
             if st["dropped_replies"] >= 1:
@@ -263,45 +290,18 @@ def test_daemon_end_to_end(tmp_path):
         assert r.returncode == 0, r.stdout + r.stderr
         assert "'valid': True" in r.stdout
         assert c.shutdown()
-    finally:
-        try:
-            rc = proc.wait(timeout=120)
-        except subprocess.TimeoutExpired:
-            proc.kill()           # never leak a daemon into the suite
-            proc.wait(timeout=30)
-            raise
-    assert rc == 0
-
-
-def test_bench_service_quick():
-    """The bench script's structural assertions (dispatch-count bound,
-    overload replies, disconnect survival) on a small CPU run."""
-    out = os.path.join(ROOT, "tests", "_bench_service_quick.json")
+    except BaseException:
+        # kill at once, so the failure above is what the run reports
+        proc.kill()
+        proc.wait(timeout=30)
+        raise
     try:
-        r = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "scripts",
-                                          "bench_service.py"),
-             "--quick", "--out", out],
-            cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"},
-            capture_output=True, text=True, timeout=600)
-        assert r.returncode == 0, r.stdout + r.stderr
-        with open(out) as fh:
-            res = json.loads(fh.read())
-        assert res["burst_dispatches"] <= res["requests"]
-        assert res["burst"]["latency_p99_ms"] >= \
-            res["burst"]["latency_p50_ms"] > 0
-        assert res["overload_replies"] >= 1
-        assert res["survived_disconnect"] is True
-        # the obs plane rides the bench: per-stage histograms from
-        # the scrape, per-reply stage sums checked, trace artifact
-        assert set(res["stages_ms"]) == {"queue_wait", "host_pack",
-                                         "device", "finalize"}
-        assert res["stages_ms"]["device"]["count"] > 0
-        assert res["stage_sum_checked"] >= 1
-        assert res["trace"]["events"] > 0
-    finally:
-        if os.path.exists(out):
-            os.unlink(out)
+        rc = proc.wait(timeout=120)
+    except subprocess.TimeoutExpired:
+        proc.kill()               # never leak a daemon into the suite
+        proc.wait(timeout=30)
+        raise
+    assert rc == 0
 
 
 # --- store artifact ----------------------------------------------------------

@@ -174,19 +174,18 @@ def test_f_escalation_wiring_with_fake_stream_engine(monkeypatch):
     monkeypatch.setattr(PSEG, "stream_dispatch", fake_dispatch)
 
     info: dict = {}
-    status, fail_at, n_final = B.check_batch(batch, F=1024,
-                                             engine="stream", info=info)
+    status, fail_at, n_final = B.check_batch(batch, F=1024, info=info)
+    assert info["engine"] == "stream"
     # the UNKNOWN resolved through the ladder; everything matches solo
     for b in range(len(hs)):
         assert int(status[b]) == int(solo[b][0][0]), (b, status)
     assert info.get("escalated", {}).get("count") == 1, info
-    assert info["escalated"]["engine"] in ("keys", "flat", "vmap")
+    assert info["escalated"]["engine"] == "keys"
 
     # at F == the top rung there is nothing to escalate: the UNKNOWN
     # must surface as-is (re-running at the same budget could only
     # reproduce the overflow)
     info2: dict = {}
-    status2, _, _ = B.check_batch(batch, F=PSEG.F, engine="stream",
-                                  info=info2)
+    status2, _, _ = B.check_batch(batch, F=PSEG.F, info=info2)
     assert int(status2[2]) == LJ.UNKNOWN
     assert "escalated" not in info2
